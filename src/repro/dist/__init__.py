@@ -1,5 +1,5 @@
 """Parallelism substrate: logical-axis sharding rules, mesh/shard_map
-version compatibility, and spec derivation for params/batches/caches.
+entry points, and spec derivation for params/batches/caches.
 
 ``api``      — ``ShardingRules`` (logical axis -> mesh axis), the
                ``use_rules``/``current_rules`` context, and ``constrain``
@@ -8,9 +8,8 @@ version compatibility, and spec derivation for params/batches/caches.
 ``sharding`` — ``ShardFlags``, ``make_rules`` (train/serve rule sets),
                and the pytree spec derivers ``param_specs`` /
                ``batch_specs`` / ``cache_specs`` / ``to_shardings``.
-``compat``   — the narrow slice of newer-JAX surface this repo uses
-               (``make_mesh``, ``shard_map``), tolerant of the installed
-               JAX version.
+``compat``   — ``make_mesh`` and ``shard_map``, spelled once for the
+               installed JAX.
 """
 from . import api, compat, sharding
 from .api import ShardingRules, constrain, current_rules, use_rules

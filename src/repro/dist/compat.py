@@ -1,66 +1,26 @@
-"""The narrow slice of newer-JAX API this repo uses, tolerant of the
-installed version.
-
-Two surfaces moved between JAX releases:
-
-* ``jax.make_mesh`` grew an ``axis_types`` kwarg (explicit-sharding work);
-  older releases reject it. All our meshes are Auto-typed — the default on
-  every release — so the portable spelling simply omits it.
-* ``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-  and renamed ``check_rep``->``check_vma`` / ``auto`` (complement) ->
-  ``axis_names`` (manual set).
-
-Callers use these wrappers instead of touching ``jax.*`` directly.
+"""The two mesh entry points this repo shares, spelled once for the
+installed JAX (0.9): every mesh is Auto-typed, and ``shard_map`` is
+manual over ``axis_names`` and auto over the rest of the mesh.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
-_MAKE_MESH_PARAMS = inspect.signature(jax.make_mesh).parameters
-
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """Auto-typed mesh on any supported JAX version."""
-    kwargs = {}
-    if "axis_types" in _MAKE_MESH_PARAMS and hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    """Auto-typed mesh."""
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         devices=devices, **kwargs)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new name) / ``TPUCompilerParams`` (old)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-# shard_map moved to the top level and renamed kwargs (check_rep ->
-# check_vma, auto-complement -> axis_names) at different releases, so
-# resolve the function first, then key every kwarg off its signature.
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-_SHARD_MAP = _resolve_shard_map()
-_SHARD_MAP_PARAMS = inspect.signature(_SHARD_MAP).parameters
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names: frozenset,
               check: bool = False):
     """Manual over ``axis_names``, auto over the rest of ``mesh``."""
-    kwargs = {"check_vma" if "check_vma" in _SHARD_MAP_PARAMS else "check_rep":
-              check}
-    if "axis_names" in _SHARD_MAP_PARAMS:
-        kwargs["axis_names"] = frozenset(axis_names)
-    else:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset(axis_names), check_vma=check)

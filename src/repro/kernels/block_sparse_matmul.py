@@ -41,7 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.quant import round_sat
-from ..dist.compat import tpu_compiler_params
 
 # int8 symmetric code bound: requantizing epilogues clamp to ±127 (both
 # Q2.5 and Q3.4 share it — the sign bit plus 7 magnitude bits of an int8)
@@ -70,6 +69,13 @@ def quantized_contract(x, w, scale, out_scale=None):
         "the requantizing epilogue (out_scale) is part of the int8-code "
         "contract — f32 operands flush f32")
     return jnp.float32, x.dtype
+
+
+def mxu_precision(dtype):
+    """Dot precision of one operand dtype: f32 operands contract at full
+    f32 precision on the MXU (the f32 execution contract, held to the f32
+    reference); bf16 and int8 operands are exact at Mosaic's default."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def unpack_epilogue_refs(refs, has_scale, has_bias, has_out=False):
@@ -130,7 +136,8 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs, acc_dtype, has_scale,
     @pl.when(s < cnt_ref[j])
     def _compute():
         acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                                preferred_element_type=acc_dtype)
+                                preferred_element_type=acc_dtype,
+                                precision=mxu_precision(x_ref.dtype))
 
     @pl.when(s == pl.num_programs(2) - 1)
     def _flush():
@@ -190,7 +197,7 @@ def block_sparse_matmul(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*inputs)
 
@@ -204,7 +211,8 @@ def _grad_w_kernel(kk_ref, nn_ref, x_ref, g_ref, o_ref, acc_ref):
 
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=mxu_precision(x_ref.dtype))
 
     @pl.when(m == pl.num_programs(1) - 1)
     def _flush():
@@ -259,6 +267,6 @@ def block_sparse_grad_weight(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((L, bk, bn), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(kk, nn, x, g)

@@ -12,15 +12,28 @@ contract on the Pallas grid:
 - Grid is ``(B·bpi, nNb, max_nnz)`` — M-blocks × output tile columns ×
   live K-tiles, exactly like :mod:`block_sparse_matmul`.
 - The x operand is the **padded NHWC activation itself**, left in HBM
-  (``memory_space=ANY``). Per live K-tile the kernel DMAs only the
-  *window* its M-block reads — ``(rows, cols, cpk)`` where ``rows/cols``
-  cover ``block_oh × block_ow`` output pixels at the conv's stride —
-  into a **double-buffered** VMEM slab with
+  (``memory_space=ANY``), its channels padded to whole 128-lane groups
+  and its columns to whole 8-row tiles (the tiled HBM layout pads them
+  anyway; a DMA may slice those axes only on tile boundaries). Per live
+  K-tile the kernel DMAs only the *window* its M-block reads —
+  ``(rows, cols)`` pixels covering ``block_oh × block_ow`` output pixels
+  at the conv's stride, of the 128-lane channel group that holds the
+  K-tile's ``cpk`` channels — into a **double-buffered** VMEM slab with
   :func:`pltpu.make_async_copy`: the copy for live tile ``t+1`` (keyed
   on the scalar-prefetched next table entry) is started before tile
   ``t``'s gather+dot runs, so slab traffic hides behind compute. Pruned
   groups cost neither DMA nor MXU cycles: dead tiles are never in the
   table, so their slabs are never fetched.
+- The gather builds the same ``(bm, bk)`` patch tile the materializing
+  path reads from HBM, in the forms the TPU's Mosaic compiler lowers:
+  int8 / bf16 slabs are widened to 32 bits (Mosaic's strided loads are
+  32-bit only); each tap ``(dy, dx)`` is one strided 3-D load,
+  reshaped to ``(bm, 128)`` and transposed so the K-tile's channels sit
+  on sublanes; a sublane-strided store drops channel ``c`` at row
+  ``c·slot + tap`` of a transposed ``(bk, bm)`` patch tile; one
+  transpose back, a cast to the operand dtype, and one MXU dot per live
+  step. The tile is element-for-element the materializing tile, so the
+  two paths feed the MXU identical operands.
 - M-blocking is **adaptive**: an M-block is ``block_oh`` whole output
   rows (``bm = ceil8(block_oh·Wo) ≤ cap`` — a batch-1 4×4 tail runs at
   ``bm=16`` instead of padding to 128), and when even one output row
@@ -31,15 +44,16 @@ contract on the Pallas grid:
   never straddle images.
 - The fused bias+ReLU flush epilogue carries over unchanged.
 
-Per live grid step the kernel moves ``rows·cols·cpk`` activation
-elements — the window its M-block actually reads — instead of ``bm·bk``
-patch-matrix elements, and the patch matrix is never written at all.
-VMEM working set adds the two slab buffers;
-:data:`SLAB_VMEM_BUDGET` bounds them, callers fall back to the
-materializing oracle above it.
+Per live grid step the kernel moves one ``(rows, cols)`` window of one
+128-lane channel group — the pixels its M-block actually reads —
+instead of ``bm·bk`` patch-matrix elements, and the patch matrix is
+never written to HBM at all. :func:`window_vmem_bytes` counts the
+window working set as the chip allocates it (lane- and sublane-padded);
+:data:`SLAB_VMEM_BUDGET` bounds it, and layers above it take the
+materializing path (``sparse.conv_plan.implicit_m_block``).
 
 Operands may be **int8 Q-format codes** (the paper's Q3.4 activations ×
-Q2.5 coefficients): the in-VMEM gather is dtype-agnostic, accumulation
+Q2.5 coefficients): the in-VMEM gather moves codes exactly, accumulation
 switches to exact int32, and the flush epilogue dequantizes through a
 per-cout ``scale`` row before bias/ReLU — one byte per operand element
 moved instead of four, on exactly the same grid and index table.
@@ -69,6 +83,7 @@ structure to exploit).
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -76,16 +91,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..dist.compat import tpu_compiler_params
 from .block_sparse_matmul import (append_epilogue_inputs, flush_epilogue,
-                                  quantized_contract, unpack_epilogue_refs)
+                                  mxu_precision, quantized_contract,
+                                  unpack_epilogue_refs)
 from .conv_lowering import same_pads
 
-# Largest activation working set (bytes) the implicit kernel will hold in
-# VMEM: both double-buffer slots of the (rows, cols, cpk) window slab.
-# Above this the caller uses the materializing path (still correct, just
-# HBM-hungrier).
+# Largest padded window working set (bytes, window_vmem_bytes) the
+# implicit kernel will hold in VMEM. Above this the layer uses the
+# materializing path (still correct, just HBM-hungrier).
 SLAB_VMEM_BUDGET = 2 * 1024 * 1024
+LANES = 128
 
 
 def _ceil_to(n: int, m: int) -> int:
@@ -174,37 +189,79 @@ def crop_output(out2d: jnp.ndarray, mb: MBlock, batch: int, ho: int,
     return o[:, :ho, :wo]
 
 
+def _sublane_tile(itemsize: int) -> int:
+    """Rows of one ``(sublanes, 128 lanes)`` VMEM tile for this element
+    width: 8 for 32-bit, 16 for bf16, 32 for int8."""
+    return 8 * (4 // itemsize)
+
+
+def _tiled_bytes(shape: Tuple[int, ...], itemsize: int) -> int:
+    """Bytes the chip allocates for a VMEM buffer of ``shape``: the minor
+    dim padded to 128 lanes, the second-minor to the sublane tile."""
+    *lead, sub, lane = shape
+    return (math.prod(lead) * _ceil_to(sub, _sublane_tile(itemsize))
+            * _ceil_to(lane, LANES) * itemsize)
+
+
+def dma_cols(mb: MBlock, kx: int, ky: int, stride: int) -> int:
+    """Columns of one window DMA: the window's ``cols`` rounded up to the
+    8-row tiling of the HBM activation's second-minor axis (a DMA may
+    only slice that axis on tile boundaries)."""
+    return _ceil_to(window_shape(mb, kx, ky, stride)[1], 8)
+
+
+def window_vmem_bytes(mb: MBlock, kx: int, ky: int, stride: int, bk: int,
+                      itemsize: int) -> int:
+    """Padded VMEM bytes of the implicit kernel's window working set: both
+    DMA slab slots (a window carries its whole 128-lane channel group),
+    the 32-bit working copy of the live slab (int8 / bf16 operands), and
+    the two transposed ``(128, bm)`` / ``(bk, bm)`` tap and patch tiles."""
+    rows = window_shape(mb, kx, ky, stride)[0]
+    cols = dma_cols(mb, kx, ky, stride)
+    slab = _tiled_bytes((2, rows, cols, LANES), itemsize)
+    work = 0 if itemsize == 4 else _tiled_bytes((rows, cols, LANES), 4)
+    return (slab + work + _tiled_bytes((LANES, mb.bm), 4)
+            + _tiled_bytes((bk, mb.bm), 4))
+
+
 def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
-            kx, ky, stride, block_oh, block_ow, spi, bpi, cpk, slot, bm, bk,
+            kx, ky, stride, block_oh, block_ow, spi, bpi, cpk, slot,
             acc_dtype, has_scale, has_bias, has_out, relu, activation_dsb,
-            count_skips):
+            count_skips, widen):
     n_ep = int(has_scale) + int(has_bias) + int(has_out)
     skip_ref = refs[n_ep + 1] if count_skips else None
-    acc_ref, slab_ref, sem_ref = refs[-3], refs[-2], refs[-1]
+    n_scratch = 6 if widen else 5
+    acc_ref, slab_ref, *work, tap_ref, patch_ref, sem_ref = refs[-n_scratch:]
     scale_ref, b_ref, out_ref, o_ref, _ = unpack_epilogue_refs(
         (*refs[:n_ep + 1], acc_ref), has_scale, has_bias, has_out)
     i, j, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     live = cnt_ref[j]
     rows = (block_oh - 1) * stride + kx
     cols = (block_ow - 1) * stride + ky
+    cols_dma = _ceil_to(cols, 8)
+    m = block_oh * block_ow
     b = i // bpi
     p = i % bpi
     r0 = (p // spi) * (block_oh * stride)
-    q0 = (p % spi) * (block_ow * stride)
+    q0 = 0 if spi == 1 else pl.multiple_of((p % spi) * (block_ow * stride), 8)
     buf = jax.lax.rem(s, 2)
 
     def slab_copy(e, sl):
-        # window of live K-tile (= cin-block) idx[j, e] into slab slot sl
-        c0 = idx_ref[j, e] * cpk
+        # window of the 128-lane channel group holding live K-tile
+        # (= cin-block) idx[j, e], into slab slot sl
+        lane0 = pl.multiple_of(idx_ref[j, e] * cpk // LANES * LANES, LANES)
         return pltpu.make_async_copy(
-            x_ref.at[b, pl.ds(r0, rows), pl.ds(q0, cols), pl.ds(c0, cpk)],
+            x_ref.at[b, pl.ds(r0, rows), pl.ds(q0, cols_dma),
+                     pl.ds(lane0, LANES)],
             slab_ref.at[sl], sem_ref.at[sl])
 
     @pl.when(s == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        # unused tap slots and rows past the M-block stay zero
+        patch_ref[...] = jnp.zeros_like(patch_ref)
         if count_skips:
-            skip_ref[0, 0] = 0
+            skip_ref[0, 0, 0] = 0
 
         @pl.when(live > 0)
         def _warmup():
@@ -218,36 +275,50 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
         def _prefetch():                    # overlap tile s+1's DMA with
             slab_copy(s + 1, 1 - buf).start()   # tile s's gather+dot
 
-        win = slab_ref[buf]                 # (rows, cols, cpk) window slab
+        # Mosaic loads strided windows of 32-bit data only: int8 / bf16
+        # slabs are widened once per step into the working copy
+        if widen:
+            win_ref = work[0]
+            win_ref[...] = slab_ref[buf].astype(win_ref.dtype)
+        else:
+            win_ref = slab_ref.at[buf]
+        coff = idx_ref[j, s] * cpk % LANES     # K-tile's first lane
+        if cpk % 8 == 0:
+            coff = pl.multiple_of(coff, 8)
 
         def _gather_mac():
             # the im2col gather, in VMEM: tap (dy, dx) of output pixel
-            # (oh, ow) is win[oh*stride + dy, ow*stride + dx] — kx*ky
-            # static strided slices instead of an HBM patch matrix
-            taps = [win[dy:dy + (block_oh - 1) * stride + 1:stride,
-                        dx:dx + (block_ow - 1) * stride + 1:stride, :]
-                    for dy in range(kx) for dx in range(ky)]
-            pt = jnp.stack(taps, axis=-1)   # (block_oh, block_ow, cpk, kx*ky)
-            if slot > kx * ky:              # sublane-aligned row slots
-                pt = jnp.pad(pt, ((0, 0), (0, 0), (0, 0),
-                                  (0, slot - kx * ky)))
-            pt = pt.reshape(block_oh * block_ow, cpk * slot)
-            if bm > block_oh * block_ow or bk > cpk * slot:
-                pt = jnp.pad(pt, ((0, bm - block_oh * block_ow),
-                                  (0, bk - cpk * slot)))
+            # (oh, ow) is win[oh*stride + dy, ow*stride + dx]. Each tap is
+            # one strided load, transposed so the K-tile's cpk channels
+            # land on sublanes c*slot + tap of the (bk, bm) patch tile;
+            # one transpose back gives the (bm, bk) tile the materializing
+            # path would have read from HBM, element for element
+            for dy in range(kx):
+                for dx in range(ky):
+                    tap = win_ref[pl.ds(dy, block_oh, stride=stride),
+                                  pl.ds(dx, block_ow, stride=stride), :]
+                    tap_ref[:, pl.ds(0, m)] = tap.reshape(m, LANES).T
+                    patch_ref[pl.ds(dy * ky + dx, cpk, stride=slot),
+                              pl.ds(0, m)] = tap_ref[pl.ds(coff, cpk),
+                                                     pl.ds(0, m)]
+            pt = patch_ref[...].T.astype(w_ref.dtype)
             acc_ref[...] += jnp.dot(pt, w_ref[...],
-                                    preferred_element_type=acc_dtype)
+                                    preferred_element_type=acc_dtype,
+                                    precision=mxu_precision(w_ref.dtype))
 
         if activation_dsb:
             # post-ReLU zeros are exact int8 codes: an all-zero window
             # contributes exactly nothing, so skip the gather AND the
             # MXU dot — the untouched accumulator keeps bit-exactness
-            hit = jnp.any(win != 0)
+            win = jnp.abs(win_ref[:, pl.ds(0, cols), :])
+            lane = jax.lax.broadcasted_iota(jnp.int32, win.shape, 2)
+            own = (lane >= coff) & (lane < coff + cpk)
+            hit = jnp.max(jnp.where(own, win, 0)) > 0
             pl.when(hit)(_gather_mac)
             if count_skips:
                 @pl.when(jnp.logical_not(hit))
                 def _count():
-                    skip_ref[0, 0] += 1
+                    skip_ref[0, 0, 0] += 1
         else:
             _gather_mac()
 
@@ -316,46 +387,66 @@ def implicit_block_sparse_conv(
     has_scale = scale is not None
     has_bias = bias is not None
     has_out = out_scale is not None
+    # HBM view with the channels padded to whole 128-lane groups (the
+    # tiled layout pads them anyway) and the columns to whole 8-row
+    # tiles: a window DMA then slices the lane axis and the column axis
+    # only on tile boundaries
+    cols_dma = dma_cols(mb, kx, ky, stride)
+    wd = _ceil_to(max(Wp, (mb.spi - 1) * mb.block_ow * stride + cols_dma), 8)
+    xk = jnp.pad(xp, ((0, 0), (0, 0), (0, wd - Wp),
+                      (0, _ceil_to(Cp, LANES) - Cp)))
 
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),   # padded NHWC stays in HBM;
+        pl.BlockSpec(memory_space=pl.ANY),      # activation stays in HBM;
         # the kernel DMAs per-M-block windows of the prefetched K-tile
         pl.BlockSpec((bk, bn), lambda i, j, s, idx, cnt: (idx[j, s], j)),
     ]
-    inputs = [idx, cnt, xp, w]
+    inputs = [idx, cnt, xk, w]
     append_epilogue_inputs(in_specs, inputs, scale, bias, bn, out_scale)
 
     out_specs = pl.BlockSpec((mb.bm, bn), lambda i, j, s, idx, cnt: (i, j))
     out_shape = jax.ShapeDtypeStruct((B * mb.bpi * mb.bm, w.shape[1]),
                                      out_dtype)
     if count_skips:
+        # one SMEM word per (M-block, column); the two minor dims are
+        # whole-array so the (1, 1, 1) block is legal
         out_specs = [out_specs, pl.BlockSpec(
-            memory_space=pltpu.SMEM, block_shape=(1, 1),
-            index_map=lambda i, j, s, idx, cnt: (i, j))]
+            (1, 1, 1), lambda i, j, s, idx, cnt: (i * nNb + j, 0, 0),
+            memory_space=pltpu.SMEM)]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((B * mb.bpi, nNb), jnp.int32)]
+                     jax.ShapeDtypeStruct((B * mb.bpi * nNb, 1, 1), jnp.int32)]
 
+    widen = xp.dtype.itemsize != 4
+    wide_dtype = jnp.int32 if xp.dtype == jnp.int8 else jnp.float32
+    scratch = [pltpu.VMEM((mb.bm, bn), acc_dtype),
+               pltpu.VMEM((2, rows, cols_dma, LANES), xp.dtype)]
+    if widen:
+        scratch.append(pltpu.VMEM((rows, cols_dma, LANES), wide_dtype))
+    scratch += [pltpu.VMEM((LANES, mb.bm), wide_dtype),
+                pltpu.VMEM((bk, mb.bm), wide_dtype),
+                pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * mb.bpi, nNb, max_nnz),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((mb.bm, bn), acc_dtype),
-                        pltpu.VMEM((2, rows, cols, cpk), xp.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
+        scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         functools.partial(_kernel, kx=kx, ky=ky, stride=stride,
                           block_oh=mb.block_oh, block_ow=mb.block_ow,
-                          spi=mb.spi, bpi=mb.bpi, cpk=cpk,
-                          slot=slot, bm=mb.bm, bk=bk, acc_dtype=acc_dtype,
-                          has_scale=has_scale, has_bias=has_bias,
-                          has_out=has_out, relu=relu,
+                          spi=mb.spi, bpi=mb.bpi, cpk=cpk, slot=slot,
+                          acc_dtype=acc_dtype, has_scale=has_scale,
+                          has_bias=has_bias, has_out=has_out, relu=relu,
                           activation_dsb=activation_dsb,
-                          count_skips=count_skips),
+                          count_skips=count_skips, widen=widen),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*inputs)
+    if count_skips:
+        out, skips = res
+        return out, skips.reshape(B * mb.bpi, nNb)
+    return res

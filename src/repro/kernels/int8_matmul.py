@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..dist.compat import tpu_compiler_params
 
 
 def _kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref):
@@ -74,6 +73,6 @@ def int8_matmul(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x_codes, w_codes, scale.reshape(1, N).astype(jnp.float32))

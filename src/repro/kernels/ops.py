@@ -19,7 +19,17 @@ from .int8_matmul import int8_matmul
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU (tests, rehearsals); compiled
+    Mosaic kernels on a TPU. Any other backend has no way to run the
+    kernels and is refused rather than silently interpreted."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels run compiled on a TPU or interpreted on the "
+        f"CPU; JAX's default backend is {backend!r}")
 
 
 def _pad_rows(x2d: jnp.ndarray, bm: int):

@@ -42,6 +42,8 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import logging
+import os
+import pathlib
 import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -61,7 +63,22 @@ from .resilience import (DENSE_RUNG, DeadlineExceeded, FaultPlan,
 logger = logging.getLogger(__name__)
 
 SNAPSHOT_KIND = "cnn_server_snapshot"
+CHECKOUT_ROOT = pathlib.Path(__file__).resolve().parents[3]
 _MASK_PREFIX = "masks|"          # checkpoint._flatten path join of {"masks": ...}
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at one fixed directory, so
+    later runs from this checkout reuse compiled programs and kernels.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set here; otherwise the cache is ``<checkout>/.jax_cache``.
+    Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _fresh_resilience_counters() -> Dict[str, int]:
@@ -702,6 +719,7 @@ def main(argv=None):
                     help="per-request deadline for the trace simulation")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     from repro.core import (HAPMConfig, apply_masks, hapm_element_masks,
                             hapm_epoch_update, hapm_init)

@@ -409,6 +409,8 @@ class SparseConvExec:
     bm: Any = 128                    # M-blocking policy: int (fixed) or "auto"
     spec: Optional[ExecSpec] = None  # the requested bind contract, if built
                                      # through bind_execution
+    paths: Any = None                # {path: "implicit" | "materializing" |
+                                     # "dense"} — each layer's bind-time route
 
     def _accounting(self, bm=None, implicit=None, operand_bytes=None,
                     dtype_bytes: int = 4, out_bytes=None):
@@ -578,9 +580,11 @@ class SparseConvExec:
         1-byte output writes (the requantizing epilogue emits Q3.4 codes
         the next layer ingests). ``hbm_bytes`` and the grid-step fields
         describe the exec's *own* policy (own contract, own ``bm``, own
-        operand/output widths). ``per_layer=True`` adds the same fields
-        per conv layer (keys ``"/".join(path)``), which is what the
-        simulator reports next to the cycle model.
+        operand/output widths). ``layers_{implicit,materializing,dense}``
+        count the conv layers on each route chosen at bind time
+        (:attr:`paths`). ``per_layer=True`` adds the same fields, plus
+        the layer's ``path``, per conv layer (keys ``"/".join(path)``),
+        which is what the simulator reports next to the cycle model.
 
         ``dsb_sample`` (with ``dsb_tree``, the tree this exec was bound
         from, and ``dsb_state`` for non-folded execs) additionally runs
@@ -610,6 +614,9 @@ class SparseConvExec:
             "padded_mac_utilization": self.mac_utilization(cfg, batch=batch),
             "dense_fallback_layers": sum(v is None
                                          for v in self.table.values()),
+            **{f"layers_{route}": sum(v == route
+                                      for v in self.paths.values())
+               for route in ("implicit", "materializing", "dense")},
             "bm_effective": self.bm_effective(cfg, batch=batch),
             "hbm_bytes": self.hbm_bytes(cfg, batch, dtype_bytes=dtype_bytes),
             "hbm_bytes_materialized": hbm(False, 128, dtype_bytes),
@@ -641,6 +648,7 @@ class SparseConvExec:
                 dtype_bytes=dtype_bytes, operand_bytes=ob,
                 out_bytes=dtype_bytes if out_b is None else out_b)
             out["/".join(path)] = {
+                "path": self.paths[path],
                 "executed": mb * int(plan.cnt.sum()),
                 "dense": mb * plan.tiles[0] * plan.tiles[1],
                 "bm_effective": bm_eff,
@@ -803,9 +811,32 @@ def bind_execution(
     or serve through ``launch.exec_cache`` which re-keys on the sparsity
     fingerprint).
     """
-    from ..sparse.conv_plan import make_sparse_conv
+    from ..sparse.conv_plan import implicit_m_block, make_sparse_conv
 
     spec = ExecSpec() if spec is None else spec
+    # (stride, input size) per conv: the shapes the route is chosen from
+    geometry = ({path: (stride, feat)
+                 for path, stride, feat in conv_layer_order(cfg)}
+                if cfg is not None else {})
+
+    def implicit_for(keys, layout):
+        """Bind-time route of one layer: the implicit kernel where
+        ``implicit_m_block`` accepts the layer's geometry at its operand
+        width, else the materializing path. Without a config (no
+        geometry) or on a tap-major layout the request passes through."""
+        if (spec.implicit is False or keys not in geometry
+                or layout.implicit_geometry() is None):
+            return spec.implicit
+        stride, feat = geometry[keys]
+        fits = implicit_m_block(layout, feat, feat, stride, "SAME",
+                                1 if spec.quantized else 4,
+                                spec.bm) is not None
+        if spec.activation_dsb and not fits:
+            raise PermanentBindError(
+                f"activation_dsb lives in the implicit kernel, but "
+                f"{'/'.join(keys)} ({feat}x{feat}, stride {stride}) does "
+                "not fit its VMEM window budget")
+        return fits
     if spec.folded:
         if quant_spec is not None:
             raise PermanentBindError(
@@ -834,8 +865,8 @@ def bind_execution(
                     "uniform per-layer scale chain")
             return make_sparse_conv(layout, gm, bm=spec.bm, weight=w,
                                     bias=bias, relu=relu,
-                                    implicit=spec.implicit, quant=quant,
-                                    out_quant=out_q,
+                                    implicit=implicit_for(keys, layout),
+                                    quant=quant, out_quant=out_q,
                                     activation_dsb=spec.activation_dsb)
     else:
         if quant_spec is not None and not spec.quantized:
@@ -858,16 +889,20 @@ def bind_execution(
                 # no prepack: the conv re-packs the caller's (traced)
                 # weight every call, so mid-epoch updates are never stale
                 return make_sparse_conv(layout, gm, bm=spec.bm,
-                                        implicit=spec.implicit,
+                                        implicit=implicit_for(keys, layout),
                                         trainable=True)
             return make_sparse_conv(layout, gm, bm=spec.bm,
                                     weight=leaf if spec.quantized else w,
-                                    implicit=spec.implicit, quant=qspec,
+                                    implicit=implicit_for(keys, layout),
+                                    quant=qspec,
                                     activation_dsb=spec.activation_dsb)
 
     table, plans, layouts, gms, bound = _bind_conv_layers(
         tree, specs, group_masks, spec.n_cu, spec.packed, weight_of,
         bind_one)
+    paths = {keys: "dense" if fn is None
+             else "implicit" if fn.implicit else "materializing"
+             for keys, fn in table.items()}
     return SparseConvExec(table=table, plans=plans, n_cu=spec.n_cu,
                           layouts=layouts, group_masks_np=gms,
                           quantized=spec.quantized, folded=spec.folded,
@@ -877,7 +912,7 @@ def bind_execution(
                           bound_weights=None if spec.trainable else bound,
                           implicit=_resolve_exec_implicit(spec.implicit,
                                                           layouts),
-                          bm=spec.bm, spec=spec)
+                          bm=spec.bm, spec=spec, paths=paths)
 
 
 def build_sparse_execution(

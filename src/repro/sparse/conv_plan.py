@@ -417,6 +417,33 @@ def conv_m_blocks(ho: int, wo: int, batch: int, *, bm="auto",
     return -(-batch * ho * wo // bm_eff), bm_eff
 
 
+def implicit_m_block(layout: ConvGemmLayout, h: int, w: int, stride: int,
+                     padding: str, itemsize: int, bm="auto"):
+    """The implicit kernel's M-block for one conv geometry, or ``None``
+    when the layer takes the materializing path: the layout packs K
+    tap-major (no window to gather), no whole-row or column-segment
+    M-block fits the ``bm`` cap, or the kernel's padded VMEM working set
+    (:func:`repro.kernels.implicit_conv.window_vmem_bytes`, at operand
+    width ``itemsize``) exceeds :data:`~repro.kernels.implicit_conv.
+    SLAB_VMEM_BUDGET`. The one decision point: ``bind_execution`` records
+    it per layer at bind time and the bound conv runs the same rule."""
+    from ..kernels import implicit_conv as IC
+    from ..kernels.conv_lowering import conv_out_size
+
+    geo = layout.implicit_geometry()
+    if geo is None:
+        return None
+    kx, ky = geo["kx"], geo["ky"]
+    mb = IC.choose_m_block(conv_out_size(h, kx, stride, padding),
+                           conv_out_size(w, ky, stride, padding),
+                           cap=128 if bm == "auto" else int(bm))
+    if mb is None or IC.window_vmem_bytes(
+            mb, kx, ky, stride, layout.block[0],
+            itemsize) > IC.SLAB_VMEM_BUDGET:
+        return None
+    return mb
+
+
 def conv_hbm_bytes(layout: ConvGemmLayout, group_mask, batch: int, h: int,
                    w: int, stride: int = 1, padding: str = "SAME", *,
                    implicit: bool, bm="auto", dtype_bytes: int = 4,
@@ -501,9 +528,11 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
         **implicit-im2col** kernel (:mod:`repro.kernels.implicit_conv`)
         gathers kernel windows from the padded NHWC activation inside the
         grid — the ``(B·Ho·Wo, kx·ky·cin)`` patch matrix is never
-        materialized in HBM. Falls back to the materializing path per
-        call when no whole-row M-block fits (very wide images) or the
-        activation slab would blow :data:`implicit_conv.SLAB_VMEM_BUDGET`.
+        materialized in HBM. A geometry :func:`implicit_m_block` rejects
+        (no M-block fits, or the padded window working set exceeds
+        :data:`implicit_conv.SLAB_VMEM_BUDGET`) runs the materializing
+        path; ``bind_execution`` makes that choice per layer at bind
+        time, from the config's shapes, and reports it.
         Forward-only (the materializing non-epilogue path keeps its VJP).
       - ``False``: the materializing im2col + ``block_sparse_matmul``
         path — the parity oracle, and the only path for
@@ -658,34 +687,29 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
         B, H, W, C = x.shape
         ho = conv_out_size(H, kx, stride, padding)
         wo = conv_out_size(W, ky, stride, padding)
-        if use_implicit:
-            mbk = IC.choose_m_block(ho, wo, cap=bm_cap)
-            if mbk is not None:
-                cpk, slot = geo["cpk"], geo["slot"]
-                rows, cols = IC.window_shape(mbk, kx, ky, stride)
-                # both double-buffer slots of the window slab
-                slab = 2 * rows * cols * cpk * x.dtype.itemsize
-                if slab <= IC.SLAB_VMEM_BUDGET:
-                    nKb = layout.tiles[0]
-                    xp = IC.pad_input(x, kx, ky, stride, padding, mbk,
-                                      nKb * cpk)
-                    res = IC.implicit_block_sparse_conv(
-                        xp, wp, idx_dev, cnt_dev, packed_bias, packed_scale,
-                        packed_out_scale,
-                        kx=kx, ky=ky, stride=stride, mb=mbk,
-                        block=layout.block, cpk=cpk, slot=slot, relu=relu,
-                        activation_dsb=activation_dsb,
-                        count_skips=count_skips,
-                        interpret=ops._interpret())
-                    out2d, skips = res if count_skips else (res, None)
-                    o = IC.crop_output(out2d, mbk, B, ho, wo)
-                    y = layout.unpack_output(
-                        o.reshape(B * ho * wo, -1), (B, ho, wo))
-                    if count_skips:
-                        live = B * mbk.bpi * int(plan.cnt.sum())
-                        return y, {"skipped_steps": int(skips.sum()),
-                                   "live_steps": live}
-                    return y
+        mbk = (implicit_m_block(layout, H, W, stride, padding,
+                                x.dtype.itemsize, bm)
+               if use_implicit else None)
+        if mbk is not None:
+            cpk, slot = geo["cpk"], geo["slot"]
+            xp = IC.pad_input(x, kx, ky, stride, padding, mbk,
+                              layout.tiles[0] * cpk)
+            res = IC.implicit_block_sparse_conv(
+                xp, wp, idx_dev, cnt_dev, packed_bias, packed_scale,
+                packed_out_scale,
+                kx=kx, ky=ky, stride=stride, mb=mbk,
+                block=layout.block, cpk=cpk, slot=slot, relu=relu,
+                activation_dsb=activation_dsb,
+                count_skips=count_skips,
+                interpret=ops._interpret())
+            out2d, skips = res if count_skips else (res, None)
+            o = IC.crop_output(out2d, mbk, B, ho, wo)
+            y = layout.unpack_output(o.reshape(B * ho * wo, -1), (B, ho, wo))
+            if count_skips:
+                live = B * mbk.bpi * int(plan.cnt.sum())
+                return y, {"skipped_steps": int(skips.sum()),
+                           "live_steps": live}
+            return y
         patches = im2col_patches(x, kx, ky, stride, padding)
         bm_eff = adaptive_bm(B * ho * wo, bm_cap) if adaptive else bm_cap
         out2d = _materializing(bm_eff)(layout.pack_patches(patches), wp)
