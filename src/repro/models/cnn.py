@@ -135,6 +135,14 @@ def _conv(x, w, stride):
     )
 
 
+def _logits(pooled, fc):
+    """The classifier head at full f32 precision: XLA's default on a TPU
+    rounds both operands to bf16, which would put the f32 execution
+    contract 1e-3 off the f32 reference in the last layer alone."""
+    return (jnp.dot(pooled, fc["w"], precision=jax.lax.Precision.HIGHEST)
+            + fc["b"])
+
+
 def _bn(x, p, s, train: bool, cfg: ResNetConfig):
     if train:
         mean = jnp.mean(x, axis=(0, 1, 2))
@@ -230,9 +238,7 @@ def apply(
                 sc = h
             h = _maybe_qa(jax.nn.relu(y + sc), cfg)
             new_state[name] = ns
-    pooled = jnp.mean(h, axis=(1, 2))
-    logits = pooled @ params["fc"]["w"] + params["fc"]["b"]
-    return logits, new_state
+    return _logits(jnp.mean(h, axis=(1, 2)), params["fc"]), new_state
 
 
 # ---------------------------------------------------------------------------
@@ -1204,5 +1210,4 @@ def apply_folded(
                 h = jax.nn.relu(y + sc)
     if wire:
         h = h.astype(jnp.float32) / wire_scale        # head: exact dequant
-    pooled = jnp.mean(h, axis=(1, 2))
-    return pooled @ folded["fc"]["w"] + folded["fc"]["b"]
+    return _logits(jnp.mean(h, axis=(1, 2)), folded["fc"])
