@@ -47,7 +47,9 @@ contract on the Pallas grid:
 Per live grid step the kernel moves one ``(rows, cols)`` window of one
 128-lane channel group — the pixels its M-block actually reads —
 instead of ``bm·bk`` patch-matrix elements, and the patch matrix is
-never written to HBM at all. :func:`window_vmem_bytes` counts the
+never written to HBM at all; the price is the lane-padded copy of the
+activation the windows are DMA'd from. :func:`implicit_hbm_bytes`
+counts both as the chip moves them, and :func:`window_vmem_bytes` the
 window working set as the chip allocates it (lane- and sublane-padded);
 :data:`SLAB_VMEM_BUDGET` bounds it, and layers above it take the
 materializing path (``sparse.conv_plan.implicit_m_block``).
@@ -152,6 +154,24 @@ def window_shape(mb: MBlock, kx: int, ky: int, stride: int) -> Tuple[int, int]:
             (mb.block_ow - 1) * stride + ky)
 
 
+def _input_pads(h: int, w: int, kx: int, ky: int, stride: int, padding: str,
+                mb: MBlock):
+    """((top, bottom), (left, right)) zero pads of :func:`pad_input`."""
+    if padding == "SAME":
+        (pt, pb), (pw0, pw1) = (same_pads(h, kx, stride),
+                                same_pads(w, ky, stride))
+    else:
+        pt = pb = pw0 = pw1 = 0
+    rb = mb.bpi // mb.spi
+    rows_need = (rb - 1) * mb.block_oh * stride \
+        + (mb.block_oh - 1) * stride + kx
+    cols_need = (mb.spi - 1) * mb.block_ow * stride \
+        + (mb.block_ow - 1) * stride + ky
+    extra_r = max(rows_need - (h + pt + pb), 0)
+    extra_c = max(cols_need - (w + pw0 + pw1), 0)
+    return (pt, pb + extra_r), (pw0, pw1 + extra_c)
+
+
 def pad_input(x: jnp.ndarray, kx: int, ky: int, stride: int, padding: str,
               mb: MBlock, c_packed: int) -> jnp.ndarray:
     """Zero-pad an NHWC input for the implicit kernel: the conv's own
@@ -160,19 +180,8 @@ def pad_input(x: jnp.ndarray, kx: int, ky: int, stride: int, padding: str,
     after the kernel), and channel padding to the packed K grid. Pure
     ``jnp.pad`` — no kx·ky patch blowup, no transpose."""
     B, H, W, C = x.shape
-    if padding == "SAME":
-        (pt, pb), (pw0, pw1) = same_pads(H, kx, stride), same_pads(W, ky, stride)
-    else:
-        pt = pb = pw0 = pw1 = 0
-    rb = mb.bpi // mb.spi
-    rows_need = (rb - 1) * mb.block_oh * stride \
-        + (mb.block_oh - 1) * stride + kx
-    cols_need = (mb.spi - 1) * mb.block_ow * stride \
-        + (mb.block_ow - 1) * stride + ky
-    extra_r = max(rows_need - (H + pt + pb), 0)
-    extra_c = max(cols_need - (W + pw0 + pw1), 0)
-    return jnp.pad(x, ((0, 0), (pt, pb + extra_r), (pw0, pw1 + extra_c),
-                       (0, c_packed - C)))
+    rows, cols = _input_pads(H, W, kx, ky, stride, padding, mb)
+    return jnp.pad(x, ((0, 0), rows, cols, (0, c_packed - C)))
 
 
 def crop_output(out2d: jnp.ndarray, mb: MBlock, batch: int, ho: int,
@@ -208,6 +217,35 @@ def dma_cols(mb: MBlock, kx: int, ky: int, stride: int) -> int:
     8-row tiling of the HBM activation's second-minor axis (a DMA may
     only slice that axis on tile boundaries)."""
     return _ceil_to(window_shape(mb, kx, ky, stride)[1], 8)
+
+
+def hbm_view_shape(xp_shape: Tuple[int, int, int, int], mb: MBlock, kx: int,
+                   ky: int, stride: int) -> Tuple[int, int, int, int]:
+    """Shape of the activation copy the kernel DMAs its windows from:
+    :func:`pad_input`'s ``(B, Hp, Wp, Cp)`` with the columns widened to
+    whole 8-row tiles that cover the last window DMA and the channels to
+    whole 128-lane groups (a DMA slices those axes only on tile
+    boundaries)."""
+    b, hp, wp, cp = xp_shape
+    wd = _ceil_to(max(wp, (mb.spi - 1) * mb.block_ow * stride
+                      + dma_cols(mb, kx, ky, stride)), 8)
+    return b, hp, wd, _ceil_to(cp, LANES)
+
+
+def implicit_hbm_bytes(batch: int, h: int, w: int, cin: int, c_packed: int,
+                       kx: int, ky: int, stride: int, padding: str,
+                       mb: MBlock, itemsize: int) -> Tuple[int, int]:
+    """HBM bytes of the implicit kernel's activation traffic, as the chip
+    moves it: ``(ingest, per_step)``. ``ingest`` reads the ``(batch, h,
+    w, cin)`` input once and writes its padded copy
+    (:func:`hbm_view_shape`); ``per_step`` is one live grid step's window
+    DMA, ``rows × dma_cols`` pixels of a whole 128-lane channel group."""
+    pads = _input_pads(h, w, kx, ky, stride, padding, mb)
+    view = hbm_view_shape((batch, h + sum(pads[0]), w + sum(pads[1]),
+                           c_packed), mb, kx, ky, stride)
+    rows = window_shape(mb, kx, ky, stride)[0]
+    return ((batch * h * w * cin + math.prod(view)) * itemsize,
+            rows * dma_cols(mb, kx, ky, stride) * LANES * itemsize)
 
 
 def window_vmem_bytes(mb: MBlock, kx: int, ky: int, stride: int, bk: int,
@@ -392,9 +430,8 @@ def implicit_block_sparse_conv(
     # tiles: a window DMA then slices the lane axis and the column axis
     # only on tile boundaries
     cols_dma = dma_cols(mb, kx, ky, stride)
-    wd = _ceil_to(max(Wp, (mb.spi - 1) * mb.block_ow * stride + cols_dma), 8)
-    xk = jnp.pad(xp, ((0, 0), (0, 0), (0, wd - Wp),
-                      (0, _ceil_to(Cp, LANES) - Cp)))
+    _, _, wd, cd = hbm_view_shape(xp.shape, mb, kx, ky, stride)
+    xk = jnp.pad(xp, ((0, 0), (0, 0), (0, wd - Wp), (0, cd - Cp)))
 
     in_specs = [
         pl.BlockSpec(memory_space=pl.ANY),      # activation stays in HBM;

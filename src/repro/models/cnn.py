@@ -415,8 +415,6 @@ class SparseConvExec:
     bm: Any = 128                    # M-blocking policy: int (fixed) or "auto"
     spec: Optional[ExecSpec] = None  # the requested bind contract, if built
                                      # through bind_execution
-    paths: Any = None                # {path: "implicit" | "materializing" |
-                                     # "dense"} — each layer's bind-time route
 
     def _accounting(self, bm=None, implicit=None, operand_bytes=None,
                     dtype_bytes: int = 4, out_bytes=None):
@@ -462,6 +460,18 @@ class SparseConvExec:
         return {"/".join(path):
                 self._m_blocks(-(-feat // stride), batch, bm, implicit)[1]
                 for path, stride, feat in conv_layer_order(cfg)}
+
+    def routes(self, cfg: ResNetConfig) -> dict:
+        """{layer path: "implicit" | "materializing" | "dense"} — the
+        route each conv runs at ``cfg``'s input sizes, as its bound conv
+        chose it (``conv.m_block``); "dense" for ``lax.conv`` layers."""
+        out = {}
+        for path, stride, feat in conv_layer_order(cfg):
+            fn = self.table[path]
+            out[path] = ("dense" if fn is None else "implicit"
+                         if fn.m_block(feat, feat, stride, "SAME") is not None
+                         else "materializing")
+        return out
 
     def hbm_bytes(self, cfg: ResNetConfig, batch: int = 1,
                   implicit: Any = None, bm=None, dtype_bytes: int = 4,
@@ -587,8 +597,8 @@ class SparseConvExec:
         the next layer ingests). ``hbm_bytes`` and the grid-step fields
         describe the exec's *own* policy (own contract, own ``bm``, own
         operand/output widths). ``layers_{implicit,materializing,dense}``
-        count the conv layers on each route chosen at bind time
-        (:attr:`paths`). ``per_layer=True`` adds the same fields, plus
+        count the conv layers on each route (:meth:`routes`).
+        ``per_layer=True`` adds the same fields, plus
         the layer's ``path``, per conv layer (keys ``"/".join(path)``),
         which is what the simulator reports next to the cycle model.
 
@@ -599,6 +609,7 @@ class SparseConvExec:
         fields — the measured dual-sided skip accounting."""
         executed, dense = self.step_counts(cfg, batch=batch)
         live, total = self.schedule_step_counts()
+        routes = self.routes(cfg)
         hbm = lambda imp, bm, ob, out=None: self.hbm_bytes(
             cfg, batch, implicit=imp, bm=bm, dtype_bytes=dtype_bytes,
             operand_bytes=ob, out_bytes=dtype_bytes if out is None else out)
@@ -620,8 +631,7 @@ class SparseConvExec:
             "padded_mac_utilization": self.mac_utilization(cfg, batch=batch),
             "dense_fallback_layers": sum(v is None
                                          for v in self.table.values()),
-            **{f"layers_{route}": sum(v == route
-                                      for v in self.paths.values())
+            **{f"layers_{route}": sum(v == route for v in routes.values())
                for route in ("implicit", "materializing", "dense")},
             "bm_effective": self.bm_effective(cfg, batch=batch),
             "hbm_bytes": self.hbm_bytes(cfg, batch, dtype_bytes=dtype_bytes),
@@ -634,14 +644,15 @@ class SparseConvExec:
         rep["hbm_bytes_ratio"] = (rep["hbm_bytes_implicit"]
                                   / max(rep["hbm_bytes_materialized"], 1))
         if per_layer:
-            rep["per_layer"] = self._per_layer_report(cfg, batch, dtype_bytes)
+            rep["per_layer"] = self._per_layer_report(cfg, batch, dtype_bytes,
+                                                      routes)
         if dsb_sample is not None:
             rep.update(self.measure_dsb_skip(dsb_tree, dsb_sample, cfg,
                                              state=dsb_state))
         return rep
 
     def _per_layer_report(self, cfg: ResNetConfig, batch: int,
-                          dtype_bytes: int) -> dict:
+                          dtype_bytes: int, routes: dict) -> dict:
         from ..sparse.conv_plan import conv_hbm_bytes
         out = {}
         for path, stride, feat in conv_layer_order(cfg):
@@ -654,7 +665,7 @@ class SparseConvExec:
                 dtype_bytes=dtype_bytes, operand_bytes=ob,
                 out_bytes=dtype_bytes if out_b is None else out_b)
             out["/".join(path)] = {
-                "path": self.paths[path],
+                "path": routes[path],
                 "executed": mb * int(plan.cnt.sum()),
                 "dense": mb * plan.tiles[0] * plan.tiles[1],
                 "bm_effective": bm_eff,
@@ -796,10 +807,11 @@ def bind_execution(
     :func:`apply_folded` detects the streamed exec and runs the whole
     residual dataflow on codes.
 
-    ``cfg`` is accepted for signature uniformity across the two bind
-    flavors (layer topology comes from the tree itself; a future
-    cfg-dependent bind — e.g. HPIPE-style layer fusion — slots in without
-    changing call sites). ``specs``: GroupSpec tree (default:
+    ``cfg`` gives each conv's input size and stride: every bound layer's
+    route (implicit kernel or materializing path) is then chosen once,
+    here, and the exec serves only that input size. Without it (layer
+    topology comes from the tree itself) each conv picks its route on
+    its first call at each input size. ``specs``: GroupSpec tree (default:
     ``conv_group_specs(params, spec.n_cu)``). ``group_masks``:
     (num_groups,) {0,1} per conv leaf (e.g. ``HAPMState.group_masks``);
     ``None`` derives masks from the weights' zero slabs
@@ -817,32 +829,26 @@ def bind_execution(
     or serve through ``launch.exec_cache`` which re-keys on the sparsity
     fingerprint).
     """
-    from ..sparse.conv_plan import implicit_m_block, make_sparse_conv
+    from ..sparse.conv_plan import make_sparse_conv
 
     spec = ExecSpec() if spec is None else spec
-    # (stride, input size) per conv: the shapes the route is chosen from
-    geometry = ({path: (stride, feat)
+    # (input size, input size, stride, padding) per conv: with a config
+    # each layer's route is chosen once, here, from these shapes
+    geometry = ({path: (feat, feat, stride, "SAME")
                  for path, stride, feat in conv_layer_order(cfg)}
                 if cfg is not None else {})
 
-    def implicit_for(keys, layout):
-        """Bind-time route of one layer: the implicit kernel where
-        ``implicit_m_block`` accepts the layer's geometry at its operand
-        width, else the materializing path. Without a config (no
-        geometry) or on a tap-major layout the request passes through."""
-        if (spec.implicit is False or keys not in geometry
-                or layout.implicit_geometry() is None):
-            return spec.implicit
-        stride, feat = geometry[keys]
-        fits = implicit_m_block(layout, feat, feat, stride, "SAME",
-                                1 if spec.quantized else 4,
-                                spec.bm) is not None
-        if spec.activation_dsb and not fits:
-            raise PermanentBindError(
-                f"activation_dsb lives in the implicit kernel, but "
-                f"{'/'.join(keys)} ({feat}x{feat}, stride {stride}) does "
-                "not fit its VMEM window budget")
-        return fits
+    def bind_conv(keys, layout, gm, **kw):
+        """One layer onto the kernels under ``spec``; a layer the bind
+        contract cannot serve (e.g. ``activation_dsb`` at a geometry the
+        implicit kernel does not fit) is a :class:`PermanentBindError`."""
+        try:
+            return make_sparse_conv(layout, gm, bm=spec.bm,
+                                    implicit=spec.implicit,
+                                    geometry=geometry.get(keys), **kw)
+        except ValueError as e:
+            raise PermanentBindError(f"{'/'.join(keys)}: {e}") from e
+
     if spec.folded:
         if quant_spec is not None:
             raise PermanentBindError(
@@ -869,11 +875,9 @@ def bind_execution(
                     f"layer ingests activation scale {quant.act_scale} but "
                     f"the wire emits {out_q.act_scale} — streaming needs a "
                     "uniform per-layer scale chain")
-            return make_sparse_conv(layout, gm, bm=spec.bm, weight=w,
-                                    bias=bias, relu=relu,
-                                    implicit=implicit_for(keys, layout),
-                                    quant=quant, out_quant=out_q,
-                                    activation_dsb=spec.activation_dsb)
+            return bind_conv(keys, layout, gm, weight=w, bias=bias,
+                             relu=relu, quant=quant, out_quant=out_q,
+                             activation_dsb=spec.activation_dsb)
     else:
         if quant_spec is not None and not spec.quantized:
             raise PermanentBindError(
@@ -894,21 +898,14 @@ def bind_execution(
             if spec.trainable:
                 # no prepack: the conv re-packs the caller's (traced)
                 # weight every call, so mid-epoch updates are never stale
-                return make_sparse_conv(layout, gm, bm=spec.bm,
-                                        implicit=implicit_for(keys, layout),
-                                        trainable=True)
-            return make_sparse_conv(layout, gm, bm=spec.bm,
-                                    weight=leaf if spec.quantized else w,
-                                    implicit=implicit_for(keys, layout),
-                                    quant=qspec,
-                                    activation_dsb=spec.activation_dsb)
+                return bind_conv(keys, layout, gm, trainable=True)
+            return bind_conv(keys, layout, gm,
+                             weight=leaf if spec.quantized else w,
+                             quant=qspec, activation_dsb=spec.activation_dsb)
 
     table, plans, layouts, gms, bound = _bind_conv_layers(
         tree, specs, group_masks, spec.n_cu, spec.packed, weight_of,
         bind_one)
-    paths = {keys: "dense" if fn is None
-             else "implicit" if fn.implicit else "materializing"
-             for keys, fn in table.items()}
     return SparseConvExec(table=table, plans=plans, n_cu=spec.n_cu,
                           layouts=layouts, group_masks_np=gms,
                           quantized=spec.quantized, folded=spec.folded,
@@ -918,7 +915,7 @@ def bind_execution(
                           bound_weights=None if spec.trainable else bound,
                           implicit=_resolve_exec_implicit(spec.implicit,
                                                           layouts),
-                          bm=spec.bm, spec=spec, paths=paths)
+                          bm=spec.bm, spec=spec)
 
 
 def build_sparse_execution(

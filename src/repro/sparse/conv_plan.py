@@ -425,8 +425,9 @@ def implicit_m_block(layout: ConvGemmLayout, h: int, w: int, stride: int,
     M-block fits the ``bm`` cap, or the kernel's padded VMEM working set
     (:func:`repro.kernels.implicit_conv.window_vmem_bytes`, at operand
     width ``itemsize``) exceeds :data:`~repro.kernels.implicit_conv.
-    SLAB_VMEM_BUDGET`. The one decision point: ``bind_execution`` records
-    it per layer at bind time and the bound conv runs the same rule."""
+    SLAB_VMEM_BUDGET`. The one route rule: :func:`make_sparse_conv`
+    applies it once per input geometry (at bind time where the layer's
+    geometry is given) and :func:`conv_hbm_bytes` prices by it."""
     from ..kernels import implicit_conv as IC
     from ..kernels.conv_lowering import conv_out_size
 
@@ -458,11 +459,14 @@ def conv_hbm_bytes(layout: ConvGemmLayout, group_mask, batch: int, h: int,
     ``(M̂, n_packed)`` output. (A lower bound — XLA's im2col/pack
     intermediates add more unless fully fused.)
 
-    Implicit: stream one ``(rows, cols, cpk)`` activation *window* slab
-    (the double-buffered DMA granule — just the input pixels the
-    M-block reads, not the whole padded image) + one weight tile per
-    live grid step and write the output — the patch matrix never
-    exists.
+    Implicit (the layers :func:`implicit_m_block` routes there, others
+    are priced as materializing): read the activation once and write
+    its padded copy, channels padded to whole 128-lane groups, then
+    stream one window DMA — ``rows × dma_cols`` pixels of a whole
+    128-lane channel group — + one weight tile per live grid step and
+    write the output; the patch matrix never exists. The activation
+    terms are :func:`repro.kernels.implicit_conv.implicit_hbm_bytes`,
+    the kernel's own window and padding formulas.
 
     ``operand_bytes`` prices the *operand* traffic (activations /
     patches / weights) separately from the f32 output write
@@ -479,27 +483,27 @@ def conv_hbm_bytes(layout: ConvGemmLayout, group_mask, batch: int, h: int,
     contract still paid for).
     """
     from ..kernels.conv_lowering import conv_out_size
-    from ..kernels.implicit_conv import choose_m_block, window_shape
+    from ..kernels.implicit_conv import implicit_hbm_bytes
 
     ob = dtype_bytes if operand_bytes is None else operand_bytes
     ob_out = dtype_bytes if out_bytes is None else out_bytes
-    geo = layout.implicit_geometry()
     kx, ky, cin, cout = layout.spec.shape
     ho, wo = conv_out_size(h, kx, stride, padding), conv_out_size(w, ky, stride, padding)
     plan = layout.plan(group_mask)
     live = int(plan.cnt.sum())
     bk, bn = layout.block
+    mbk = (implicit_m_block(layout, h, w, stride, padding, ob, bm)
+           if implicit else None)
     mb, bm_eff = conv_m_blocks(ho, wo, batch, bm=bm,
-                               implicit=implicit and geo is not None)
+                               implicit=mbk is not None)
     steps = mb * live
     w_bytes = steps * bk * bn * ob
     out_write = mb * bm_eff * layout.n_packed * ob_out
-    mbk = (choose_m_block(ho, wo, cap=128 if bm == "auto" else int(bm))
-           if implicit and geo is not None else None)
     if mbk is not None:
-        rows, cols = window_shape(mbk, kx, ky, stride)
-        slab = rows * cols * geo["cpk"] * ob
-        return steps * slab + w_bytes + out_write
+        c_packed = layout.tiles[0] * layout.implicit_geometry()["cpk"]
+        ingest, window = implicit_hbm_bytes(batch, h, w, cin, c_packed, kx,
+                                            ky, stride, padding, mbk, ob)
+        return ingest + steps * window + w_bytes + out_write
     x_bytes = batch * h * w * cin * ob
     patches = mb * bm_eff * layout.k_packed * ob               # write once
     patch_reads = steps * bm_eff * bk * ob                     # kernel DMA
@@ -514,7 +518,8 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
                      quant=None,
                      out_quant=None,
                      activation_dsb: bool = False,
-                     trainable: bool = False):
+                     trainable: bool = False,
+                     geometry: Optional[Tuple[int, int, int, str]] = None):
     """Bind a Pallas block-sparse kernel to one conv layer's plan.
 
     Returns ``conv(x, w=None, stride=1, padding="SAME") -> (B, Ho, Wo, cout)``
@@ -531,12 +536,18 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
         materialized in HBM. A geometry :func:`implicit_m_block` rejects
         (no M-block fits, or the padded window working set exceeds
         :data:`implicit_conv.SLAB_VMEM_BUDGET`) runs the materializing
-        path; ``bind_execution`` makes that choice per layer at bind
-        time, from the config's shapes, and reports it.
+        path instead.
         Forward-only (the materializing non-epilogue path keeps its VJP).
       - ``False``: the materializing im2col + ``block_sparse_matmul``
         path — the parity oracle, and the only path for
         :class:`TileConvGemmLayout` (its K axis is tap-major).
+
+    ``geometry``: ``(h, w, stride, padding)`` of the layer's input —
+    the route is then chosen here, once: the implicit kernel's M-block
+    (or the materializing path), ``conv.implicit`` says which, and a
+    call at any other geometry raises ``ValueError``. Without it (a conv
+    called at several input sizes) the same rule picks the route on the
+    first call at each geometry and keeps it.
 
     ``bm``: M-blocking. ``"auto"`` (default) adapts to the layer —
     whole-output-row blocks for the implicit kernel, ``ceil8(B·Ho·Wo)``
@@ -575,12 +586,13 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
     implicit kernel reduces each DMA'd activation window to an
     any-nonzero flag and skips the gather+MXU pass when the int8 code
     block is all-zero (post-ReLU zeros are exact codes, so the skip is
-    bit-exact at every density). Best-effort: calls that fall back to
-    the materializing path run without the skip, identically exact.
+    bit-exact at every density). The skip exists only in the implicit
+    kernel, so a geometry routed to the materializing path raises
+    ``ValueError`` (at bind time with ``geometry``, else at the call).
     ``conv.skip_counts(x, ...)`` runs the same bound kernel with the
     skip counter enabled and returns ``(y, stats)`` where ``stats`` is
     ``{"skipped_steps", "live_steps"}`` (``None`` on the materializing
-    fallback) — the measured ``dsb_skip_frac`` source.
+    path) — the measured ``dsb_skip_frac`` source.
 
     ``trainable=True`` makes the closure differentiable in **both**
     arguments via a ``jax.custom_vjp``: ``conv(x, w, ...)`` re-packs the
@@ -600,7 +612,8 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
 
     ``conv.plan`` / ``conv.layout`` / ``conv.group_mask`` /
     ``conv.implicit`` / ``conv.quant`` / ``conv.trainable`` expose the
-    dispatch accounting.
+    dispatch accounting; ``conv.m_block(h, w, stride, padding)`` the
+    route at an input geometry (``None`` = materializing).
     """
     from ..kernels import ops
     from ..kernels import implicit_conv as IC
@@ -677,19 +690,48 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
     else:
         w_packed, bound_hw = None, None
 
+    # operand width the route's VMEM budget is checked at: int8 codes
+    # under quant, else f32 (the widest operand the kernels take)
+    itemsize = 1 if quant is not None else 4
+    routes: dict = {}     # (h, w, stride, padding) -> MBlock | None
+
+    def _choose(h, w, stride, padding):
+        mbk = (implicit_m_block(layout, h, w, stride, padding, itemsize, bm)
+               if use_implicit else None)
+        if activation_dsb and mbk is None:
+            raise ValueError(
+                f"activation_dsb lives in the implicit kernel, but input "
+                f"geometry {(h, w, stride, padding)} does not fit its "
+                "M-block cap and VMEM window budget")
+        return mbk
+
+    if geometry is not None:
+        geometry = (int(geometry[0]), int(geometry[1]), int(geometry[2]),
+                    geometry[3])
+        routes[geometry] = _choose(*geometry)
+
+    def _route(h, w, stride=1, padding="SAME"):
+        key = (int(h), int(w), int(stride), padding)
+        if key not in routes:
+            if geometry is not None:
+                raise ValueError(
+                    f"conv bound for input geometry (h, w, stride, padding) "
+                    f"= {geometry}, called at {key} — rebind for this "
+                    "geometry")
+            routes[key] = _choose(*key)
+        return routes[key]
+
     def _run(x, wp, kx, ky, stride, padding, count_skips=False):
         """Forward with an already-packed weight ``wp`` (concrete or
-        traced): the bound plan's implicit kernel when it fits, else the
-        materializing path. With ``count_skips`` returns ``(y, stats)``
-        — the kernel-side skip counter summed into
+        traced) on the route of ``x``'s geometry: the implicit kernel at
+        its M-block, or the materializing path. With ``count_skips``
+        returns ``(y, stats)`` — the kernel-side skip counter summed into
         ``{"skipped_steps", "live_steps"}``, ``None`` off the implicit
         path."""
         B, H, W, C = x.shape
         ho = conv_out_size(H, kx, stride, padding)
         wo = conv_out_size(W, ky, stride, padding)
-        mbk = (implicit_m_block(layout, H, W, stride, padding,
-                                x.dtype.itemsize, bm)
-               if use_implicit else None)
+        mbk = _route(H, W, stride, padding)
         if mbk is not None:
             cpk, slot = geo["cpk"], geo["slot"]
             xp = IC.pad_input(x, kx, ky, stride, padding, mbk,
@@ -799,8 +841,8 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
         """Run the bound conv with the kernel-side skip counter on:
         ``(y, {"skipped_steps", "live_steps"})`` — ``y`` identical to
         ``conv(x, ...)`` (the counter is a second output, not a
-        different kernel), stats ``None`` when the call fell back to the
-        materializing path. Counts actual skips, so a bind without
+        different kernel), stats ``None`` when the geometry's route is
+        the materializing path. Counts actual skips, so a bind without
         ``activation_dsb`` reports 0."""
         if w_packed is None:
             raise ValueError("no weight bound at build time — "
@@ -814,7 +856,10 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
     conv.layout = layout
     conv.group_mask = gm
     conv.prebound = weight is not None
-    conv.implicit = use_implicit
+    # the route taken where the geometry is bound, else the request
+    conv.implicit = (use_implicit if geometry is None
+                     else routes[geometry] is not None)
+    conv.m_block = _route
     conv.bm = bm
     conv.quant = quant
     conv.out_quant = out_quant

@@ -62,7 +62,10 @@ def test_phases_pass_and_last_line_names_the_device(smoke, monkeypatch,
     assert len(routes) == 2          # one per phase, every conv implicit
     assert all(f"'implicit': {n}, 'materializing': 0, 'dense': 0" in ln
                for ln in routes)
-    assert sum("max abs error vs reference" in ln for ln in lines) == 2
+    # f32 reference in phase a; integer twin and dense wire reference in
+    # phase b; then the control the integer bound must catch
+    assert sum("max abs error vs" in ln for ln in lines) == 3
+    assert sum(ln.startswith("[chip_smoke] control") for ln in lines) == 1
 
 
 def test_failed_check_exits_nonzero_without_a_result(smoke, monkeypatch,

@@ -264,8 +264,9 @@ def _pruned_tiny_resnet(target=0.5, n_cu=4):
 
 def test_implicit_exec_end_to_end_matches_materializing():
     """build_sparse_execution(implicit=True) == implicit=False == dense on
-    a HAPM-pruned net, with identical schedule accounting and strictly
-    fewer analytic HBM bytes (kernel layers bound on both paths)."""
+    a HAPM-pruned net, with identical schedule accounting, every layer on
+    the requested route, and each exec's analytic HBM bytes priced on
+    that route (kernel layers bound on both paths)."""
     n_cu = 4
     cfg, pruned, state, specs, st = _pruned_tiny_resnet(0.5, n_cu)
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
@@ -282,8 +283,12 @@ def test_implicit_exec_end_to_end_matches_materializing():
     assert execs[True].implicit and not execs[False].implicit
     assert (execs[True].schedule_step_counts()
             == execs[False].schedule_step_counts())
+    n_convs = len(cnn.conv_layer_order(cfg))
+    assert execs[True].report(cfg)["layers_implicit"] == n_convs
+    assert execs[False].report(cfg)["layers_materializing"] == n_convs
     assert (execs[True].hbm_bytes(cfg, batch=1)
-            < execs[False].hbm_bytes(cfg, batch=1, bm=128))
+            == execs[False].hbm_bytes(cfg, batch=1, implicit=True)
+            != execs[False].hbm_bytes(cfg, batch=1))
     # adaptive bm engages on the 8x8 tail layers
     bms = execs[True].bm_effective(cfg, batch=1)
     assert bms["s1b0/conv2/w"] == 64 and bms["conv0/w"] == 128
@@ -293,14 +298,31 @@ def test_implicit_exec_end_to_end_matches_materializing():
 
 
 def test_conv_hbm_bytes_contract():
-    """The analytic byte counts encode the contract change: the implicit
-    path never pays the patch-matrix write, the materializing path does."""
+    """The analytic byte counts encode the contract change, term by term
+    as the chip moves the data: the implicit path never pays the
+    patch-matrix write but DMAs whole 128-lane windows from a padded
+    copy of the activation; the materializing path writes and re-reads
+    the patch matrix."""
     spec = fpga_conv_groups((3, 3, 16, 32), 12)
     layout = conv_gemm_layout(spec, packed=True)
     gm = np.ones(spec.num_groups, np.float32)
     imp = conv_hbm_bytes(layout, gm, 1, 16, 16, implicit=True)
     mat = conv_hbm_bytes(layout, gm, 1, 16, 16, implicit=False, bm=128)
-    assert 0 < imp < mat
-    # pruning everything leaves only the output write on both paths
+    f = 4
+    steps = 2 * 2                 # 2 M-blocks x 2 live K-tiles (cpk 8)
+    x_read = 16 * 16 * 16 * f
+    out_write = 2 * 128 * 128 * f
+    # implicit: the padded copy is 18 rows x 24 columns (whole 8-row
+    # tiles for the DMA) x 128 lanes; a live step moves one 10 x 24
+    # window of the 128-lane group and one 128x128 weight tile
+    ingest = x_read + 18 * 24 * 128 * f
+    assert imp == (ingest + steps * (10 * 24 * 128 * f + 128 * 128 * f)
+                   + out_write)
+    # materializing: the (256, 256) patch matrix is written once, and a
+    # live step reads one 128x128 patch tile and one weight tile
+    assert mat == (x_read + 256 * 256 * f + steps * 2 * 128 * 128 * f
+                   + out_write)
+    # pruning everything leaves the ingest and the output write
     gm0 = np.zeros(spec.num_groups, np.float32)
-    assert conv_hbm_bytes(layout, gm0, 1, 16, 16, implicit=True) < imp
+    assert conv_hbm_bytes(layout, gm0, 1, 16, 16,
+                          implicit=True) == ingest + out_write

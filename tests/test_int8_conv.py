@@ -255,8 +255,9 @@ def test_quantized_folded_inference_calibrated():
 
 
 def test_conv_hbm_bytes_int8_operand_pricing():
-    """operand_bytes=1 shrinks exactly the operand terms: slabs, patch
-    matrix, patch reads and weight tiles — never the f32 output write."""
+    """operand_bytes=1 shrinks exactly the operand terms: activation
+    ingest, window slabs, patch matrix, patch reads and weight tiles —
+    never the f32 output write."""
     spec = fpga_conv_groups((3, 3, 16, 32), 12)
     layout = conv_gemm_layout(spec, packed=True)
     gm = np.ones(spec.num_groups, np.float32)
@@ -269,9 +270,12 @@ def test_conv_hbm_bytes_int8_operand_pricing():
         out_only_q = conv_hbm_bytes(layout, np.zeros_like(gm), 1, 16, 16,
                                     implicit=implicit, bm=128, operand_bytes=1)
         if implicit:
-            assert out_only == out_only_q            # pure f32 output write
+            # zero density: the activation ingest (a quarter as many bytes
+            # as int8 codes) plus the f32 output write of 2 M-blocks
+            ingest_q = (out_only - out_only_q) // 3
+            assert out_only_q - ingest_q == 2 * 128 * 128 * 4
             # int8 operands are exactly a quarter of the f32 operand bytes
-            assert (q - out_only) * 4 == f32 - out_only
+            assert (q - out_only_q) * 4 == f32 - out_only
         else:
             # materializing zero-density still reads x and writes patches
             assert q < f32

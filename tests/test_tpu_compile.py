@@ -9,6 +9,7 @@ runs in the test's own process; the persistent compilation cache is off
 around these tests (entries for a described chip cannot be read back).
 """
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -34,8 +35,11 @@ def no_compile_cache():
 
 
 @pytest.fixture(scope="module")
-def topo(no_compile_cache):
+def topo(no_compile_cache, tmp_path_factory):
     from jax.experimental import topologies
+    # libtpu otherwise writes its logs to /tmp ("disabled" does not stop it)
+    os.environ.setdefault("TPU_LOG_DIR",
+                          str(tmp_path_factory.mktemp("tpu_logs")))
     try:
         return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
