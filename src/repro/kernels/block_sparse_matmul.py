@@ -145,7 +145,9 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs, acc_dtype, has_scale,
         o_ref[...] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
+# inline: called inside another jitted function, the kernel takes that
+# function's name in the compiled program (``ops.named_jit``)
+@functools.partial(jax.jit, inline=True,
                    static_argnames=("block", "bm", "relu", "interpret"))
 def block_sparse_matmul(
     x: jnp.ndarray,            # (M, K) f32/bf16, or int8 codes

@@ -97,6 +97,7 @@ from .block_sparse_matmul import (append_epilogue_inputs, flush_epilogue,
                                   mxu_precision, quantized_contract,
                                   unpack_epilogue_refs)
 from .conv_lowering import same_pads
+from .ops import named_jit
 
 # Largest padded window working set (bytes, window_vmem_bytes) the
 # implicit kernel will hold in VMEM. Above this the layer uses the
@@ -366,9 +367,13 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
         o_ref[...] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "kx", "ky", "stride", "mb", "block", "cpk", "slot", "relu",
-    "activation_dsb", "count_skips", "interpret"))
+STATIC_ARGS = ("kx", "ky", "stride", "mb", "block", "cpk", "slot", "relu",
+               "activation_dsb", "count_skips", "interpret")
+
+
+# inline: called inside another jitted function, the kernel takes that
+# function's name in the compiled program (see :func:`named`)
+@functools.partial(jax.jit, static_argnames=STATIC_ARGS, inline=True)
 def implicit_block_sparse_conv(
     xp: jnp.ndarray,           # (B, Hp, Wp, nKb*cpk) pad_input() output
     w: jnp.ndarray,            # (nKb*bk, nNb*bn) packed weight (f32/bf16/int8)
@@ -487,3 +492,12 @@ def implicit_block_sparse_conv(
         out, skips = res
         return out, skips.reshape(B * mb.bpi, nNb)
     return res
+
+
+@functools.lru_cache(maxsize=None)
+def named(name: str):
+    """:func:`implicit_block_sparse_conv`, same kernel body, jitted under
+    the name ``name`` (:func:`repro.kernels.ops.named_jit`): the compiled
+    program and a device trace then call this kernel ``name.<n>``."""
+    return named_jit(implicit_block_sparse_conv, name,
+                     static_argnames=STATIC_ARGS)

@@ -32,6 +32,19 @@ def _interpret() -> bool:
         f"CPU; JAX's default backend is {backend!r}")
 
 
+def named_jit(fn, name: str, **jit_kwargs):
+    """``jax.jit(fn)`` under the function name ``name``. XLA names an op
+    after the innermost jitted function that holds it, so a kernel called
+    through this is ``name`` (``name.<n>``) in the compiled program and
+    in a device trace."""
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call, **jit_kwargs)
+
+
 def _pad_rows(x2d: jnp.ndarray, bm: int):
     M = x2d.shape[0]
     Mp = -(-M // bm) * bm

@@ -33,9 +33,15 @@ or downgrades oversized requests. :meth:`CnnServer.snapshot` persists
 the mask/fingerprint state through :mod:`repro.train.checkpoint` so a
 restarted server warms its exec cache without re-deriving HAPM masks.
 
+**Spans** (:mod:`repro.spans`): a server handed a
+:class:`~repro.spans.Spans` records its install, each bind and
+each conv's bind, each bucket's warm-up, and three steps of every
+``infer`` chunk (prepare, dispatch, collect) on the clock a device trace
+is anchored to; the default records nothing.
+
 ``python -m repro.launch.serve_cnn --smoke`` runs the driver standalone;
-:mod:`benchmarks.bench_serving_cnn` measures it (``--chaos`` for the
-fault-injection scenario).
+``chipbench/run.py`` measures it on a TPU (open-loop and closed-loop
+traffic on the wall clock, with a device trace of the window).
 """
 from __future__ import annotations
 
@@ -54,6 +60,7 @@ import numpy as np
 
 from ..models import cnn
 from ..sparse.conv_plan import mask_fingerprint
+from ..spans import NO_SPANS
 from .exec_cache import (DEFAULT_BUCKETS, BucketBatcher, CacheEntry,
                          ExecCache, arch_fingerprint, bucket_for)
 from .resilience import (DENSE_RUNG, DeadlineExceeded, FaultPlan,
@@ -102,7 +109,9 @@ class CnnServer:
     submit f32 frames and receive f32 logits.
 
     ``policy`` (a :class:`~repro.launch.resilience.ServePolicy`) controls
-    the recovery machinery; ``faults`` installs a
+    the recovery machinery; ``spans`` (a
+    :class:`~repro.spans.Spans`; default none) records the host
+    steps of install, bind, warm-up and ``infer``; ``faults`` installs a
     :class:`~repro.launch.resilience.FaultPlan` whose hooks fire inside
     the real bind/forward/mask-update paths (chaos testing);
     ``snapshot_dir`` warm-starts the mask/fingerprint state from a prior
@@ -124,7 +133,9 @@ class CnnServer:
                  cache_capacity: int = 16,
                  policy: Optional[ServePolicy] = None,
                  faults: Optional[FaultPlan] = None,
-                 snapshot_dir: Optional[str] = None):
+                 snapshot_dir: Optional[str] = None,
+                 spans=NO_SPANS):
+        self.spans = spans
         self.spec = cnn.ExecSpec() if spec is None else spec
         self.policy = ServePolicy() if policy is None else policy
         self.faults = faults
@@ -145,40 +156,43 @@ class CnnServer:
     # -- model / fingerprint state ------------------------------------
     def _install(self, params, state, snapshot_dir: Optional[str] = None
                  ) -> None:
-        self.params, self.state = params, state
-        if self.spec.folded:
-            self._tree = cnn.fold_batchnorm(params, state, self.cfg)
-            conv_tree = {k: v for k, v in self._tree.items() if k != "fc"}
-            derive = lambda: cnn.derive_group_masks(conv_tree, self.spec.n_cu)
-        else:
-            self._tree = params
-            derive = lambda: cnn.derive_group_masks(
-                params, self.spec.n_cu, quantized=self.spec.quantized)
-        self.arch_fp = arch_fingerprint(self.cfg, params)
-        masks = fp = None
-        if snapshot_dir is not None:
-            loaded = self._snapshot_masks(snapshot_dir)
-            if loaded is not None:
-                masks, fp = loaded
-        if masks is None:
-            masks = derive()
-            fp = mask_fingerprint(masks)
-            if self.faults is not None:
-                # the fault hook models corruption *after* derivation (a
-                # flipped bit in the mask buffer / a torn update); the
-                # fingerprint cross-check is the real detection path
-                seen = self.faults.on_masks(masks)
-                if seen is not masks and mask_fingerprint(seen) != fp:
-                    if self.policy.validate_masks:
-                        self.resilience["mask_repairs"] += 1
-                        logger.warning(
-                            "mask update failed fingerprint validation — "
-                            "repaired from the freshly-derived pattern")
-                    else:
-                        masks, fp = seen, mask_fingerprint(seen)
-        self.group_masks = masks
-        self.mask_fp = fp
-        self._rung_masks: Dict[bool, tuple] = {}
+        with self.spans("install"):
+            self.params, self.state = params, state
+            if self.spec.folded:
+                self._tree = cnn.fold_batchnorm(params, state, self.cfg)
+                conv_tree = {k: v for k, v in self._tree.items()
+                             if k != "fc"}
+                derive = lambda: cnn.derive_group_masks(conv_tree,
+                                                        self.spec.n_cu)
+            else:
+                self._tree = params
+                derive = lambda: cnn.derive_group_masks(
+                    params, self.spec.n_cu, quantized=self.spec.quantized)
+            self.arch_fp = arch_fingerprint(self.cfg, params)
+            masks = fp = None
+            if snapshot_dir is not None:
+                loaded = self._snapshot_masks(snapshot_dir)
+                if loaded is not None:
+                    masks, fp = loaded
+            if masks is None:
+                masks = derive()
+                fp = mask_fingerprint(masks)
+                if self.faults is not None:
+                    # the fault hook models corruption *after* derivation (a
+                    # flipped bit in the mask buffer / a torn update); the
+                    # fingerprint cross-check is the real detection path
+                    seen = self.faults.on_masks(masks)
+                    if seen is not masks and mask_fingerprint(seen) != fp:
+                        if self.policy.validate_masks:
+                            self.resilience["mask_repairs"] += 1
+                            logger.warning(
+                                "mask update failed fingerprint validation — "
+                                "repaired from the freshly-derived pattern")
+                        else:
+                            masks, fp = seen, mask_fingerprint(seen)
+            self.group_masks = masks
+            self.mask_fp = fp
+            self._rung_masks: Dict[bool, tuple] = {}
 
     @property
     def bind_key(self) -> tuple:
@@ -324,8 +338,9 @@ class CnnServer:
         def do_bind():
             if self.faults is not None:
                 self.faults.on_bind(rung)
-            return cnn.bind_execution(self._tree, self.cfg, spec=rung,
-                                      group_masks=masks)
+            with self.spans("bind"):
+                return cnn.bind_execution(self._tree, self.cfg, spec=rung,
+                                          group_masks=masks, spans=self.spans)
 
         def on_retry(attempt):
             self.resilience["bind_retries"] += 1
@@ -378,8 +393,9 @@ class CnnServer:
         h = self.cfg.image_size
         rung = self._rungs[self._level]
         for b in (self.buckets if buckets is None else buckets):
-            entry = self._entry_for(rung, b)
-            np.asarray(entry.fn(jnp.zeros((b, h, h, 3), jnp.float32)))
+            with self.spans("warmup.bucket"):
+                entry = self._entry_for(rung, b)
+                np.asarray(entry.fn(jnp.zeros((b, h, h, 3), jnp.float32)))
 
     # -- request path --------------------------------------------------
     def _validate_images(self, images) -> None:
@@ -440,12 +456,14 @@ class CnnServer:
         del self.degrade_log[:-50]
         logger.info("degradation ladder: %s", step)
 
-    def _run_chunk(self, x, bucket: int, level: int):
+    def _run_chunk(self, x, bucket: int, level: int, t: int):
         """One padded chunk through the ladder: bind (with retries) at
         the current rung, run, guard the output; on failure quarantine /
-        step down and re-run. Returns ``(logits, level)`` — the rung the
-        answer actually ran under (bit-exact for that rung's spec)."""
-        pol = self.policy
+        step down and re-run. Returns ``(logits, level, t)`` — the rung
+        the answer actually ran under (bit-exact for that rung's spec),
+        and the start of the chunk's ``infer.collect`` span. ``t`` is the
+        start of its ``infer.prepare`` span."""
+        pol, spans = self.policy, self.spans
         while True:
             rung = self._rungs[level]
             if rung is not None and self.cache.is_quarantined(
@@ -461,10 +479,13 @@ class CnnServer:
                 level = self._degrade(level, f"bind failed after retries "
                                              f"({type(e).__name__})")
                 continue
+            t = spans.mark("infer.prepare", t)
             y = entry.fn(x)
             if self.faults is not None:
                 y = self.faults.on_output(y)
+            t = spans.mark("infer.dispatch", t)
             if pol.check_finite and not bool(np.isfinite(np.asarray(y)).all()):
+                t = spans.mark("infer.collect", t)
                 self.resilience["nonfinite_caught"] += 1
                 if rung is not None:
                     self.cache.quarantine(self._key_for(rung))
@@ -476,7 +497,7 @@ class CnnServer:
                 level = self._degrade(level, "non-finite output (entry "
                                              "quarantined)")
                 continue
-            return y, level
+            return y, level, t
 
     def infer(self, images, *, deadline_s: Optional[float] = None
               ) -> jnp.ndarray:
@@ -495,6 +516,8 @@ class CnnServer:
         (``policy.max_request_images``): shed with
         :class:`OverloadError` or served one ladder rung down, per
         ``policy.overload_action``."""
+        spans = self.spans
+        t = spans.now()                 # the first chunk's infer.prepare
         images = jnp.asarray(images)
         self._validate_images(images)
         pol = self.policy
@@ -527,6 +550,8 @@ class CnnServer:
         out = []
         max_b = self.buckets[-1]
         for lo in range(0, n, max_b):
+            if lo:
+                t = spans.now()
             chunk = images[lo:lo + max_b]
             bucket = bucket_for(chunk.shape[0], self.buckets)
             if deadline_s is not None:
@@ -544,14 +569,18 @@ class CnnServer:
             else:
                 x = chunk
             t1 = time.monotonic()
-            y, level = self._run_chunk(x, bucket, level)
+            y, level, t = self._run_chunk(x, bucket, level, t)
             dt = time.monotonic() - t1
             ema = self._svc_ema.get(bucket)
             self._svc_ema[bucket] = dt if ema is None else 0.7 * ema + 0.3 * dt
             out.append(y[:chunk.shape[0]])
+            if lo + max_b < n:
+                spans.mark("infer.collect", t)
         self.last_request_level = level
         self._note_clean_request(start_level, level, overload_downgraded)
-        return out[0] if len(out) == 1 else jnp.concatenate(out)
+        y = out[0] if len(out) == 1 else jnp.concatenate(out)
+        spans.mark("infer.collect", t)
+        return y
 
     def report(self, batch: int = 1, **kw) -> Dict[str, Any]:
         """The bind's :meth:`SparseConvExec.report` accounting (per-image

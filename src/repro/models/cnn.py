@@ -22,6 +22,7 @@ import numpy as np
 from ..core import quant as Q
 from ..core.groups import fpga_conv_groups
 from ..accel.cycle_model import ConvLayerDims
+from ..spans import NO_SPANS
 
 PyTree = Any
 
@@ -678,15 +679,24 @@ class SparseConvExec:
         return out
 
 
+def conv_kernel_name(keys: tuple) -> str:
+    """The name of a bound conv's kernel in the compiled program and in a
+    device trace, from its param path: ``("s0b1", "conv2", "w")`` ->
+    ``conv_s0b1_conv2``."""
+    return "conv_" + "_".join(keys[:-1])
+
+
 def _bind_conv_layers(tree: PyTree, specs: PyTree, group_masks: PyTree,
-                      n_cu: int, packed: bool, weight_of, bind_one):
+                      n_cu: int, packed: bool, weight_of, bind_one,
+                      spans=NO_SPANS):
     """Shared bind loop of the two exec builders: walk the conv weights of
     ``tree``, derive each layer's (spec, group mask, layout, plan), and let
     ``bind_one(keys, w, layout, gm, plan, leaf)`` produce the table entry.
     ``weight_of(leaf)`` is the weight the mask derivation should score
     (e.g. the Q2.5-quantized view); ``leaf`` is the raw array for binders
     that quantize themselves (a calibrated QuantSpec must see unclipped
-    values — pre-quantizing onto the static grid would double-quantize)."""
+    values — pre-quantizing onto the static grid would double-quantize).
+    Each layer is one ``bind.layer.<kernel name>`` span of ``spans``."""
     from ..sparse.conv_plan import conv_gemm_layout
 
     if specs is None:
@@ -701,27 +711,28 @@ def _bind_conv_layers(tree: PyTree, specs: PyTree, group_masks: PyTree,
                 "host-side numpy) but got a tracer — build the "
                 "SparseConvExec outside jit and pass it via sparse=exec")
         keys = tuple(getattr(k, "key", str(k)) for k in path)
-        w = weight_of(leaf)
-        spec = _get_path(specs, keys)
-        if group_masks is None:
-            gm = None
-        elif (isinstance(group_masks, dict)
-              and all(isinstance(k, tuple) for k in group_masks)):
-            # flat {path-tuple: mask} form (exec.group_masks_np /
-            # derive_group_masks) alongside the params-shaped pytree form
-            gm = group_masks.get(keys)
-        else:
-            gm = _get_path(group_masks, keys)
-        if gm is None:
-            # tile specs score the 2-D im2col matrix, not the HWIO tensor
-            w2 = w.reshape(spec.shape) if w.shape != spec.shape else w
-            gm = np.asarray(spec.group_scores(w2)) > 0
-        gm = np.asarray(gm, np.float32)
-        layout = conv_gemm_layout(spec, packed=packed)
-        plan = layout.plan(gm)
-        plans[keys], layouts[keys], gms[keys] = plan, layout, gm
-        bound[keys] = leaf
-        table[keys] = bind_one(keys, w, layout, gm, plan, leaf)
+        with spans("bind.layer." + conv_kernel_name(keys)):
+            w = weight_of(leaf)
+            spec = _get_path(specs, keys)
+            if group_masks is None:
+                gm = None
+            elif (isinstance(group_masks, dict)
+                  and all(isinstance(k, tuple) for k in group_masks)):
+                # flat {path-tuple: mask} form (exec.group_masks_np /
+                # derive_group_masks) alongside the params-shaped pytree form
+                gm = group_masks.get(keys)
+            else:
+                gm = _get_path(group_masks, keys)
+            if gm is None:
+                # tile specs score the 2-D im2col matrix, not the HWIO tensor
+                w2 = w.reshape(spec.shape) if w.shape != spec.shape else w
+                gm = np.asarray(spec.group_scores(w2)) > 0
+            gm = np.asarray(gm, np.float32)
+            layout = conv_gemm_layout(spec, packed=packed)
+            plan = layout.plan(gm)
+            plans[keys], layouts[keys], gms[keys] = plan, layout, gm
+            bound[keys] = leaf
+            table[keys] = bind_one(keys, w, layout, gm, plan, leaf)
     return table, plans, layouts, gms, bound
 
 
@@ -774,6 +785,7 @@ def bind_execution(
     group_masks: PyTree = None,
     quant_spec: Any = None,
     bind_kernels: bool = True,
+    spans=NO_SPANS,
 ) -> SparseConvExec:
     """The one bind entry point: every conv layer of ``params`` onto the
     Pallas block-sparse kernels under the execution contract ``spec``
@@ -822,6 +834,11 @@ def bind_execution(
     table entry ``None`` — no kernel closures, no weight packing (what
     ``accel.simulator`` prices).
 
+    Each bound conv's kernel is named :func:`conv_kernel_name` of its
+    path in the compiled program and in a device trace. ``spans`` (a
+    :class:`repro.spans.Spans`; default none) records each layer's
+    bind as a ``bind.layer.<kernel name>`` span.
+
     Host-side: requires concrete weights (plans are numpy; raises under
     jit — prebuild and pass the exec in); the bound kernels are jitted.
     The exec is pinned to these exact weight arrays — ``apply`` rejects a
@@ -845,7 +862,8 @@ def bind_execution(
         try:
             return make_sparse_conv(layout, gm, bm=spec.bm,
                                     implicit=spec.implicit,
-                                    geometry=geometry.get(keys), **kw)
+                                    geometry=geometry.get(keys),
+                                    name=conv_kernel_name(keys), **kw)
         except ValueError as e:
             raise PermanentBindError(f"{'/'.join(keys)}: {e}") from e
 
@@ -905,7 +923,7 @@ def bind_execution(
 
     table, plans, layouts, gms, bound = _bind_conv_layers(
         tree, specs, group_masks, spec.n_cu, spec.packed, weight_of,
-        bind_one)
+        bind_one, spans)
     return SparseConvExec(table=table, plans=plans, n_cu=spec.n_cu,
                           layouts=layouts, group_masks_np=gms,
                           quantized=spec.quantized, folded=spec.folded,

@@ -519,7 +519,8 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
                      out_quant=None,
                      activation_dsb: bool = False,
                      trainable: bool = False,
-                     geometry: Optional[Tuple[int, int, int, str]] = None):
+                     geometry: Optional[Tuple[int, int, int, str]] = None,
+                     name: str = "block_sparse_conv"):
     """Bind a Pallas block-sparse kernel to one conv layer's plan.
 
     Returns ``conv(x, w=None, stride=1, padding="SAME") -> (B, Ho, Wo, cout)``
@@ -610,6 +611,11 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
     view; this path runs the f32 kernels on whatever view the caller
     passes).
 
+    ``name``: the kernel's name in the compiled program and in a device
+    trace (``name.<n>``; ``bind_execution`` passes the layer path, e.g.
+    ``conv_s0b1_conv2``). It renames the jitted function that holds the
+    kernel, never the kernel body.
+
     ``conv.plan`` / ``conv.layout`` / ``conv.group_mask`` /
     ``conv.implicit`` / ``conv.quant`` / ``conv.trainable`` expose the
     dispatch accounting; ``conv.m_block(h, w, stride, padding)`` the
@@ -660,13 +666,14 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
     packed_out_scale = (None if out_quant is None else layout.pack_bias(
         jnp.full((layout.spec.shape[-1],), out_quant.act_scale, jnp.float32)))
     idx_dev, cnt_dev = jnp.asarray(plan.idx), jnp.asarray(plan.cnt)
+    implicit_kernel = IC.named(name)
     mms: dict = {}        # materializing kernels, keyed by effective bm
 
     def _materializing(bm_eff):
         if bm_eff not in mms:
-            mms[bm_eff] = ops.make_block_sparse_matmul(
+            mms[bm_eff] = ops.named_jit(ops.make_block_sparse_matmul(
                 plan, tm, bm=bm_eff, bias=packed_bias, relu=relu,
-                scale=packed_scale, out_scale=packed_out_scale)
+                scale=packed_scale, out_scale=packed_out_scale), name)
         return mms[bm_eff]
 
     gm_dev = jnp.asarray(gm, jnp.float32)
@@ -736,7 +743,7 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
             cpk, slot = geo["cpk"], geo["slot"]
             xp = IC.pad_input(x, kx, ky, stride, padding, mbk,
                               layout.tiles[0] * cpk)
-            res = IC.implicit_block_sparse_conv(
+            res = implicit_kernel(
                 xp, wp, idx_dev, cnt_dev, packed_bias, packed_scale,
                 packed_out_scale,
                 kx=kx, ky=ky, stride=stride, mb=mbk,

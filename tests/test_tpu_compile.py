@@ -131,3 +131,46 @@ def test_block_sparse_grad_weight_compiles(one_chip):
 def test_int8_matmul_compiles(one_chip):
     _compile(one_chip, int8_matmul, ((256, 384), jnp.int8),
              ((384, 256), jnp.int8), ((256,), jnp.float32))
+
+
+def _served_kernel_names(one_chip, monkeypatch, **spec):
+    """The layer paths of a tiny served network's convs, and the names of
+    the custom calls in its bucket-8 program compiled for a v5e."""
+    import re
+
+    from repro.kernels import ops
+    from repro.launch.serve_cnn import CnnServer
+    from repro.models import cnn
+
+    cfg = cnn.ResNetConfig(stages=(1, 1), widths=(8, 16), image_size=16)
+    params, state = cnn.init(jax.random.PRNGKey(0), cfg)
+    server = CnnServer(params, state, cfg, buckets=(8,), spec=cnn.ExecSpec(
+        n_cu=4, quantized=True, folded=True, dense_fallback=2.0, **spec))
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    entry = server._entry_for(server.rungs[0], 8)
+    x = jax.ShapeDtypeStruct((8, 16, 16, 3), jnp.float32, sharding=one_chip)
+    text = entry.fn.lower(x).compile().as_text()
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(", text)
+    want = sorted(cnn.conv_kernel_name(p)
+                  for p, _, _ in cnn.conv_layer_order(cfg))
+    return want, sorted(re.sub(r"\.\d+$", "", c) for c in calls)
+
+
+def test_served_forward_names_each_conv_kernel_by_its_layer(one_chip,
+                                                            monkeypatch):
+    """A bucket program of ``CnnServer`` compiled for a v5e: each conv's
+    Mosaic kernel is the custom call named by its layer path, the name a
+    device trace gives the op (``conv_s0b0_conv2.<n>``)."""
+    want, calls = _served_kernel_names(one_chip, monkeypatch, streamed=True)
+    assert calls == want
+
+
+def test_materializing_conv_kernels_are_named_by_their_layer(one_chip,
+                                                             monkeypatch):
+    """The same on the materializing kernel (``block_sparse_matmul``),
+    bound with ``implicit=False``: no custom call keeps a kernel's own
+    name."""
+    want, calls = _served_kernel_names(one_chip, monkeypatch, implicit=False)
+    assert [c for c in calls if c.startswith("conv_")] == want
+    assert not {"block_sparse_matmul", "implicit_block_sparse_conv"} & set(
+        calls)
