@@ -9,8 +9,8 @@ do that: kernel windows stream straight out of the input feature map
 while the DSB skips pruned groups. This kernel executes the same
 contract on the Pallas grid:
 
-- Grid is ``(B·bpi, nNb, max_nnz)`` — M-blocks × output tile columns ×
-  live K-tiles, exactly like :mod:`block_sparse_matmul`.
+- Grid is ``(B/ipb·bpi, nNb, max_nnz)`` — M-blocks × output tile
+  columns × live K-tiles, exactly like :mod:`block_sparse_matmul`.
 - The x operand is the **padded NHWC activation itself**, left in HBM
   (``memory_space=ANY``), its channels padded to whole 128-lane groups
   and its columns to whole 8-row tiles (the tiled HBM layout pads them
@@ -34,14 +34,18 @@ contract on the Pallas grid:
   transpose back, a cast to the operand dtype, and one MXU dot per live
   step. The tile is element-for-element the materializing tile, so the
   two paths feed the MXU identical operands.
-- M-blocking is **adaptive**: an M-block is ``block_oh`` whole output
-  rows (``bm = ceil8(block_oh·Wo) ≤ cap`` — a batch-1 4×4 tail runs at
-  ``bm=16`` instead of padding to 128), and when even one output row
-  exceeds the cap the row is split into ``spi`` **column segments** of
-  ``block_ow`` pixels, so wide-resolution inputs keep the implicit path
-  instead of falling back to the materializing oracle.
-  :func:`choose_m_block` returns the :class:`MBlock` geometry; blocks
-  never straddle images.
+- M-blocking is **adaptive**: an image's M-block is ``block_oh`` whole
+  output rows (``bm = ceil8(block_oh·Wo) ≤ cap`` — a batch-1 4×4 tail
+  runs at ``bm=16`` instead of padding to 128), and when even one output
+  row exceeds the cap the row is split into ``spi`` **column segments**
+  of ``block_ow`` pixels, so wide-resolution inputs keep the implicit
+  path instead of falling back to the materializing oracle. Where one
+  image's whole output fills one block (``bpi == 1`` and ``Ho·Wo = bm``),
+  a block **folds** ``ipb`` whole images — the largest divisor of the
+  batch with ``ipb·bm ≤ cap`` — so a 4×4 layer at batch 8 runs 128-row blocks
+  instead of 16-row ones: one window DMA, one transpose per tap and one
+  MXU dot serve ``ipb`` images. :func:`choose_m_block` returns the
+  :class:`MBlock` geometry; a block holds whole images or part of one.
 - The fused bias+ReLU flush epilogue carries over unchanged.
 
 Per live grid step the kernel moves one ``(rows, cols)`` window of one
@@ -112,18 +116,28 @@ def _ceil_to(n: int, m: int) -> int:
 
 class MBlock(NamedTuple):
     """Adaptive M-block geometry: ``block_oh × block_ow`` output pixels
-    per grid block, ``spi`` column segments per row band, ``bpi =
-    ceil(ho/block_oh)·spi`` M-blocks per image."""
+    of an image per grid block, padded to ``bm`` rows, ``spi`` column
+    segments per row band, ``bpi = ceil(ho/block_oh)·spi`` M-blocks per
+    image. ``ipb`` whole images share one block where one image's output
+    fills it (``bpi == 1``, ``block_oh·block_ow == bm``): the block's
+    ``m_rows = ipb·bm`` rows are those images' pixels, image after
+    image."""
     block_oh: int
     block_ow: int
     spi: int
     bm: int
     bpi: int
+    ipb: int = 1
+
+    @property
+    def m_rows(self) -> int:
+        return self.ipb * self.bm
 
 
-def choose_m_block(ho: int, wo: int, cap: int = 128) -> Optional[MBlock]:
+def choose_m_block(ho: int, wo: int, cap: int = 128,
+                   batch: int = 1) -> Optional[MBlock]:
     """Adaptive M-blocking: whole output rows per grid block, column
-    segments when a row is too wide.
+    segments when a row is too wide, whole images when one image fits.
 
     Picks the largest ``block_oh`` whole output rows with ``bm =
     ceil8(block_oh·wo) ≤ cap``, so small layers stop padding up to a
@@ -131,16 +145,23 @@ def choose_m_block(ho: int, wo: int, cap: int = 128) -> Optional[MBlock]:
     When even one output row exceeds ``cap`` the row splits into
     ``spi = ceil(wo/block_ow)`` column segments of ``block_ow =
     8·⌊cap/8⌋`` pixels — wide-resolution inputs keep the implicit path.
-    ``None`` only when the cap can't fit one 8-pixel segment. Blocks
-    never straddle images.
+    Where the whole image fills one block (``bpi == 1`` and ``ho·wo`` a
+    multiple of 8, so no padding row sits between images), the block
+    folds ``ipb`` images of a ``batch``-image call: the largest divisor
+    of ``batch`` with ``ipb·bm ≤ cap`` (a 4×4 output at batch 8: 8
+    images, 128 rows). ``None`` only when the cap can't fit one 8-pixel
+    segment.
     """
     if ho < 1 or wo < 1:
         return None
     if _ceil_to(wo, 8) <= cap:
         block_oh = max(b for b in range(1, ho + 1)
                        if _ceil_to(b * wo, 8) <= cap)
-        return MBlock(block_oh, wo, 1, _ceil_to(block_oh * wo, 8),
-                      -(-ho // block_oh))
+        bm = _ceil_to(block_oh * wo, 8)
+        bpi = -(-ho // block_oh)
+        ipb = 1 if bpi > 1 or ho * wo != bm else max(
+            d for d in range(1, batch + 1) if batch % d == 0 and d * bm <= cap)
+        return MBlock(block_oh, wo, 1, bm, bpi, ipb)
     block_ow = (cap // 8) * 8
     if block_ow < 8:
         return None
@@ -189,7 +210,8 @@ def crop_output(out2d: jnp.ndarray, mb: MBlock, batch: int, ho: int,
                 wo: int) -> jnp.ndarray:
     """Undo the M-block tiling: ``(B·bpi·bm, n_packed)`` kernel output →
     ``(B, ho, wo, n_packed)`` with the bm row padding and block
-    overhang dropped."""
+    overhang dropped. A folded block's rows are its images' ``bm`` rows
+    in image order, so a fold needs nothing more here."""
     rb = mb.bpi // mb.spi
     o = out2d.reshape(batch, rb, mb.spi, mb.bm, -1)
     o = o[:, :, :, :mb.block_oh * mb.block_ow]
@@ -240,31 +262,34 @@ def implicit_hbm_bytes(batch: int, h: int, w: int, cin: int, c_packed: int,
     moves it: ``(ingest, per_step)``. ``ingest`` reads the ``(batch, h,
     w, cin)`` input once and writes its padded copy
     (:func:`hbm_view_shape`); ``per_step`` is one live grid step's window
-    DMA, ``rows × dma_cols`` pixels of a whole 128-lane channel group."""
+    DMA, ``rows × dma_cols`` pixels of a whole 128-lane channel group for
+    each of the block's ``ipb`` images."""
     pads = _input_pads(h, w, kx, ky, stride, padding, mb)
     view = hbm_view_shape((batch, h + sum(pads[0]), w + sum(pads[1]),
                            c_packed), mb, kx, ky, stride)
     rows = window_shape(mb, kx, ky, stride)[0]
     return ((batch * h * w * cin + math.prod(view)) * itemsize,
-            rows * dma_cols(mb, kx, ky, stride) * LANES * itemsize)
+            mb.ipb * rows * dma_cols(mb, kx, ky, stride) * LANES * itemsize)
 
 
 def window_vmem_bytes(mb: MBlock, kx: int, ky: int, stride: int, bk: int,
                       itemsize: int) -> int:
     """Padded VMEM bytes of the implicit kernel's window working set: both
-    DMA slab slots (a window carries its whole 128-lane channel group),
-    the 32-bit working copy of the live slab (int8 / bf16 operands), and
-    the two transposed ``(128, bm)`` / ``(bk, bm)`` tap and patch tiles."""
+    DMA slab slots of the block's ``ipb`` windows (a window carries its
+    whole 128-lane channel group), the 32-bit working copy of the live
+    slab (int8 / bf16 operands), and the two transposed ``(128, ipb·bm)``
+    / ``(bk, ipb·bm)`` tap and patch tiles."""
     rows = window_shape(mb, kx, ky, stride)[0]
     cols = dma_cols(mb, kx, ky, stride)
-    slab = _tiled_bytes((2, rows, cols, LANES), itemsize)
-    work = 0 if itemsize == 4 else _tiled_bytes((rows, cols, LANES), 4)
-    return (slab + work + _tiled_bytes((LANES, mb.bm), 4)
-            + _tiled_bytes((bk, mb.bm), 4))
+    slab = _tiled_bytes((2, mb.ipb, rows, cols, LANES), itemsize)
+    work = (0 if itemsize == 4
+            else _tiled_bytes((mb.ipb, rows, cols, LANES), 4))
+    return (slab + work + _tiled_bytes((LANES, mb.m_rows), 4)
+            + _tiled_bytes((bk, mb.m_rows), 4))
 
 
 def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
-            kx, ky, stride, block_oh, block_ow, spi, bpi, cpk, slot,
+            kx, ky, stride, block_oh, block_ow, spi, bpi, ipb, cpk, slot,
             acc_dtype, has_scale, has_bias, has_out, relu, activation_dsb,
             count_skips, widen):
     n_ep = int(has_scale) + int(has_bias) + int(has_out)
@@ -279,7 +304,9 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
     cols = (block_ow - 1) * stride + ky
     cols_dma = _ceil_to(cols, 8)
     m = block_oh * block_ow
-    b = i // bpi
+    # a folded block (ipb > 1, one block per image) holds images
+    # [i*ipb, (i+1)*ipb) and DMAs their windows in one copy
+    b = i // bpi if ipb == 1 else pl.ds(i * ipb, ipb)
     p = i % bpi
     r0 = (p // spi) * (block_oh * stride)
     q0 = 0 if spi == 1 else pl.multiple_of((p % spi) * (block_ow * stride), 8)
@@ -331,15 +358,21 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
             # one strided load, transposed so the K-tile's cpk channels
             # land on sublanes c*slot + tap of the (bk, bm) patch tile;
             # one transpose back gives the (bm, bk) tile the materializing
-            # path would have read from HBM, element for element
+            # path would have read from HBM, element for element. A folded
+            # block loads the tap of all its images at once (image k's
+            # pixels at sublanes k*m), so each tap is still one transpose
+            # per block
+            lead = () if ipb == 1 else (slice(None),)
+            mt = ipb * m
             for dy in range(kx):
                 for dx in range(ky):
-                    tap = win_ref[pl.ds(dy, block_oh, stride=stride),
-                                  pl.ds(dx, block_ow, stride=stride), :]
-                    tap_ref[:, pl.ds(0, m)] = tap.reshape(m, LANES).T
+                    tap = win_ref[(*lead, pl.ds(dy, block_oh, stride=stride),
+                                   pl.ds(dx, block_ow, stride=stride),
+                                   slice(None))].reshape(mt, LANES)
+                    tap_ref[:, pl.ds(0, mt)] = tap.T
                     patch_ref[pl.ds(dy * ky + dx, cpk, stride=slot),
-                              pl.ds(0, m)] = tap_ref[pl.ds(coff, cpk),
-                                                     pl.ds(0, m)]
+                              pl.ds(0, mt)] = tap_ref[pl.ds(coff, cpk),
+                                                      pl.ds(0, mt)]
             pt = patch_ref[...].T.astype(w_ref.dtype)
             acc_ref[...] += jnp.dot(pt, w_ref[...],
                                     preferred_element_type=acc_dtype,
@@ -391,11 +424,12 @@ def implicit_block_sparse_conv(
     count_skips: bool = False,
     interpret: bool = False,
 ):
-    """-> (B*bpi*bm, nNb*bn). M-block ``(b, p)`` starts at row
+    """-> (B*bpi*bm, nNb*bn). Image ``b``'s block ``p`` starts at row
     ``(b*bpi + p)*bm``; its first ``block_oh*block_ow`` rows are the
     block's output pixels row-major (row band ``p // spi``, column
     segment ``p % spi``), the rest padding — undo with
-    :func:`crop_output`.
+    :func:`crop_output`. A folded ``mb`` (``ipb > 1``) runs one grid
+    block per ``ipb`` images, over the same rows.
 
     int8 operands (``xp``/``w`` are Q-format codes): the gather works on
     codes, accumulation is exact **int32**, and the flush epilogue
@@ -406,18 +440,22 @@ def implicit_block_sparse_conv(
 
     ``activation_dsb`` (int8 codes only) skips all-zero window slabs —
     bit-exact, see the module docstring. With ``count_skips`` the return
-    is ``(out, skips)`` where ``skips`` is the ``(B*bpi, nNb)`` int32
-    per-M-block/per-column skip counter (skipped live steps; total live
-    steps are ``B*bpi*cnt.sum()``)."""
+    is ``(out, skips)`` where ``skips`` is the ``(B//ipb*bpi, nNb)``
+    int32 per-M-block/per-column skip counter (skipped live steps; total
+    live steps are ``B//ipb*bpi*cnt.sum()``)."""
     B, Hp, Wp, Cp = xp.shape
     bk, bn = block
     assert Cp % cpk == 0 and w.shape[0] % bk == 0 and w.shape[1] % bn == 0, (
         f"packed shapes off-grid: x {xp.shape} (cpk={cpk}), w {w.shape}, "
         f"block={block}")
+    assert B % mb.ipb == 0 and (mb.ipb == 1 or mb.bpi == 1
+                                and mb.block_oh * mb.block_ow == mb.bm), (
+        f"a block folds whole images that fill their rows: batch {B}, {mb}")
     if activation_dsb:
         assert xp.dtype == jnp.int8, (
             "activation_dsb keys the skip on exact int8 zero codes — "
             "quantize the activation (quant=...) to use it")
+        assert mb.ipb == 1, "activation_dsb tests one image's window"
     rows, cols = window_shape(mb, kx, ky, stride)
     rb = mb.bpi // mb.spi
     assert ((rb - 1) * mb.block_oh * stride + rows <= Hp
@@ -446,7 +484,9 @@ def implicit_block_sparse_conv(
     inputs = [idx, cnt, xk, w]
     append_epilogue_inputs(in_specs, inputs, scale, bias, bn, out_scale)
 
-    out_specs = pl.BlockSpec((mb.bm, bn), lambda i, j, s, idx, cnt: (i, j))
+    n_blocks = B // mb.ipb * mb.bpi
+    out_specs = pl.BlockSpec((mb.m_rows, bn),
+                             lambda i, j, s, idx, cnt: (i, j))
     out_shape = jax.ShapeDtypeStruct((B * mb.bpi * mb.bm, w.shape[1]),
                                      out_dtype)
     if count_skips:
@@ -456,20 +496,22 @@ def implicit_block_sparse_conv(
             (1, 1, 1), lambda i, j, s, idx, cnt: (i * nNb + j, 0, 0),
             memory_space=pltpu.SMEM)]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((B * mb.bpi * nNb, 1, 1), jnp.int32)]
+                     jax.ShapeDtypeStruct((n_blocks * nNb, 1, 1), jnp.int32)]
 
     widen = xp.dtype.itemsize != 4
     wide_dtype = jnp.int32 if xp.dtype == jnp.int8 else jnp.float32
-    scratch = [pltpu.VMEM((mb.bm, bn), acc_dtype),
-               pltpu.VMEM((2, rows, cols_dma, LANES), xp.dtype)]
+    # one window per image of the block (the image axis only when folded)
+    win = (*((mb.ipb,) if mb.ipb > 1 else ()), rows, cols_dma, LANES)
+    scratch = [pltpu.VMEM((mb.m_rows, bn), acc_dtype),
+               pltpu.VMEM((2, *win), xp.dtype)]
     if widen:
-        scratch.append(pltpu.VMEM((rows, cols_dma, LANES), wide_dtype))
-    scratch += [pltpu.VMEM((LANES, mb.bm), wide_dtype),
-                pltpu.VMEM((bk, mb.bm), wide_dtype),
+        scratch.append(pltpu.VMEM(win, wide_dtype))
+    scratch += [pltpu.VMEM((LANES, mb.m_rows), wide_dtype),
+                pltpu.VMEM((bk, mb.m_rows), wide_dtype),
                 pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * mb.bpi, nNb, max_nnz),
+        grid=(n_blocks, nNb, max_nnz),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -477,7 +519,8 @@ def implicit_block_sparse_conv(
     res = pl.pallas_call(
         functools.partial(_kernel, kx=kx, ky=ky, stride=stride,
                           block_oh=mb.block_oh, block_ow=mb.block_ow,
-                          spi=mb.spi, bpi=mb.bpi, cpk=cpk, slot=slot,
+                          spi=mb.spi, bpi=mb.bpi, ipb=mb.ipb, cpk=cpk,
+                          slot=slot,
                           acc_dtype=acc_dtype, has_scale=has_scale,
                           has_bias=has_bias, has_out=has_out, relu=relu,
                           activation_dsb=activation_dsb,
@@ -490,7 +533,7 @@ def implicit_block_sparse_conv(
     )(*inputs)
     if count_skips:
         out, skips = res
-        return out, skips.reshape(B * mb.bpi, nNb)
+        return out, skips.reshape(n_blocks, nNb)
     return res
 
 

@@ -433,10 +433,31 @@ class SparseConvExec:
                 ((1 if self.streamed else dtype_bytes)
                  if out_bytes is None else out_bytes))
 
-    def _m_blocks(self, out: int, batch: int, bm=None, implicit=None):
-        from ..sparse.conv_plan import conv_m_blocks
-        bm, implicit, _, _ = self._accounting(bm, implicit)
-        return conv_m_blocks(out, out, batch, bm=bm, implicit=implicit)
+    def _m_blocks(self, path, stride: int, feat: int, batch: int, bm=None,
+                  implicit=None):
+        """(M-blocks, effective bm, images per block) of one conv layer's
+        grid at ``batch``. An implicit layer's M-block is the one its
+        kernel launches (``sparse.conv_plan.implicit_m_block``, the rule
+        the bound conv routes by: a fold the VMEM budget reduces, and one
+        image a block under ``activation_dsb``); any other layer blocks
+        on the materializing path's flat rows."""
+        from ..sparse.conv_plan import conv_m_blocks, implicit_m_block
+        bm, implicit, ob, _ = self._accounting(bm, implicit)
+        mbk = (implicit_m_block(self.layouts[path], feat, feat, stride,
+                                "SAME", ob, bm, batch=batch,
+                                activation_dsb=self.activation_dsb)
+               if implicit else None)
+        if mbk is not None:
+            return batch // mbk.ipb * mbk.bpi, mbk.m_rows, mbk.ipb
+        out = -(-feat // stride)
+        return (*conv_m_blocks(out, out, batch, bm=bm), 1)
+
+    def images_per_block(self, cfg: ResNetConfig, batch: int = 1) -> dict:
+        """{layer path: whole images one M-block holds} at ``batch`` under
+        this exec's own policy — the implicit kernel's fold, 1 where one
+        image takes several blocks or the layer is not implicit."""
+        return {"/".join(path): self._m_blocks(path, stride, feat, batch)[2]
+                for path, stride, feat in conv_layer_order(cfg)}
 
     def step_counts(self, cfg: ResNetConfig, batch: int = 1, bm=None):
         """(executed, dense) dispatched grid steps over the whole network —
@@ -447,8 +468,7 @@ class SparseConvExec:
         executed = dense = 0
         for path, stride, feat in conv_layer_order(cfg):
             plan = self.plans[path]
-            out = -(-feat // stride)
-            mb, _ = self._m_blocks(out, batch, bm)
+            mb = self._m_blocks(path, stride, feat, batch, bm)[0]
             executed += mb * int(plan.cnt.sum())
             dense += mb * plan.tiles[0] * plan.tiles[1]
         return executed, dense
@@ -459,7 +479,7 @@ class SparseConvExec:
         (``bm``/``implicit`` override it, e.g. the canonical adaptive
         implicit contract regardless of the bind)."""
         return {"/".join(path):
-                self._m_blocks(-(-feat // stride), batch, bm, implicit)[1]
+                self._m_blocks(path, stride, feat, batch, bm, implicit)[1]
                 for path, stride, feat in conv_layer_order(cfg)}
 
     def routes(self, cfg: ResNetConfig) -> dict:
@@ -493,7 +513,7 @@ class SparseConvExec:
                 self.layouts[path], self.group_masks_np[path], batch, feat,
                 feat, stride, "SAME", implicit=use_implicit,
                 bm=bm, dtype_bytes=dtype_bytes, operand_bytes=operand_bytes,
-                out_bytes=out_bytes)
+                out_bytes=out_bytes, activation_dsb=self.activation_dsb)
         return total
 
     def schedule_step_counts(self):
@@ -519,7 +539,7 @@ class SparseConvExec:
         num = den = 0.0
         for path, stride, feat in conv_layer_order(cfg):
             out = -(-feat // stride)
-            mb, bm_eff = self._m_blocks(out, batch, bm)
+            mb, bm_eff, _ = self._m_blocks(path, stride, feat, batch, bm)
             live_elems, area = self.layouts[path].mac_accounting(
                 self.group_masks_np[path])
             num += batch * out * out * live_elems
@@ -598,7 +618,9 @@ class SparseConvExec:
         the next layer ingests). ``hbm_bytes`` and the grid-step fields
         describe the exec's *own* policy (own contract, own ``bm``, own
         operand/output widths). ``layers_{implicit,materializing,dense}``
-        count the conv layers on each route (:meth:`routes`).
+        count the conv layers on each route (:meth:`routes`);
+        ``images_per_block`` is each layer's fold at ``batch``
+        (:meth:`images_per_block`), which the grid-step fields follow.
         ``per_layer=True`` adds the same fields, plus
         the layer's ``path``, per conv layer (keys ``"/".join(path)``),
         which is what the simulator reports next to the cycle model.
@@ -635,6 +657,7 @@ class SparseConvExec:
             **{f"layers_{route}": sum(v == route for v in routes.values())
                for route in ("implicit", "materializing", "dense")},
             "bm_effective": self.bm_effective(cfg, batch=batch),
+            "images_per_block": self.images_per_block(cfg, batch=batch),
             "hbm_bytes": self.hbm_bytes(cfg, batch, dtype_bytes=dtype_bytes),
             "hbm_bytes_materialized": hbm(False, 128, dtype_bytes),
             "hbm_bytes_implicit": hbm(True, "auto", dtype_bytes),
@@ -658,18 +681,19 @@ class SparseConvExec:
         out = {}
         for path, stride, feat in conv_layer_order(cfg):
             plan = self.plans[path]
-            o = -(-feat // stride)
-            mb, bm_eff = self._m_blocks(o, batch)
+            mb, bm_eff, ipb = self._m_blocks(path, stride, feat, batch)
             hbm = lambda imp, bm, ob, out_b=None: conv_hbm_bytes(
                 self.layouts[path], self.group_masks_np[path], batch, feat,
                 feat, stride, "SAME", implicit=imp, bm=bm,
                 dtype_bytes=dtype_bytes, operand_bytes=ob,
-                out_bytes=dtype_bytes if out_b is None else out_b)
+                out_bytes=dtype_bytes if out_b is None else out_b,
+                activation_dsb=self.activation_dsb)
             out["/".join(path)] = {
                 "path": routes[path],
                 "executed": mb * int(plan.cnt.sum()),
                 "dense": mb * plan.tiles[0] * plan.tiles[1],
                 "bm_effective": bm_eff,
+                "images_per_block": ipb,
                 "hbm_materialized": hbm(False, 128, dtype_bytes),
                 "hbm_implicit": hbm(True, "auto", dtype_bytes),
                 "hbm_materialized_int8": hbm(False, 128, 1),

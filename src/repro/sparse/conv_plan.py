@@ -398,36 +398,36 @@ def adaptive_bm(m_rows: int, cap: int = 128) -> int:
     return min(cap, _ceil_to(max(int(m_rows), 1), 8))
 
 
-def conv_m_blocks(ho: int, wo: int, batch: int, *, bm="auto",
-                  implicit: bool = False) -> Tuple[int, int]:
-    """(number of M-blocks, effective bm) for one conv layer's grid —
-    the single source for step/MAC accounting (``SparseConvExec``,
-    ``accel.simulator``, benches). ``bm`` is an int (fixed, the PR-3
-    contract) or ``"auto"`` (adaptive). The implicit kernel blocks on
-    whole output rows per image; the materializing path on flat
-    ``B·Ho·Wo`` rows."""
-    from ..kernels.implicit_conv import choose_m_block
-
+def conv_m_blocks(ho: int, wo: int, batch: int, *,
+                  bm="auto") -> Tuple[int, int]:
+    """(number of M-blocks, effective bm) of the materializing path's
+    grid for one conv layer: flat ``B·Ho·Wo`` rows. ``bm`` is an int
+    (fixed, the PR-3 contract) or ``"auto"`` (adaptive). The implicit
+    kernel's grid is :func:`implicit_m_block`'s; ``SparseConvExec``,
+    ``accel.simulator`` and the benches count steps by the two."""
     cap = 128 if bm == "auto" else int(bm)
-    if implicit:
-        mb = choose_m_block(ho, wo, cap=cap)
-        if mb is not None:
-            return batch * mb.bpi, mb.bm
     bm_eff = adaptive_bm(batch * ho * wo, cap) if bm == "auto" else cap
     return -(-batch * ho * wo // bm_eff), bm_eff
 
 
 def implicit_m_block(layout: ConvGemmLayout, h: int, w: int, stride: int,
-                     padding: str, itemsize: int, bm="auto"):
-    """The implicit kernel's M-block for one conv geometry, or ``None``
-    when the layer takes the materializing path: the layout packs K
-    tap-major (no window to gather), no whole-row or column-segment
-    M-block fits the ``bm`` cap, or the kernel's padded VMEM working set
+                     padding: str, itemsize: int, bm="auto", batch: int = 1,
+                     activation_dsb: bool = False):
+    """The implicit kernel's M-block for one conv geometry and a
+    ``batch``-image call, or ``None`` when the layer takes the
+    materializing path: the layout packs K tap-major (no window to
+    gather), no whole-row or column-segment M-block fits the ``bm`` cap,
+    or the kernel's padded VMEM working set
     (:func:`repro.kernels.implicit_conv.window_vmem_bytes`, at operand
     width ``itemsize``) exceeds :data:`~repro.kernels.implicit_conv.
-    SLAB_VMEM_BUDGET`. The one route rule: :func:`make_sparse_conv`
-    applies it once per input geometry (at bind time where the layer's
-    geometry is given) and :func:`conv_hbm_bytes` prices by it."""
+    SLAB_VMEM_BUDGET` even for one image. A fold of whole images
+    (``choose_m_block``) whose windows exceed the budget is reduced to
+    the largest that fits; an ``activation_dsb`` bind never folds (its
+    skip tests one image's window). The one route and grid rule:
+    :func:`make_sparse_conv` applies it once per input geometry (at bind
+    time where the layer's geometry is given) and once per batch, and
+    :func:`conv_hbm_bytes` and ``SparseConvExec``'s step accounting
+    count by it."""
     from ..kernels import implicit_conv as IC
     from ..kernels.conv_lowering import conv_out_size
 
@@ -435,13 +435,18 @@ def implicit_m_block(layout: ConvGemmLayout, h: int, w: int, stride: int,
     if geo is None:
         return None
     kx, ky = geo["kx"], geo["ky"]
-    mb = IC.choose_m_block(conv_out_size(h, kx, stride, padding),
-                           conv_out_size(w, ky, stride, padding),
-                           cap=128 if bm == "auto" else int(bm))
-    if mb is None or IC.window_vmem_bytes(
+    ho = conv_out_size(h, kx, stride, padding)
+    wo = conv_out_size(w, ky, stride, padding)
+    cap = 128 if bm == "auto" else int(bm)
+    mb = IC.choose_m_block(ho, wo, cap=cap,
+                           batch=1 if activation_dsb else batch)
+    while mb is not None and IC.window_vmem_bytes(
             mb, kx, ky, stride, layout.block[0],
             itemsize) > IC.SLAB_VMEM_BUDGET:
-        return None
+        # a cap just under the fold keeps the image's own block and folds
+        # the next smaller divisor of the batch
+        mb = (IC.choose_m_block(ho, wo, cap=mb.m_rows - 1, batch=batch)
+              if mb.ipb > 1 else None)
     return mb
 
 
@@ -449,7 +454,8 @@ def conv_hbm_bytes(layout: ConvGemmLayout, group_mask, batch: int, h: int,
                    w: int, stride: int = 1, padding: str = "SAME", *,
                    implicit: bool, bm="auto", dtype_bytes: int = 4,
                    operand_bytes: Optional[int] = None,
-                   out_bytes: Optional[int] = None) -> int:
+                   out_bytes: Optional[int] = None,
+                   activation_dsb: bool = False) -> int:
     """Analytic HBM bytes one forward of this conv layer moves — the
     data-movement contract the implicit kernel changes.
 
@@ -463,10 +469,13 @@ def conv_hbm_bytes(layout: ConvGemmLayout, group_mask, batch: int, h: int,
     are priced as materializing): read the activation once and write
     its padded copy, channels padded to whole 128-lane groups, then
     stream one window DMA — ``rows × dma_cols`` pixels of a whole
-    128-lane channel group — + one weight tile per live grid step and
-    write the output; the patch matrix never exists. The activation
-    terms are :func:`repro.kernels.implicit_conv.implicit_hbm_bytes`,
-    the kernel's own window and padding formulas.
+    128-lane channel group for each image of the M-block — + one weight
+    tile per live grid step and write the output; the patch matrix never
+    exists. The activation terms are
+    :func:`repro.kernels.implicit_conv.implicit_hbm_bytes`, the kernel's
+    own window and padding formulas, at the M-block
+    :func:`implicit_m_block` gives (``activation_dsb``: one image per
+    M-block).
 
     ``operand_bytes`` prices the *operand* traffic (activations /
     patches / weights) separately from the f32 output write
@@ -492,10 +501,11 @@ def conv_hbm_bytes(layout: ConvGemmLayout, group_mask, batch: int, h: int,
     plan = layout.plan(group_mask)
     live = int(plan.cnt.sum())
     bk, bn = layout.block
-    mbk = (implicit_m_block(layout, h, w, stride, padding, ob, bm)
+    mbk = (implicit_m_block(layout, h, w, stride, padding, ob, bm,
+                            batch=batch, activation_dsb=activation_dsb)
            if implicit else None)
-    mb, bm_eff = conv_m_blocks(ho, wo, batch, bm=bm,
-                               implicit=mbk is not None)
+    mb, bm_eff = ((batch // mbk.ipb * mbk.bpi, mbk.m_rows) if mbk is not None
+                  else conv_m_blocks(ho, wo, batch, bm=bm))
     steps = mb * live
     w_bytes = steps * bk * bn * ob
     out_write = mb * bm_eff * layout.n_packed * ob_out
@@ -551,9 +561,11 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
     first call at each geometry and keeps it.
 
     ``bm``: M-blocking. ``"auto"`` (default) adapts to the layer —
-    whole-output-row blocks for the implicit kernel, ``ceil8(B·Ho·Wo)``
-    capped at 128 for the materializing path — so batch-1 tails stop
-    padding 10×; an int pins it (the PR-3 contract).
+    whole-output-row blocks for the implicit kernel, whole images folded
+    into one block where one image's output fills fewer rows than the cap,
+    ``ceil8(B·Ho·Wo)`` capped at 128 for the materializing path — so
+    batch-1 tails stop padding 10×; an int pins the cap (the PR-3
+    contract).
 
     ``weight``: bind-time prepacking. The masked weight is packed **once**
     here and the closure only pads the activation (implicit) or packs
@@ -618,8 +630,9 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
 
     ``conv.plan`` / ``conv.layout`` / ``conv.group_mask`` /
     ``conv.implicit`` / ``conv.quant`` / ``conv.trainable`` expose the
-    dispatch accounting; ``conv.m_block(h, w, stride, padding)`` the
-    route at an input geometry (``None`` = materializing).
+    dispatch accounting; ``conv.m_block(h, w, stride, padding, batch=1)``
+    the route at an input geometry (``None`` = materializing), its
+    M-block folded for a ``batch``-image call.
     """
     from ..kernels import ops
     from ..kernels import implicit_conv as IC
@@ -717,7 +730,7 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
                     geometry[3])
         routes[geometry] = _choose(*geometry)
 
-    def _route(h, w, stride=1, padding="SAME"):
+    def _route(h, w, stride=1, padding="SAME", batch=1):
         key = (int(h), int(w), int(stride), padding)
         if key not in routes:
             if geometry is not None:
@@ -726,7 +739,12 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
                     f"= {geometry}, called at {key} — rebind for this "
                     "geometry")
             routes[key] = _choose(*key)
-        return routes[key]
+        # the route is the geometry's; a call's batch only folds whole
+        # images into its blocks
+        if routes[key] is None:
+            return None
+        return implicit_m_block(layout, *key, itemsize, bm, batch=int(batch),
+                                activation_dsb=activation_dsb)
 
     def _run(x, wp, kx, ky, stride, padding, count_skips=False):
         """Forward with an already-packed weight ``wp`` (concrete or
@@ -738,7 +756,7 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
         B, H, W, C = x.shape
         ho = conv_out_size(H, kx, stride, padding)
         wo = conv_out_size(W, ky, stride, padding)
-        mbk = _route(H, W, stride, padding)
+        mbk = _route(H, W, stride, padding, B)
         if mbk is not None:
             cpk, slot = geo["cpk"], geo["slot"]
             xp = IC.pad_input(x, kx, ky, stride, padding, mbk,
@@ -755,7 +773,7 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
             o = IC.crop_output(out2d, mbk, B, ho, wo)
             y = layout.unpack_output(o.reshape(B * ho * wo, -1), (B, ho, wo))
             if count_skips:
-                live = B * mbk.bpi * int(plan.cnt.sum())
+                live = B // mbk.ipb * mbk.bpi * int(plan.cnt.sum())
                 return y, {"skipped_steps": int(skips.sum()),
                            "live_steps": live}
             return y

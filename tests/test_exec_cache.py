@@ -406,3 +406,76 @@ def test_report_matches_individual_methods(tiny):
         rep["hbm_bytes_implicit"]
     # accounting-only exec: no kernels were bound
     assert all(v is None for v in ex.table.values())
+
+
+def _kernel_grids(jaxpr, name=None):
+    """(kernel name, grid) of every Pallas call in ``jaxpr``, named by the
+    innermost enclosing ``conv_<layer>`` jit."""
+    for e in jaxpr.eqns:
+        inner = name
+        if e.primitive.name in ("jit", "pjit") and str(
+                e.params.get("name", "")).startswith("conv_"):
+            inner = e.params["name"]
+        if e.primitive.name == "pallas_call":
+            yield name, tuple(e.params["grid_mapping"].grid)
+        for v in e.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _kernel_grids(sub, inner)
+
+
+# folds by layer: 2 for 8x8 outputs and 8 for 4x4 ones; none under
+# activation_dsb; under a VMEM budget cut to the s1b0/conv1 window at one
+# image, each fold shrinks to the largest whose windows fit it
+GRID_CASES = {
+    "streamed": {"conv0": 1, "s0b0/conv1": 1, "s0b0/conv2": 1,
+                 "s1b0/conv1": 2, "s1b0/conv2": 2, "s1b0/proj": 2,
+                 "s2b0/conv1": 8, "s2b0/conv2": 8, "s2b0/proj": 8},
+    "activation_dsb": {},
+    "budget_shrinks_the_fold": {"s1b0/conv2": 2, "s2b0/conv1": 2,
+                                "s2b0/conv2": 4, "s2b0/proj": 4},
+}
+
+
+@pytest.mark.parametrize("case", list(GRID_CASES))
+def test_report_grid_steps_equal_the_launched_grids(case, monkeypatch):
+    """The report's grid steps are the grids the served program's kernels
+    launch: per layer, M-blocks (whole images folded into one where an
+    image's output is small) × live K-tiles, at the bucket's batch —
+    also where the VMEM budget reduces a fold."""
+    from repro.kernels import implicit_conv as IC
+    if case == "budget_shrinks_the_fold":
+        s1_window = IC.choose_m_block(8, 8)
+        monkeypatch.setattr(IC, "SLAB_VMEM_BUDGET", IC.window_vmem_bytes(
+            s1_window, 3, 3, 2, 128, 1))
+    cfg = cnn.ResNetConfig(stages=(1, 1, 1), widths=(8, 16, 32),
+                           image_size=16)
+    params, state = cnn.init(jax.random.PRNGKey(0), cfg)
+    dsb = case == "activation_dsb"
+    server = CnnServer(params, state, cfg, buckets=(8,), spec=cnn.ExecSpec(
+        n_cu=N_CU, quantized=True, folded=True, streamed=not dsb,
+        activation_dsb=dsb, dense_fallback=2.0))
+    entry = server._entry_for(server.rungs[0], 8)
+    jaxpr = jax.make_jaxpr(entry.fn)(jnp.zeros((8, 16, 16, 3))).jaxpr
+    grids = dict(_kernel_grids(jaxpr))
+    ex = entry.exec_
+    rep = ex.report(cfg, batch=8, per_layer=True)
+    assert len(grids) == len(cnn.conv_layer_order(cfg))
+    assert rep["layers_implicit"] == len(grids)
+    executed = 0
+    for path, stride, feat in cnn.conv_layer_order(cfg):
+        plan, layer = ex.plans[path], rep["per_layer"]["/".join(path)]
+        n_m, n_n, n_s = grids[cnn.conv_kernel_name(path)]
+        assert (n_n, n_s) == plan.idx.shape
+        assert layer["executed"] == n_m * int(plan.cnt.sum())
+        assert layer["dense"] == n_m * plan.tiles[0] * plan.tiles[1]
+        o = -(-feat // stride)
+        bpi = 1 if o <= 8 else -(-o * o // 128)
+        assert layer["images_per_block"] * n_m == 8 * bpi
+        assert layer["bm_effective"] == layer["images_per_block"] * min(
+            o * o, 128)
+        executed += n_m * int(plan.cnt.sum())
+    assert rep["executed_grid_steps"] == executed
+    assert rep["images_per_block"] == {
+        "/".join(p): GRID_CASES[case].get("/".join(p[:-1]), 1)
+        for p, _, _ in cnn.conv_layer_order(cfg)}

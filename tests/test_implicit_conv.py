@@ -170,12 +170,106 @@ def test_choose_m_block_invariants():
     assert wide.spi * wide.block_ow >= 256
     assert adaptive_bm(16) == 16 and adaptive_bm(3) == 8
     assert adaptive_bm(10_000) == 128
-    # accounting helper agrees with the kernel's blocking
-    mb, bm = conv_m_blocks(8, 8, batch=3, bm="auto", implicit=True)
-    assert (mb, bm) == (3 * IC.choose_m_block(8, 8).bpi,
-                        IC.choose_m_block(8, 8).bm)
-    mb, bm = conv_m_blocks(8, 8, batch=3, bm="auto", implicit=False)
+    # the materializing path's accounting blocks on flat B*Ho*Wo rows (the
+    # implicit grid is implicit_m_block's: test_images_per_block_rule)
+    mb, bm = conv_m_blocks(8, 8, batch=3, bm="auto")
     assert (mb, bm) == (-(-3 * 64 // 128), 128)
+
+
+# outputs 4x4 and 8x8, (input size, kernel, stride): 3x3 stride 1, 3x3
+# stride 2 and 1x1 stride 2 onto each — the layers whose blocks fold
+FOLD_GEOMETRIES = [(4, 3, 1), (8, 3, 2), (8, 1, 2),
+                   (8, 3, 1), (16, 3, 2), (16, 1, 2)]
+
+
+@pytest.mark.parametrize("form", ["int8_streamed", "f32"])
+@pytest.mark.parametrize("batch", [1, 2, 3, 6, 8, 16])
+@pytest.mark.parametrize("h,k,stride", FOLD_GEOMETRIES)
+def test_folded_implicit_conv_matches_materializing(h, k, stride, batch,
+                                                    form):
+    """Whole images folded into one M-block: the implicit kernel still
+    equals the materializing path — int8 streamed codes exactly, f32
+    within the sweep's tolerance — at folds of 1 up to 8 images."""
+    from repro.core import QuantSpec
+    rng = np.random.RandomState(h * 1000 + k * 100 + stride * 10 + batch)
+    cin, cout, n_cu = 16, 24, 4
+    spec = fpga_conv_groups((k, k, cin, cout), n_cu)
+    gm = _group_mask(rng, spec.num_groups, 0.6)
+    w = jnp.asarray(rng.uniform(-1, 1, (k, k, cin, cout)), jnp.float32)
+    x = jnp.asarray(rng.uniform(-2, 2, (batch, h, h, cin)), jnp.float32)
+    kw = dict(weight=w, bias=jnp.asarray(rng.uniform(-1, 1, cout),
+                                         jnp.float32), relu=True)
+    if form == "int8_streamed":
+        kw.update(quant=QuantSpec(), out_quant=QuantSpec())
+    layout = conv_gemm_layout(spec, packed=True)
+    convs = {implicit: make_sparse_conv(layout, gm, implicit=implicit, **kw)
+             for implicit in (True, False)}
+    outs = {implicit: np.asarray(conv(x, stride=stride))
+            for implicit, conv in convs.items()}
+    ho = -(-h // stride)
+    assert convs[True].m_block(h, h, stride, "SAME", batch).ipb == max(
+        d for d in range(1, batch + 1) if batch % d == 0 and d * ho * ho <= 128)
+    if form == "int8_streamed":
+        assert outs[True].dtype == np.int8
+        np.testing.assert_array_equal(outs[True], outs[False])
+    else:
+        np.testing.assert_allclose(outs[True], outs[False],
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_images_per_block_rule():
+    """The fold: the largest divisor of the batch whose images fit the
+    128-row cap, only where one image's pixels fill one block, never
+    under activation_dsb."""
+    assert IC.choose_m_block(8, 8, batch=3).ipb == 1
+    assert IC.choose_m_block(8, 8, batch=32).ipb == 2
+    assert IC.choose_m_block(4, 4, batch=6).ipb == 6
+    assert IC.choose_m_block(4, 4, batch=8).ipb == 8
+    assert IC.choose_m_block(4, 4, batch=128).ipb == 8
+    assert IC.choose_m_block(4, 4, batch=16, cap=64).ipb == 4
+    assert IC.choose_m_block(16, 16, batch=8).ipb == 1     # bpi 2
+    assert IC.choose_m_block(32, 32, batch=8).ipb == 1     # bpi 8
+    # 49 and 4 pixels are short of their 56- and 8-row blocks
+    assert IC.choose_m_block(7, 7, batch=2).ipb == 1
+    assert IC.choose_m_block(2, 2, batch=8).ipb == 1
+    assert IC.choose_m_block(4, 4, batch=8).m_rows == 128
+    # the grid the accounting prices follows the fold
+    from repro.core import QuantSpec
+    from repro.sparse.conv_plan import implicit_m_block
+    spec = fpga_conv_groups((3, 3, 16, 16), 4)
+    gm = np.ones(spec.num_groups, np.float32)
+    w = jnp.ones((3, 3, 16, 16), jnp.float32)
+    layout = conv_gemm_layout(spec, packed=True)
+    assert implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8).ipb == 8
+    assert implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8,
+                            activation_dsb=True).ipb == 1
+    kw = dict(weight=w, quant=QuantSpec(), implicit=True)
+    assert make_sparse_conv(layout, gm, **kw).m_block(4, 4, 1, "SAME",
+                                                      8).ipb == 8
+    dsb = make_sparse_conv(layout, gm, activation_dsb=True, **kw)
+    assert dsb.m_block(4, 4, 1, "SAME", 8).ipb == 1
+    x = jnp.asarray(np.random.RandomState(0).randint(0, 3, (8, 4, 4, 16)),
+                    jnp.int8)
+    _, stats = dsb.skip_counts(x)
+    assert stats["live_steps"] == 8 * int(dsb.plan.cnt.sum())
+
+
+def test_fold_shrinks_to_the_vmem_budget(monkeypatch):
+    """A fold whose windows exceed the VMEM budget is reduced to the
+    largest that fits before the layer leaves the implicit path."""
+    from repro.sparse.conv_plan import implicit_m_block
+    spec = fpga_conv_groups((3, 3, 16, 16), 4)
+    layout = conv_gemm_layout(spec, packed=True)
+    full = implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8)
+    assert full.ipb == 8
+    need = {d: IC.window_vmem_bytes(full._replace(ipb=d), 3, 3, 1, 128, 1)
+            for d in (1, 2, 4, 8)}
+    monkeypatch.setattr(IC, "SLAB_VMEM_BUDGET", need[4])
+    assert implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8).ipb == 4
+    monkeypatch.setattr(IC, "SLAB_VMEM_BUDGET", need[1])
+    assert implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8).ipb == 1
+    monkeypatch.setattr(IC, "SLAB_VMEM_BUDGET", need[1] - 1)
+    assert implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8) is None
 
 
 def test_implicit_falls_back_to_materializing(monkeypatch):

@@ -80,14 +80,14 @@ FORMS = {
 }
 
 
-@pytest.mark.parametrize("form", FORMS)
-@pytest.mark.parametrize("geometry", GEOMETRIES)
-def test_implicit_conv_compiles(one_chip, geometry, form):
-    h, k, stride, cin, cout = GEOMETRIES[geometry]
+def _compile_implicit(one_chip, geometry, form, batch, fold):
+    """Compile one implicit conv of ``geometry`` (input size, kernel,
+    stride, cin, cout) in ``form`` at ``batch``; ``fold`` puts whole
+    images in one M-block where the geometry allows."""
+    h, k, stride, cin, cout = geometry
     dtype, out_scale, dsb = FORMS[form]
-    batch = 8
     ho = conv_out_size(h, k, stride, "SAME")
-    mb = IC.choose_m_block(ho, ho)
+    mb = IC.choose_m_block(ho, ho, batch=batch if fold else 1)
     slot = -(-k * k // 8) * 8
     cpk = 128 // slot
     n_kb = -(-cin // cpk)
@@ -107,6 +107,33 @@ def test_implicit_conv_compiles(one_chip, geometry, form):
              ((n // 128, n_kb), jnp.int32), ((n // 128,), jnp.int32),
              row, row if dtype == jnp.int8 else None,
              row if out_scale else None)
+    return mb
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_implicit_conv_compiles(one_chip, geometry, form):
+    _compile_implicit(one_chip, GEOMETRIES[geometry], form, 8, fold=False)
+
+
+# the layers of ResNet-18 (CIFAR form, widths 64-512) whose M-blocks fold
+# whole images at batch 8: (input size, kernel, stride, cin, cout)
+FOLDED = {
+    "4x4_512": (4, 3, 1, 512, 512),
+    "8x8_to_4x4_s2": (8, 3, 2, 256, 512),
+    "1x1_8x8_to_4x4_s2": (8, 1, 2, 256, 512),
+    "8x8_256": (8, 3, 1, 256, 256),
+}
+
+
+@pytest.mark.parametrize("form", ["int8_scale", "int8_out_scale"])
+@pytest.mark.parametrize("geometry", FOLDED)
+def test_folded_implicit_conv_compiles(one_chip, geometry, form):
+    """The folded gather (one window DMA for the block's images, each tap
+    of all of them loaded at once and transposed once) lowers for the
+    v5e at the widths of the layers it serves."""
+    mb = _compile_implicit(one_chip, FOLDED[geometry], form, 8, fold=True)
+    assert mb.ipb > 1 and mb.m_rows <= 128
 
 
 @pytest.mark.parametrize("form", ["f32", "int8_scale", "int8_out_scale"])
