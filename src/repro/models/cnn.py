@@ -1,6 +1,9 @@
-"""The paper's validation network: a ResNet-type CNN with 21 conv layers
-for 32×32×3 / 10-class classification (He et al. CIFAR ResNet-20 + two 1×1
-projection shortcuts = 21 convs, ≈0.046 GOP/image as in paper §IV-B).
+"""ResNets: by default the paper's validation network, a ResNet-type CNN
+with 21 conv layers for 32×32×3 / 10-class classification (He et al.
+CIFAR ResNet-20 + two 1×1 projection shortcuts = 21 convs, ≈0.046
+GOP/image as in paper §IV-B); :class:`ResNetConfig` also describes the
+ImageNet form (7×7/2 stem and max-pool, bottleneck blocks: ResNet-50
+v1.5). :func:`topology` is the one description every walk reads.
 
 Pure-functional JAX: params/state are nested dicts, conv weights in HWIO
 layout (kx, ky, cin, cout) matching ``core.groups.fpga_conv_groups``.
@@ -13,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 import warnings
 from collections import OrderedDict
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,7 @@ import numpy as np
 from ..core import quant as Q
 from ..core.groups import fpga_conv_groups
 from ..accel.cycle_model import ConvLayerDims
+from ..kernels.ops import named_jit
 from ..spans import NO_SPANS
 
 PyTree = Any
@@ -58,6 +62,115 @@ class ResNetConfig:
     quantized: bool = False            # QAT with Q2.5 / Q3.4
     bn_momentum: float = 0.9
     bn_eps: float = 1e-5
+    block: str = "basic"               # "basic" or "bottleneck" (see topology)
+    stem: str = "cifar"                # "cifar" or "imagenet" (see topology)
+
+    def __post_init__(self):
+        if self.block not in EXPANSION or self.stem not in STEMS:
+            raise ValueError(f"unknown block {self.block!r} or stem "
+                             f"{self.stem!r}; known: {sorted(EXPANSION)}, "
+                             f"{sorted(STEMS)}")
+
+
+# output channels of a block per unit of its stage width
+EXPANSION = {"basic": 1, "bottleneck": 4}
+# stem conv (kernel, stride), and whether a 3x3/2 max-pool follows it
+STEMS = {"cifar": (3, 1, False), "imagenet": (7, 2, True)}
+
+
+class ConvSite(NamedTuple):
+    """One conv of the network: the param path of its node (``("conv0",)``,
+    ``("s1b0", "conv2")``), the key of its BatchNorm beside it, kernel
+    size, stride, channels, input size, and whether a ReLU follows its
+    BatchNorm."""
+    path: Tuple[str, ...]
+    bn: str
+    k: int
+    stride: int
+    cin: int
+    cout: int
+    in_size: int
+    relu: bool
+
+    @property
+    def out_size(self) -> int:
+        return -(-self.in_size // self.stride)       # SAME padding
+
+
+class Block(NamedTuple):
+    """A residual block ``relu(main(h) + shortcut(h))``: the main branch's
+    convs in order, and the projection shortcut (``None``: identity)."""
+    name: str
+    convs: Tuple[ConvSite, ...]
+    proj: Optional[ConvSite]
+
+
+def topology(cfg: ResNetConfig) -> Tuple[ConvSite, Tuple[Block, ...]]:
+    """The network's one description: the stem conv and the residual
+    blocks in execution order (the stem's max-pool, where ``cfg.stem``
+    has one, runs between them). A stage's first block strides by 2
+    from stage 1 on. A basic block is 3x3 -> 3x3 at the stage width,
+    stride on its first conv; a bottleneck block is 1x1 -> 3x3 -> 1x1
+    with the last conv at 4x the stage width, stride on the 3x3 (the
+    "v1.5" placement of MLPerf's and torchvision's ResNet-50). A block
+    projects its shortcut (1x1 at the block's stride) where the stride
+    or the width changes."""
+    k, stride, pool = STEMS[cfg.stem]
+    stem = ConvSite(("conv0",), "bn0", k, stride, cfg.in_channels,
+                    cfg.widths[0], cfg.image_size, True)
+    feat = -(-stem.out_size // 2) if pool else stem.out_size
+    cin, blocks = cfg.widths[0], []
+    for si, (n_blocks, width) in enumerate(zip(cfg.stages, cfg.widths)):
+        cout = width * EXPANSION[cfg.block]
+        for bi in range(n_blocks):
+            name = f"s{si}b{bi}"
+            stride = 2 if (si > 0 and bi == 0) else 1
+            out = -(-feat // stride)
+
+            def site(i, k, s, ci, co, size, relu):
+                return ConvSite((name, f"conv{i}"), f"bn{i}", k, s, ci, co,
+                                size, relu)
+            if cfg.block == "bottleneck":
+                convs = (site(1, 1, 1, cin, width, feat, True),
+                         site(2, 3, stride, width, width, feat, True),
+                         site(3, 1, 1, width, cout, out, False))
+            else:
+                convs = (site(1, 3, stride, cin, width, feat, True),
+                         site(2, 3, 1, width, width, out, False))
+            proj = (ConvSite((name, "proj"), "bnp", 1, stride, cin, cout, feat,
+                             False) if stride != 1 or cin != cout else None)
+            blocks.append(Block(name, convs, proj))
+            feat, cin = out, cout
+    return stem, tuple(blocks)
+
+
+def conv_sites(cfg: ResNetConfig):
+    """Every conv of :func:`topology`, in execution order (a block's main
+    branch, then its projection)."""
+    stem, blocks = topology(cfg)
+    return [stem] + [c for b in blocks
+                     for c in b.convs + ((b.proj,) if b.proj else ())]
+
+
+def _node(tree: dict, path: Tuple[str, ...], make: bool = False) -> dict:
+    """The dict that holds the conv at ``path`` (and its BatchNorm): the
+    tree itself for the stem, the block's dict otherwise."""
+    if len(path) == 1:
+        return tree
+    return tree.setdefault(path[0], {}) if make else tree[path[0]]
+
+
+def _max_pool(h):
+    """The ImageNet stem's 3x3 stride-2 max-pool, SAME-padded with the max's
+    identity. It follows a ReLU, so that equals padding with zeros."""
+    init = (-jnp.inf if jnp.issubdtype(h.dtype, jnp.floating)
+            else jnp.iinfo(h.dtype).min)
+    return jax.lax.reduce_window(h, jnp.array(init, h.dtype), jax.lax.max,
+                                 (1, 3, 3, 1), (1, 2, 2, 1), "SAME")
+
+
+# under a named jit: the compiled reduce-window's op_name reads jit(stem_pool)
+stem_pool = named_jit(_max_pool, "stem_pool")
 
 
 def _conv_init(key, kx, ky, cin, cout):
@@ -73,47 +186,21 @@ def _bn_state_init(c):
     return {"mean": jnp.zeros((c,)), "var": jnp.ones((c,))}
 
 
-def _init_key_count(cfg: ResNetConfig) -> int:
-    """Keys :func:`init` consumes: conv0, every block's convs (2, +1 with a
-    projection shortcut), and the fc head."""
-    n, cin = 2, cfg.widths[0]                    # conv0 + fc
-    for si, (n_blocks, width) in enumerate(zip(cfg.stages, cfg.widths)):
-        for bi in range(n_blocks):
-            stride = 2 if (si > 0 and bi == 0) else 1
-            n += 3 if (stride != 1 or cin != width) else 2
-            cin = width
-    return n
-
-
 def init(key: jax.Array, cfg: ResNetConfig) -> Tuple[PyTree, PyTree]:
     """Returns (params, state). state holds BN running stats."""
-    # split sized to the layers this config actually has — a fixed split
-    # count would StopIteration on deep configs (and waste keys on small
-    # ones). NOTE: jax.random.split(key, n)[i] depends on n, so resizing
-    # the split intentionally re-seeds all init streams per config.
-    keys = iter(jax.random.split(key, _init_key_count(cfg)))
-    params: dict = {"conv0": {"w": _conv_init(next(keys), 3, 3, cfg.in_channels, cfg.widths[0])},
-                    "bn0": _bn_init(cfg.widths[0])}
-    state: dict = {"bn0": _bn_state_init(cfg.widths[0])}
-    cin = cfg.widths[0]
-    for si, (n_blocks, width) in enumerate(zip(cfg.stages, cfg.widths)):
-        for bi in range(n_blocks):
-            stride = 2 if (si > 0 and bi == 0) else 1
-            name = f"s{si}b{bi}"
-            blk = {
-                "conv1": {"w": _conv_init(next(keys), 3, 3, cin, width)},
-                "bn1": _bn_init(width),
-                "conv2": {"w": _conv_init(next(keys), 3, 3, width, width)},
-                "bn2": _bn_init(width),
-            }
-            st = {"bn1": _bn_state_init(width), "bn2": _bn_state_init(width)}
-            if stride != 1 or cin != width:
-                blk["proj"] = {"w": _conv_init(next(keys), 1, 1, cin, width)}
-                blk["bnp"] = _bn_init(width)
-                st["bnp"] = _bn_state_init(width)
-            params[name] = blk
-            state[name] = st
-            cin = width
+    # one key per conv and one for the fc head — a fixed split count would
+    # StopIteration on deep configs (and waste keys on small ones). NOTE:
+    # jax.random.split(key, n)[i] depends on n, so resizing the split
+    # intentionally re-seeds all init streams per config.
+    sites = conv_sites(cfg)
+    keys = iter(jax.random.split(key, len(sites) + 1))
+    params: dict = {}
+    state: dict = {}
+    for c in sites:
+        p, s = _node(params, c.path, True), _node(state, c.path, True)
+        p[c.path[-1]] = {"w": _conv_init(next(keys), c.k, c.k, c.cin, c.cout)}
+        p[c.bn], s[c.bn] = _bn_init(c.cout), _bn_state_init(c.cout)
+    cin = c.cout
     params["fc"] = {
         "w": jax.random.normal(next(keys), (cin, cfg.num_classes)) * np.sqrt(1.0 / cin),
         "b": jnp.zeros((cfg.num_classes,)),
@@ -214,31 +301,29 @@ def apply(
                 return fn(h, stride=stride)   # weight prepacked at bind time
         return _conv(h, w, stride)
 
+    def conv_bn(c, h, ns):
+        p, s = _node(params, c.path), _node(state, c.path)
+        y = conv(c.path + ("w",), h, _maybe_qw(p[c.path[-1]]["w"], cfg),
+                 c.stride)
+        y, ns[c.bn] = _bn(y, p[c.bn], s[c.bn], train, cfg)
+        return _maybe_qa(jax.nn.relu(y), cfg) if c.relu else y
+
     new_state: dict = {}
+    stem, blocks = topology(cfg)
     # the accelerator ingests Q3.4 activations for every layer, the input
     # frame included — quantize it so the executed-int8 path can match the
     # QAT forward exactly on codes (images are 8-bit sources anyway)
-    h = conv(("conv0", "w"), _maybe_qa(x, cfg), _maybe_qw(params["conv0"]["w"], cfg), 1)
-    h, new_state["bn0"] = _bn(h, params["bn0"], state["bn0"], train, cfg)
-    h = _maybe_qa(jax.nn.relu(h), cfg)
-    for si, n_blocks in enumerate(cfg.stages):
-        for bi in range(n_blocks):
-            name = f"s{si}b{bi}"
-            blk, st = params[name], state[name]
-            stride = 2 if (si > 0 and bi == 0) else 1
-            ns: dict = {}
-            y = conv((name, "conv1", "w"), h, _maybe_qw(blk["conv1"]["w"], cfg), stride)
-            y, ns["bn1"] = _bn(y, blk["bn1"], st["bn1"], train, cfg)
-            y = _maybe_qa(jax.nn.relu(y), cfg)
-            y = conv((name, "conv2", "w"), y, _maybe_qw(blk["conv2"]["w"], cfg), 1)
-            y, ns["bn2"] = _bn(y, blk["bn2"], st["bn2"], train, cfg)
-            if "proj" in blk:
-                sc = conv((name, "proj", "w"), h, _maybe_qw(blk["proj"]["w"], cfg), stride)
-                sc, ns["bnp"] = _bn(sc, blk["bnp"], st["bnp"], train, cfg)
-            else:
-                sc = h
-            h = _maybe_qa(jax.nn.relu(y + sc), cfg)
-            new_state[name] = ns
+    h = conv_bn(stem, _maybe_qa(x, cfg), new_state)
+    if STEMS[cfg.stem][2]:
+        h = stem_pool(h)
+    for blk in blocks:
+        ns: dict = {}
+        y = h
+        for c in blk.convs:
+            y = conv_bn(c, y, ns)
+        sc = h if blk.proj is None else conv_bn(blk.proj, h, ns)
+        h = _maybe_qa(jax.nn.relu(y + sc), cfg)
+        new_state[blk.name] = ns
     return _logits(jnp.mean(h, axis=(1, 2)), params["fc"]), new_state
 
 
@@ -622,8 +707,11 @@ class SparseConvExec:
         ``images_per_block`` is each layer's fold at ``batch``
         (:meth:`images_per_block`), which the grid-step fields follow.
         ``per_layer=True`` adds the same fields, plus
-        the layer's ``path``, per conv layer (keys ``"/".join(path)``),
-        which is what the simulator reports next to the cycle model.
+        the layer's ``path``, its ``kernel`` size and ``k_row_fill`` (the
+        share of its dispatched K-tile rows that hold a weight,
+        :meth:`~repro.sparse.conv_plan.ConvGemmLayout.k_row_fill`), per
+        conv layer (keys ``"/".join(path)``), which is what the simulator
+        reports next to the cycle model.
 
         ``dsb_sample`` (with ``dsb_tree``, the tree this exec was bound
         from, and ``dsb_state`` for non-folded execs) additionally runs
@@ -679,7 +767,8 @@ class SparseConvExec:
                           dtype_bytes: int, routes: dict) -> dict:
         from ..sparse.conv_plan import conv_hbm_bytes
         out = {}
-        for path, stride, feat in conv_layer_order(cfg):
+        for c in conv_sites(cfg):
+            path, stride, feat = c.path + ("w",), c.stride, c.in_size
             plan = self.plans[path]
             mb, bm_eff, ipb = self._m_blocks(path, stride, feat, batch)
             hbm = lambda imp, bm, ob, out_b=None: conv_hbm_bytes(
@@ -694,6 +783,9 @@ class SparseConvExec:
                 "dense": mb * plan.tiles[0] * plan.tiles[1],
                 "bm_effective": bm_eff,
                 "images_per_block": ipb,
+                "kernel": c.k,
+                "k_row_fill": self.layouts[path].k_row_fill(
+                    self.group_masks_np[path]),
                 "hbm_materialized": hbm(False, 128, dtype_bytes),
                 "hbm_implicit": hbm(True, "auto", dtype_bytes),
                 "hbm_materialized_int8": hbm(False, 128, 1),
@@ -829,10 +921,11 @@ def bind_execution(
 
     ``spec.folded=True``: ``params`` is ``fold_batchnorm`` output (per-conv
     ``{"w", "b"}``) and the bias — plus ReLU where the network applies it
-    directly after BN (conv0, every conv1) — is fused at the kernel's
-    flush step. With ``spec.quantized`` each layer gets **per-cout
-    calibrated** weight scales (BN folding scales channels arbitrarily, so
-    the static Q2.5 grid would clip); ``quant_spec`` is rejected here.
+    directly after BN (:func:`topology`; ``cfg`` is required) — is fused
+    at the kernel's flush step. With ``spec.quantized`` each layer gets
+    **per-cout calibrated** weight scales (BN folding scales channels
+    arbitrarily, so the static Q2.5 grid would clip); ``quant_spec`` is
+    rejected here.
     Consume with :func:`apply_folded`.
 
     ``spec.streamed=True`` (implies ``quantized`` + ``folded``): every
@@ -873,21 +966,22 @@ def bind_execution(
     from ..sparse.conv_plan import make_sparse_conv
 
     spec = ExecSpec() if spec is None else spec
-    # (input size, input size, stride, padding) per conv: with a config
-    # each layer's route is chosen once, here, from these shapes
-    geometry = ({path: (feat, feat, stride, "SAME")
-                 for path, stride, feat in conv_layer_order(cfg)}
-                if cfg is not None else {})
+    # each conv's site: with a config each layer's route is chosen once,
+    # here, from its input size and stride
+    sites = ({c.path + ("w",): c for c in conv_sites(cfg)}
+             if cfg is not None else {})
 
     def bind_conv(keys, layout, gm, **kw):
         """One layer onto the kernels under ``spec``; a layer the bind
         contract cannot serve (e.g. ``activation_dsb`` at a geometry the
         implicit kernel does not fit) is a :class:`PermanentBindError`."""
+        c = sites.get(keys)
         try:
-            return make_sparse_conv(layout, gm, bm=spec.bm,
-                                    implicit=spec.implicit,
-                                    geometry=geometry.get(keys),
-                                    name=conv_kernel_name(keys), **kw)
+            return make_sparse_conv(
+                layout, gm, bm=spec.bm, implicit=spec.implicit,
+                geometry=(None if c is None
+                          else (c.in_size, c.in_size, c.stride, "SAME")),
+                name=conv_kernel_name(keys), **kw)
         except ValueError as e:
             raise PermanentBindError(f"{'/'.join(keys)}: {e}") from e
 
@@ -897,6 +991,10 @@ def bind_execution(
                 "folded binds calibrate per-cout scales per layer — a "
                 "global quant_spec would clip BN-scaled channels; it is "
                 "plain-exec only")
+        if cfg is None:
+            raise PermanentBindError(
+                "a folded bind fuses the ReLU that follows a conv, which "
+                "the topology gives — pass cfg")
         tree = {k: v for k, v in params.items() if k != "fc"}
         weight_of = lambda l: l
         # streamed wire: every layer emits AND ingests the same static
@@ -909,7 +1007,6 @@ def bind_execution(
             if not bind_kernels or plan.density >= spec.dense_fallback:
                 return None
             bias = _get_path(params, keys[:-1])["b"]
-            relu = keys[-2] in ("conv0", "conv1")   # ReLU directly after BN
             quant = Q.QuantSpec.calibrate(w) if spec.quantized else None
             if out_q is not None and quant.act_scale != out_q.act_scale:
                 raise PermanentBindError(
@@ -918,7 +1015,8 @@ def bind_execution(
                     f"the wire emits {out_q.act_scale} — streaming needs a "
                     "uniform per-layer scale chain")
             return bind_conv(keys, layout, gm, weight=w, bias=bias,
-                             relu=relu, quant=quant, out_quant=out_q,
+                             relu=sites[keys].relu, quant=quant,
+                             out_quant=out_q,
                              activation_dsb=spec.activation_dsb)
     else:
         if quant_spec is not None and not spec.quantized:
@@ -1097,22 +1195,7 @@ def _resolve_sparse(sparse, params, quantized: bool = False) -> Optional[SparseC
 def conv_layer_order(cfg: ResNetConfig):
     """Execution-order list of (param-path, stride, input_feature_size) for
     every conv layer (21 for the default config)."""
-    order = [(("conv0", "w"), 1, cfg.image_size)]
-    feat = cfg.image_size
-    cin = cfg.widths[0]
-    for si, n_blocks in enumerate(cfg.stages):
-        for bi in range(n_blocks):
-            name = f"s{si}b{bi}"
-            stride = 2 if (si > 0 and bi == 0) else 1
-            width = cfg.widths[si]
-            out = -(-feat // stride)
-            order.append(((name, "conv1", "w"), stride, feat))
-            order.append(((name, "conv2", "w"), 1, out))
-            if stride != 1 or cin != width:
-                order.append(((name, "proj", "w"), stride, feat))
-            feat = out
-            cin = width
-    return order
+    return [(c.path + ("w",), c.stride, c.in_size) for c in conv_sites(cfg)]
 
 
 def layer_dims(cfg: ResNetConfig, params: PyTree):
@@ -1143,22 +1226,12 @@ def fold_batchnorm(params: PyTree, state: PyTree, cfg: ResNetConfig) -> PyTree:
     folding unchanged — this is what the accelerator executes.
     """
     folded = {}
-
-    def fold_one(w, bnp, bns):
-        g = bnp["scale"] * jax.lax.rsqrt(bns["var"] + cfg.bn_eps)
-        return w * g[None, None, None, :], bnp["bias"] - bns["mean"] * g
-
-    folded["conv0"] = dict(zip(("w", "b"), fold_one(params["conv0"]["w"], params["bn0"], state["bn0"])))
-    for si, n_blocks in enumerate(cfg.stages):
-        for bi in range(n_blocks):
-            name = f"s{si}b{bi}"
-            blk, st = params[name], state[name]
-            out = {}
-            out["conv1"] = dict(zip(("w", "b"), fold_one(blk["conv1"]["w"], blk["bn1"], st["bn1"])))
-            out["conv2"] = dict(zip(("w", "b"), fold_one(blk["conv2"]["w"], blk["bn2"], st["bn2"])))
-            if "proj" in blk:
-                out["proj"] = dict(zip(("w", "b"), fold_one(blk["proj"]["w"], blk["bnp"], st["bnp"])))
-            folded[name] = out
+    for c in conv_sites(cfg):
+        p, s = _node(params, c.path), _node(state, c.path)
+        g = p[c.bn]["scale"] * jax.lax.rsqrt(s[c.bn]["var"] + cfg.bn_eps)
+        _node(folded, c.path, True)[c.path[-1]] = {
+            "w": p[c.path[-1]]["w"] * g[None, None, None, :],
+            "b": p[c.bn]["bias"] - s[c.bn]["mean"] * g}
     folded["fc"] = dict(params["fc"])
     return folded
 
@@ -1230,23 +1303,25 @@ def apply_folded(
         y = jax.nn.relu(y) if relu else y
         return requant(y) if wire else y
 
-    h = conv(("conv0", "w"), x, 1, relu=True)
-    for si, n_blocks in enumerate(cfg.stages):
-        for bi in range(n_blocks):
-            name = f"s{si}b{bi}"
-            blk = folded[name]
-            stride = 2 if (si > 0 and bi == 0) else 1
-            y = conv((name, "conv1", "w"), h, stride, relu=True)
-            y = conv((name, "conv2", "w"), y, 1, relu=False)
-            sc = (conv((name, "proj", "w"), h, stride, relu=False)
-                  if "proj" in blk else h)
-            if wire:
-                # residual add + ReLU on codes: int32 widen, clamp to the
-                # post-ReLU code range — exact integer arithmetic
-                h = jnp.clip(y.astype(jnp.int32) + sc.astype(jnp.int32),
-                             0, int(max_code)).astype(jnp.int8)
-            else:
-                h = jax.nn.relu(y + sc)
+    def run(c, h):
+        return conv(c.path + ("w",), h, c.stride, c.relu)
+
+    stem, blocks = topology(cfg)
+    h = run(stem, x)
+    if STEMS[cfg.stem][2]:
+        h = stem_pool(h)
+    for blk in blocks:
+        y = h
+        for c in blk.convs:
+            y = run(c, y)
+        sc = h if blk.proj is None else run(blk.proj, h)
+        if wire:
+            # residual add + ReLU on codes: int32 widen, clamp to the
+            # post-ReLU code range — exact integer arithmetic
+            h = jnp.clip(y.astype(jnp.int32) + sc.astype(jnp.int32),
+                         0, int(max_code)).astype(jnp.int8)
+        else:
+            h = jax.nn.relu(y + sc)
     if wire:
         h = h.astype(jnp.float32) / wire_scale        # head: exact dequant
     return _logits(jnp.mean(h, axis=(1, 2)), folded["fc"])

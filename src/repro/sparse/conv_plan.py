@@ -161,6 +161,22 @@ class ConvGemmLayout:
                            for dx in range(geo["ky"])], np.int32)
         return entries, plan.cnt.copy(), taps
 
+    def k_row_fill(self, group_mask) -> float:
+        """Share of the dispatched K-tiles' rows that hold a weight of the
+        conv (one tap of one input channel), the rest being slot and tile
+        padding — read from the packing, not timed. A full packed tile
+        holds 16 of 128 (1x1), 72 (3x3) or 98 (7x7)."""
+        bk = self.block[0]
+        t = np.arange(self.tiles[0])
+        geo = self.implicit_geometry()
+        if geo is None:                  # K tap-major over the 2-D matrix
+            real = np.clip(self.spec.shape[0] - t * bk, 0, bk)
+        else:
+            cin, cpk = self.spec.shape[2], geo["cpk"]
+            real = np.clip(cin - t * cpk, 0, cpk) * geo["kx"] * geo["ky"]
+        live = self.tile_mask(group_mask).sum(axis=1)
+        return float((real * live).sum() / max(live.sum() * bk, 1))
+
     def tile_occupancy(self, group_mask) -> Tuple[np.ndarray, np.ndarray]:
         """(live, total) schedule groups covered per tile, (nKb, nNb) ints.
 

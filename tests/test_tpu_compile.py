@@ -136,6 +136,33 @@ def test_folded_implicit_conv_compiles(one_chip, geometry, form):
     assert mb.ipb > 1 and mb.m_rows <= 128
 
 
+# ResNet-50's geometries at 224x224 that no CIFAR layer has:
+# (input size, kernel, stride, cin, cout)
+RESNET50 = {
+    "stem_7x7_s2": (224, 7, 2, 3, 64),
+    "1x1_cin2048": (7, 1, 1, 2048, 512),
+    "1x1_s2": (56, 1, 2, 256, 512),
+    "3x3_s2_to_7x7": (14, 3, 2, 512, 512),
+    "7x7_out": (7, 3, 1, 512, 512),
+}
+
+
+@pytest.mark.parametrize("batch", [1, 128])
+@pytest.mark.parametrize("geometry", RESNET50)
+def test_resnet50_implicit_conv_compiles(one_chip, geometry, batch):
+    """The streamed int8 form of each new geometry lowers for the v5e at
+    buckets 1 and 128, inside the VMEM window budget that keeps it on
+    the implicit route; 7x7 outputs (49 pixels in 56 rows) run one image
+    a block."""
+    h, k, stride, _, _ = RESNET50[geometry]
+    mb = _compile_implicit(one_chip, RESNET50[geometry], "int8_out_scale",
+                           batch, fold=True)
+    assert IC.window_vmem_bytes(mb, k, k, stride, 128, 1) <= \
+        IC.SLAB_VMEM_BUDGET
+    if conv_out_size(h, k, stride, "SAME") == 7:
+        assert (mb.bm, mb.ipb) == (56, 1)
+
+
 @pytest.mark.parametrize("form", ["f32", "int8_scale", "int8_out_scale"])
 @pytest.mark.parametrize("bm", [8, 128])
 def test_block_sparse_matmul_compiles(one_chip, form, bm):
