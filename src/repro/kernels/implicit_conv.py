@@ -33,7 +33,10 @@ contract on the Pallas grid:
   ``c·slot + tap`` of a transposed ``(bk, bm)`` patch tile; one
   transpose back, a cast to the operand dtype, and one MXU dot per live
   step. The tile is element-for-element the materializing tile, so the
-  two paths feed the MXU identical operands.
+  two paths feed the MXU identical operands. A 1x1 conv's K-tile is one
+  whole 128-lane channel group, a row a channel (:func:`pointwise`): its
+  patch tile is the (strided) window load itself, so that step runs no
+  transpose, no strided store and no zeroed patch tile.
 - M-blocking is **adaptive**: an image's M-block is ``block_oh`` whole
   output rows (``bm = ceil8(block_oh·Wo) ≤ cap`` — a batch-1 4×4 tail
   runs at ``bm=16`` instead of padding to 128), and when even one output
@@ -272,18 +275,29 @@ def implicit_hbm_bytes(batch: int, h: int, w: int, cin: int, c_packed: int,
             mb.ipb * rows * dma_cols(mb, kx, ky, stride) * LANES * itemsize)
 
 
+def pointwise(kx: int, ky: int, bk: int) -> bool:
+    """Whether a K-tile is one whole 128-lane channel group, a row a
+    channel: a 1x1 conv on 128-row K-tiles (``conv_plan.channel_slot``
+    gives its channels one row each, so ``cpk == bk == 128``). Its patch
+    tile is then the window's pixels, channels in lane order."""
+    return kx * ky == 1 and bk == LANES
+
+
 def window_vmem_bytes(mb: MBlock, kx: int, ky: int, stride: int, bk: int,
                       itemsize: int) -> int:
     """Padded VMEM bytes of the implicit kernel's window working set: both
     DMA slab slots of the block's ``ipb`` windows (a window carries its
     whole 128-lane channel group), the 32-bit working copy of the live
-    slab (int8 / bf16 operands), and the two transposed ``(128, ipb·bm)``
-    / ``(bk, ipb·bm)`` tap and patch tiles."""
+    slab (int8 / bf16 operands), and, off the :func:`pointwise` path, the
+    two transposed ``(128, ipb·bm)`` / ``(bk, ipb·bm)`` tap and patch
+    tiles."""
     rows = window_shape(mb, kx, ky, stride)[0]
     cols = dma_cols(mb, kx, ky, stride)
     slab = _tiled_bytes((2, mb.ipb, rows, cols, LANES), itemsize)
     work = (0 if itemsize == 4
             else _tiled_bytes((mb.ipb, rows, cols, LANES), 4))
+    if pointwise(kx, ky, bk):
+        return slab + work
     return (slab + work + _tiled_bytes((LANES, mb.m_rows), 4)
             + _tiled_bytes((bk, mb.m_rows), 4))
 
@@ -291,11 +305,12 @@ def window_vmem_bytes(mb: MBlock, kx: int, ky: int, stride: int, bk: int,
 def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
             kx, ky, stride, block_oh, block_ow, spi, bpi, ipb, cpk, slot,
             acc_dtype, has_scale, has_bias, has_out, relu, activation_dsb,
-            count_skips, widen):
+            count_skips, widen, by_tap):
     n_ep = int(has_scale) + int(has_bias) + int(has_out)
     skip_ref = refs[n_ep + 1] if count_skips else None
-    n_scratch = 6 if widen else 5
-    acc_ref, slab_ref, *work, tap_ref, patch_ref, sem_ref = refs[-n_scratch:]
+    n_scratch = 3 + int(widen) + 2 * int(by_tap)
+    acc_ref, slab_ref, *mid, sem_ref = refs[-n_scratch:]
+    tap_ref, patch_ref = mid[-2:] if by_tap else (None, None)
     scale_ref, b_ref, out_ref, o_ref, _ = unpack_epilogue_refs(
         (*refs[:n_ep + 1], acc_ref), has_scale, has_bias, has_out)
     i, j, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -324,8 +339,9 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
     @pl.when(s == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        # unused tap slots and rows past the M-block stay zero
-        patch_ref[...] = jnp.zeros_like(patch_ref)
+        if by_tap:
+            # unused tap slots and rows past the M-block stay zero
+            patch_ref[...] = jnp.zeros_like(patch_ref)
         if count_skips:
             skip_ref[0, 0, 0] = 0
 
@@ -344,7 +360,7 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
         # Mosaic loads strided windows of 32-bit data only: int8 / bf16
         # slabs are widened once per step into the working copy
         if widen:
-            win_ref = work[0]
+            win_ref = mid[0]
             win_ref[...] = slab_ref[buf].astype(win_ref.dtype)
         else:
             win_ref = slab_ref.at[buf]
@@ -364,6 +380,19 @@ def _kernel(idx_ref, cnt_ref, x_ref, w_ref, *refs,
             # per block
             lead = () if ipb == 1 else (slice(None),)
             mt = ipb * m
+            if not by_tap:
+                # pointwise: the K-tile's channels are the window's 128
+                # lanes in order, so the one (strided) load is the patch
+                # tile; accumulator rows past the block's pixels stay
+                # zero, as the by-tap path's zeroed patch rows leave them
+                pt = win_ref[(*lead, pl.ds(0, block_oh, stride=stride),
+                              pl.ds(0, block_ow, stride=stride),
+                              slice(None))].reshape(mt, LANES)
+                acc_ref[pl.ds(0, mt), :] += jnp.dot(
+                    pt.astype(w_ref.dtype), w_ref[...],
+                    preferred_element_type=acc_dtype,
+                    precision=mxu_precision(w_ref.dtype))
+                return
             for dy in range(kx):
                 for dx in range(ky):
                     tap = win_ref[(*lead, pl.ds(dy, block_oh, stride=stride),
@@ -462,6 +491,10 @@ def implicit_block_sparse_conv(
             and (mb.spi - 1) * mb.block_ow * stride + cols <= Wp), (
         f"window slab out of bounds: pad_input() with this MBlock first "
         f"(xp {xp.shape}, mb {mb}, k ({kx},{ky}), stride {stride})")
+    by_tap = not pointwise(kx, ky, bk)
+    assert by_tap or (cpk, slot) == (LANES, 1), (
+        f"a 1x1 conv's 128-row K-tile packs 128 channels in one-row "
+        f"slots, got cpk={cpk}, slot={slot}")
     acc_dtype, out_dtype = quantized_contract(xp, w, scale, out_scale)
     nNb = w.shape[1] // bn
     max_nnz = idx.shape[1]
@@ -506,9 +539,10 @@ def implicit_block_sparse_conv(
                pltpu.VMEM((2, *win), xp.dtype)]
     if widen:
         scratch.append(pltpu.VMEM(win, wide_dtype))
-    scratch += [pltpu.VMEM((LANES, mb.m_rows), wide_dtype),
-                pltpu.VMEM((bk, mb.m_rows), wide_dtype),
-                pltpu.SemaphoreType.DMA((2,))]
+    if by_tap:
+        scratch += [pltpu.VMEM((LANES, mb.m_rows), wide_dtype),
+                    pltpu.VMEM((bk, mb.m_rows), wide_dtype)]
+    scratch.append(pltpu.SemaphoreType.DMA((2,)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_blocks, nNb, max_nnz),
@@ -524,7 +558,8 @@ def implicit_block_sparse_conv(
                           acc_dtype=acc_dtype, has_scale=has_scale,
                           has_bias=has_bias, has_out=has_out, relu=relu,
                           activation_dsb=activation_dsb,
-                          count_skips=count_skips, widen=widen),
+                          count_skips=count_skips, widen=widen,
+                          by_tap=by_tap),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,

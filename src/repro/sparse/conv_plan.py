@@ -20,9 +20,11 @@ Three layouts:
   only ``9·n_cu / (16·128)`` of each dispatched tile.
 - :class:`PackedFpgaConvGemmLayout` (``conv_gemm_layout(spec,
   packed=True)``): the TPU-efficiency layout. Each K-tile packs
-  ``bk // ceil8(kx·ky)`` input channels (one 8-aligned row *slot* per
-  channel) and each N-tile packs ``bn // n_cu`` f_blocks, so the tile
-  shape matches the 128-deep MXU datapath instead of one group. A tile is
+  ``bk // slot`` input channels, one row *slot* per channel
+  (:func:`channel_slot`: 8-aligned, one row for a 1x1, whose tile then
+  holds a whole 128-lane channel group) and each N-tile packs ``bn //
+  n_cu`` f_blocks, so the tile shape matches the 128-deep MXU datapath
+  instead of one group. A tile is
   live iff *any* covered (g, f_block) group is live; pruned groups inside
   a live tile are zero slabs in the packed (masked) weight, so the GEMM
   stays exact. Paper-granularity accounting survives through
@@ -60,6 +62,16 @@ from .block_mask import BlockSparsePlan, plan_from_tile_mask, transpose_plan
 
 def _ceil_to(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def channel_slot(kx: int, ky: int) -> int:
+    """Rows of a packed K-tile one input channel's ``kx·ky`` taps take:
+    ``kx·ky`` rounded up to the 8-row sublane tile, except a 1x1's single
+    tap, which takes one row, so its tile holds 128 channels, one
+    128-lane group of the activation, and the implicit kernel's patch
+    tile is that group's window itself."""
+    kxky = kx * ky
+    return 1 if kxky == 1 else _ceil_to(kxky, 8)
 
 
 def mask_fingerprint(group_masks) -> str:
@@ -165,7 +177,7 @@ class ConvGemmLayout:
         """Share of the dispatched K-tiles' rows that hold a weight of the
         conv (one tap of one input channel), the rest being slot and tile
         padding — read from the packing, not timed. A full packed tile
-        holds 16 of 128 (1x1), 72 (3x3) or 98 (7x7)."""
+        holds 128 of 128 (1x1), 72 (3x3) or 98 (7x7)."""
         bk = self.block[0]
         t = np.arange(self.tiles[0])
         geo = self.implicit_geometry()
@@ -272,21 +284,20 @@ class FpgaConvGemmLayout(ConvGemmLayout):
 
 @dataclasses.dataclass(frozen=True)
 class PackedFpgaConvGemmLayout(ConvGemmLayout):
-    """Multi-group tiles: ``cpk = bk // ceil8(kx·ky)`` input channels per
-    K-tile (channel ``g`` -> tile ``g // cpk``, row slot ``g % cpk``) and
-    ``fpn = bn // n_cu`` f_blocks per N-tile (f_block ``f`` -> tile
-    ``f // fpn``, lane slot ``f % fpn``). A tile is live iff any covered
-    group is — pruned groups inside live tiles are zeros in the packed
-    masked weight, so the GEMM stays exact while the grid shrinks by up to
-    ``cpk·fpn`` over the one-group-per-tile layout."""
+    """Multi-group tiles: ``cpk = bk // channel_slot(kx, ky)`` input
+    channels per K-tile (channel ``g`` -> tile ``g // cpk``, row slot
+    ``g % cpk``) and ``fpn = bn // n_cu`` f_blocks per N-tile (f_block
+    ``f`` -> tile ``f // fpn``, lane slot ``f % fpn``). A tile is live
+    iff any covered group is — pruned groups inside live tiles are zeros
+    in the packed masked weight, so the GEMM stays exact while the grid
+    shrinks by up to ``cpk·fpn`` over the one-group-per-tile layout."""
 
     def _packing(self):
         kx, ky, cin, cout = self.spec.shape
         n_cu, n_fb = self.spec.n_cu, self.spec.n_fblocks
         bk, bn = self.block
-        kxky = kx * ky
-        slot = _ceil_to(kxky, 8)
-        return kxky, cin, cout, n_cu, n_fb, slot, bk // slot, bn // n_cu
+        slot = channel_slot(kx, ky)
+        return kx * ky, cin, cout, n_cu, n_fb, slot, bk // slot, bn // n_cu
 
     def implicit_geometry(self) -> Optional[dict]:
         kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
@@ -387,15 +398,14 @@ def conv_gemm_layout(spec: GroupSpec, *, bn: int = 128, packed: bool = False,
         kx, ky, cin, cout = spec.shape
         if spec.n_cu > bn:
             raise ValueError(f"n_cu={spec.n_cu} exceeds the {bn}-lane tile")
-        kxky = kx * ky
         if packed:
-            slot = _ceil_to(kxky, 8)
+            slot = channel_slot(kx, ky)
             bk_eff = max(bk, slot)          # giant kernels: one channel/tile
             cpk, fpn = bk_eff // slot, bn // spec.n_cu
             return PackedFpgaConvGemmLayout(
                 spec=spec, block=(bk_eff, bn),
                 tiles=(-(-cin // cpk), -(-spec.n_fblocks // fpn)))
-        bk_pg = max(8, _ceil_to(kxky, 8))
+        bk_pg = max(8, _ceil_to(kx * ky, 8))
         return FpgaConvGemmLayout(spec=spec, block=(bk_pg, bn),
                                   tiles=(cin, spec.n_fblocks))
     if isinstance(spec, TpuTileGroupSpec):

@@ -217,6 +217,79 @@ def test_folded_implicit_conv_matches_materializing(h, k, stride, batch,
                                    rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("k,cin,slot,cpk", [
+    (1, 64, 1, 128), (1, 256, 1, 128), (1, 2048, 1, 128),
+    (3, 512, 16, 8), (7, 3, 56, 2)])
+def test_packed_layout_channel_slot(k, cin, slot, cpk):
+    """A packed 1x1 K-tile holds 128 channels, one row each, so its tile
+    is one 128-lane channel group of the activation; 3x3 and 7x7
+    channels keep their 8-aligned slots of 16 and 56 rows."""
+    layout = conv_gemm_layout(fpga_conv_groups((k, k, cin, 64), 12),
+                              packed=True)
+    geo = layout.implicit_geometry()
+    assert (geo["slot"], geo["cpk"]) == (slot, cpk)
+    assert layout.block[0] == 128 and layout.tiles[0] == -(-cin // cpk)
+    assert IC.pointwise(k, k, layout.block[0]) == (k == 1)
+
+
+# 1x1 convs on the pointwise gather: (input size, stride, cin, batch).
+# Outputs 7x7 (one image a block), 14x14 (two blocks an image) and 4x4
+# (8 images folded into a block); cin 64 is one part-filled K-tile
+POINTWISE = [(7, 1, 2048, 2), (14, 2, 256, 2), (14, 1, 64, 1),
+             (28, 2, 256, 1), (4, 1, 256, 8), (8, 2, 64, 8)]
+
+
+def _pointwise_mask(rng, spec, layout):
+    """Groups live at random, with K-tile 1 pruned whole and K-tile 0
+    pruned in N-tile 1, so the table skips both where they exist."""
+    cin, n_fb = spec.shape[2], spec.n_fblocks
+    g = (rng.rand(cin, n_fb) < 0.6).astype(np.float32)
+    fpn = layout.block[1] // spec.n_cu
+    g[128:256] = 0.0
+    g[:128, fpn:2 * fpn] = 0.0
+    return g.reshape(-1)
+
+
+@pytest.mark.parametrize("h,stride,cin,batch", POINTWISE)
+def test_pointwise_conv_matches_materializing(h, stride, cin, batch):
+    """The 1x1 kernel, whose patch tile is the window load itself, equals
+    the materializing oracle code for code in the streamed int8 form, at
+    stride 1 and 2, unfolded, split and folded M-blocks; with the
+    activation skip on, a K-tile whose window is all zero codes skips
+    and the codes still match."""
+    from repro.core import QuantSpec
+    rng = np.random.RandomState(h * 10_000 + cin * 10 + stride + batch)
+    cout, n_cu = 160, 12                    # two N-tiles of 10 f_blocks
+    spec = fpga_conv_groups((1, 1, cin, cout), n_cu)
+    layout = conv_gemm_layout(spec, packed=True)
+    gm = _pointwise_mask(rng, spec, layout)
+    plan = layout.plan(gm)
+    assert plan.cnt[1] < plan.cnt[0] <= layout.tiles[0]
+    w = jnp.asarray(rng.uniform(-1, 1, (1, 1, cin, cout)), jnp.float32)
+    x = rng.uniform(-2, 2, (batch, h, h, cin)).astype(np.float32)
+    x[..., :min(cin, 128) // 2] = 0.0       # half of K-tile 0's channels
+    kw = dict(weight=w, quant=QuantSpec(), out_quant=QuantSpec(), relu=True,
+              bias=jnp.asarray(rng.uniform(-1, 1, cout), jnp.float32))
+    convs = [make_sparse_conv(layout, gm, implicit=i, **kw)
+             for i in (True, False)]
+    outs = [np.asarray(c(jnp.asarray(x), stride=stride)) for c in convs]
+    assert outs[0].dtype == np.int8 and np.ptp(outs[0]) > 0
+    np.testing.assert_array_equal(outs[0], outs[1])
+    ho = -(-h // stride)
+    mb = convs[0].m_block(h, h, stride, "SAME", batch)
+    assert (mb.bpi, mb.ipb) == {7: (1, 1), 14: (2, 1), 4: (1, 8)}[ho]
+
+    dsb = make_sparse_conv(layout, gm, implicit=True, activation_dsb=True,
+                           **kw)
+    if cin > 128:
+        x[..., :128] = 0.0                  # K-tile 0's window all zero
+    y, stats = dsb.skip_counts(jnp.asarray(x), stride=stride)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.asarray(convs[1](jnp.asarray(x),
+                                                      stride=stride)))
+    assert (stats["skipped_steps"] > 0) == (cin > 128)
+
+
 def test_images_per_block_rule():
     """The fold: the largest divisor of the batch whose images fit the
     128-row cap, only where one image's pixels fill one block, never

@@ -135,14 +135,16 @@ def test_cifar_layer_order_and_param_tree_unchanged(name):
     assert set(state["bn0"]) == {"mean", "var"}
 
 
-@pytest.mark.parametrize("k, cin, fill", [(1, 2048, 16 / 128),
+@pytest.mark.parametrize("k, cin, fill", [(1, 2048, 1.0),
+                                          (1, 64, 64 / 128),
                                           (3, 512, 72 / 128),
                                           (7, 64, 98 / 128),
                                           (7, 3, (98 + 49) / 256)])
 def test_k_row_fill_of_the_packed_layout(k, cin, fill):
     """Rows of a dispatched packed K-tile that hold a weight: a channel's
-    ``k*k`` taps in a slot of ``ceil8(k*k)`` rows, ``128 // slot``
-    channels a tile, the last tile holding what is left of ``cin``."""
+    ``k*k`` taps in a slot of ``ceil8(k*k)`` rows (one row for a 1x1),
+    ``128 // slot`` channels a tile, the last tile holding what is left
+    of ``cin``."""
     layout = conv_gemm_layout(fpga_conv_groups((k, k, cin, 64), 12),
                               packed=True)
     assert layout.k_row_fill(np.ones(layout.spec.num_groups)) == fill
@@ -202,7 +204,7 @@ def test_bottleneck_int8_served_equals_the_integer_twin(bottleneck):
             for p, d in rep["per_layer"].items()
             if p in ("conv0/w", "s0b1/conv1/w", "s1b0/conv2/w")} == {
         "conv0/w": (7, round(3 * 49 / 256, 4)),
-        "s0b1/conv1/w": (1, 16 / 128), "s1b0/conv2/w": (3, 72 / 128)}
+        "s0b1/conv1/w": (1, 32 / 128), "s1b0/conv2/w": (3, 72 / 128)}
     got = np.asarray(server.infer(frames))
     want = np.asarray(resnet_ref.forward_int8(pruned, state, cfg, frames))
     assert np.ptp(want) > 0

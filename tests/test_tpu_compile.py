@@ -21,6 +21,7 @@ from repro.kernels.block_sparse_matmul import (block_sparse_grad_weight,
                                                block_sparse_matmul)
 from repro.kernels.conv_lowering import conv_out_size, same_pads
 from repro.kernels.int8_matmul import int8_matmul
+from repro.sparse.conv_plan import channel_slot
 
 
 @pytest.fixture(scope="module")
@@ -88,7 +89,7 @@ def _compile_implicit(one_chip, geometry, form, batch, fold):
     dtype, out_scale, dsb = FORMS[form]
     ho = conv_out_size(h, k, stride, "SAME")
     mb = IC.choose_m_block(ho, ho, batch=batch if fold else 1)
-    slot = -(-k * k // 8) * 8
+    slot = channel_slot(k, k)
     cpk = 128 // slot
     n_kb = -(-cin // cpk)
     rows, cols = IC.window_shape(mb, k, k, stride)
@@ -134,6 +135,16 @@ def test_folded_implicit_conv_compiles(one_chip, geometry, form):
     v5e at the widths of the layers it serves."""
     mb = _compile_implicit(one_chip, FOLDED[geometry], form, 8, fold=True)
     assert mb.ipb > 1 and mb.m_rows <= 128
+
+
+@pytest.mark.parametrize("form", ["int8_scale", "int8_out_scale"])
+def test_folded_pointwise_conv_compiles_at_bucket_128(one_chip, form):
+    """ResNet-18's stride-2 projection onto 4x4 outputs at bucket 128:
+    the pointwise gather (the window load is the patch tile) of 8
+    images folded into a 128-row block lowers for the v5e."""
+    mb = _compile_implicit(one_chip, FOLDED["1x1_8x8_to_4x4_s2"], form, 128,
+                           fold=True)
+    assert mb.ipb == 8 and mb.m_rows == 128
 
 
 # ResNet-50's geometries at 224x224 that no CIFAR layer has:
