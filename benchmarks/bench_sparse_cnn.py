@@ -1,5 +1,5 @@
 """Executed group sparsity: HAPM masks through the Pallas DSB kernels, on
-both tile layouts and both data-movement contracts. Sweeps group sparsity
+both data-movement contracts. Sweeps group sparsity
 0/25/50/75 % on the paper's CNN (reduced, 3 stages so the 4×4 tail layers
 exercise adaptive M-blocking) and for each level reports dense-vs-sparse
 *dispatched grid steps*, wall clock, parity vs the dense path, and the
@@ -27,10 +27,8 @@ Execution columns:
 - ``padded_mac_utilization*`` — M-padding-aware MAC utilization of the
   dispatched tiles; the ``_b1`` columns show the batch-1 tail, where
   adaptive bm must recover ≥ 2× over fixed ``bm=128``.
-- ``pergroup_*`` — the PR-2 one-(g, f_block)-group-per-tile layout
-  (schedule-exact accounting, >90 % tile padding), for comparison.
 - ``wall_quantized_ms`` / ``quantized_*`` — **native Q2.5×Q3.4 int8
-  execution** (``build_sparse_execution(quantized=True)``): int8 operand
+  execution** (``ExecSpec(quantized=True)``): int8 operand
   codes, int32 accumulation, per-cout dequant fused at the flush, on the
   same plans and schedule as the f32 implicit path (asserted identical).
   Parity vs the dense QAT forward is *bit-exact* (integer arithmetic;
@@ -157,17 +155,15 @@ def run(args=None) -> dict:
             pruned, cfg, spec=cnn.ExecSpec(n_cu=n_cu, **kw),
             specs=specs, group_masks=st.group_masks)
         execs = {
-            # production: packed layout, implicit kernel, adaptive bm
-            "implicit": bind(packed=True, implicit=True),
-            # PR-3 contract: packed layout, HBM patch matrix, fixed bm
-            "materializing": bind(packed=True, implicit=False, bm=128),
-            # PR-2 contract: one group per tile
-            "pergroup": bind(packed=False, implicit=False, bm=128),
+            # production: implicit kernel, adaptive bm
+            "implicit": bind(implicit=True),
+            # PR-3 contract: HBM patch matrix, fixed bm
+            "materializing": bind(implicit=False, bm=128),
         }
         # kernel-only twins (no dense fallback): the isolated
         # implicit-vs-materializing data-movement comparison
         kernel_only = {
-            kind: bind(packed=True, implicit=(kind == "implicit"),
+            kind: bind(implicit=(kind == "implicit"),
                        bm="auto" if kind == "implicit" else 128,
                        dense_fallback=2.0)
             for kind in ("implicit", "materializing")
@@ -177,16 +173,15 @@ def run(args=None) -> dict:
         # dense_fallback=2.0 so every layer runs its int8 kernel — the bench
         # claim is about the executed fixed-point path, not the lax fallback
         q_execs = {
-            kind: bind(packed=True, implicit=(kind == "implicit"),
+            kind: bind(implicit=(kind == "implicit"),
                        bm="auto" if kind == "implicit" else 128,
                        quantized=True, dense_fallback=2.0)
             for kind in ("implicit", "materializing")
         }
 
         # exactness of the bridge, all contracts: schedule-group accounting
-        # (per-tile occupancy) is layout- and kernel-independent and equals
-        # the cycle model's DSB step count; the per-group layout's live
-        # tiles ARE the live schedule steps
+        # (per-tile occupancy) is kernel-independent and equals the cycle
+        # model's DSB step count
         live_groups = int(sum(np.asarray(cnn._get_path(st.group_masks, k)).sum()
                               for k in execs["implicit"].plans))
         total_groups = sum(np.asarray(cnn._get_path(st.group_masks, k)).size
@@ -200,9 +195,6 @@ def run(args=None) -> dict:
         for kind in ("implicit", "materializing"):
             assert (q_execs[kind].step_counts(cfg, batch=1)
                     == kernel_only[kind].step_counts(cfg, batch=1)), kind
-        for keys, plan in execs["pergroup"].plans.items():
-            gm_layer = np.asarray(cnn._get_path(st.group_masks, keys))
-            assert int(plan.cnt.sum()) == int((gm_layer > 0).sum()), keys
 
         # dispatch accounting at batch=1 (per image, like the simulator):
         # the 4x4 tail layers make M-blocks round with ceil, so per-batch
@@ -373,7 +365,6 @@ def run(args=None) -> dict:
             # wall clock: production paths (dense fallback active)
             "wall_sparse_ms": walls["implicit"] * 1e3,
             "wall_materializing_ms": walls["materializing"] * 1e3,
-            "wall_pergroup_ms": walls["pergroup"] * 1e3,
             # wall clock: kernels isolated (fallback disabled)
             "wall_implicit_kernel_ms": walls["ko_implicit"] * 1e3,
             "wall_materializing_kernel_ms": walls["ko_materializing"] * 1e3,
@@ -418,12 +409,6 @@ def run(args=None) -> dict:
             "padded_mac_utilization_b1": util_b1,
             "padded_mac_utilization_b1_fixed_bm": util_b1_fixed,
             "adaptive_vs_fixed_b1_util": util_b1 / util_b1_fixed,
-            # PR-2 one-group-per-tile layout, for comparison
-            "pergroup_executed_grid_steps": steps["pergroup"][0],
-            "pergroup_dense_grid_steps": steps["pergroup"][1],
-            "pergroup_grid_step_ratio": steps["pergroup"][0] / steps["pergroup"][1],
-            "pergroup_mac_utilization": execs["pergroup"].mac_utilization(
-                cfg, batch=batch),
             # layout-independent accounting + model prediction + parity
             "schedule_steps_live": live_groups,
             "schedule_steps_total": total_groups,
@@ -431,12 +416,7 @@ def run(args=None) -> dict:
             "dsb_cycle_ratio": rep.dsb_cycle_ratio,
             "wall_dense_ms": t_dense * 1e3,
             "max_err_vs_dense": max(errs.values()),
-            "packed_vs_pergroup_step_cut":
-                steps["pergroup"][0] / max(steps["materializing"][0], 1),
-            "packed_vs_pergroup_wallclock_speedup":
-                walls["pergroup"] / walls["implicit"],
             "dense_fallback_layers": fallbacks["implicit"],
-            "pergroup_dense_fallback_layers": fallbacks["pergroup"],
         }
         rows.append(row)
         print(f"{target:>7.2f} {steps['implicit'][0]:>6}/{steps['implicit'][1]:<9} "
@@ -460,7 +440,6 @@ def run(args=None) -> dict:
             # the production execs are all identical all-fallback graphs:
             # exactly no speedup recorded (the kernel-only twins still run
             # their kernels — that comparison stays live at full density)
-            assert row["packed_vs_pergroup_wallclock_speedup"] == 1.0
             assert row["wall_sparse_ms"] == row["wall_materializing_ms"]
 
     # both the executed grid (any contract) and the priced FPGA schedule
@@ -470,13 +449,8 @@ def run(args=None) -> dict:
     # asserted above, are exactly equal
     for a, b in zip(rows, rows[1:]):
         assert b["grid_step_ratio"] <= a["grid_step_ratio"] + 1e-9
-        assert b["pergroup_grid_step_ratio"] <= a["pergroup_grid_step_ratio"] + 1e-9
         assert b["dsb_cycle_ratio"] <= a["dsb_cycle_ratio"] + 1e-9
     at50 = next(r for r in rows if r["target_group_sparsity"] == 0.5)
-    assert at50["pergroup_grid_step_ratio"] <= 0.6, at50
-    # the packed layout's whole point: >=4x fewer dispatched steps than the
-    # per-group layout at the paper's 50 % operating point (deterministic)
-    assert at50["packed_vs_pergroup_step_cut"] >= 4.0, at50
     # the implicit kernel's whole point: same plans and schedule, less data
     # moved (deterministic) and measurably faster with the patch matrix gone
     assert at50["hbm_bytes_ratio"] <= 0.8, at50

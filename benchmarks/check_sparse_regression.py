@@ -84,9 +84,7 @@ TOL = 1e-6
 # key -> direction: "max" = current must not exceed baseline (ratios where
 # smaller is better), "min" = current must not fall below (speedup factors)
 GATES = {
-    "grid_step_ratio": "max",                 # packed layout dispatch ratio
-    "pergroup_grid_step_ratio": "max",        # PR-2 layout dispatch ratio
-    "packed_vs_pergroup_step_cut": "min",     # packed must keep its step win
+    "grid_step_ratio": "max",                 # dispatched grid-step ratio
     "schedule_step_ratio": "max",             # paper-granularity live steps
     "hbm_bytes_ratio": "max",                 # implicit must keep moving less
     "adaptive_vs_fixed_b1_util": "min",       # batch-1 adaptive-bm recovery

@@ -5,7 +5,7 @@ widths 16/32/64, 32x32 inputs, 10 classes) from ``--seed``, prunes it
 with HAPM at 50 % group sparsity, and serves it through ``CnnServer``
 in two phases, every conv bound to a Pallas kernel:
 
-  (a) f32 on the packed layout, implicit kernel on auto;
+  (a) f32, implicit kernel on auto;
   (b) streamed int8 with the activation skip (``activation_dsb``).
 
 Each phase warms buckets 1, 8, 32 and 128, answers a few requests of
@@ -231,7 +231,7 @@ def run(seed: int) -> None:
     def within_f32(y, ref):
         return bool(np.all(np.abs(y - ref) <= F32_TOL * (1 + np.abs(ref))))
 
-    serve_phase("phase a (f32, packed, implicit auto)", cfg, params, state,
+    serve_phase("phase a (f32, implicit auto)", cfg, params, state,
                 cnn.ExecSpec(n_cu=N_CU, dense_fallback=2.0),
                 [("f32 reference", f32_ref, within_f32)], seed + 1)
 

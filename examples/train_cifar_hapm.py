@@ -76,10 +76,9 @@ def main(argv=None):
     # quantized=True: every bound conv runs int8 Q2.5×Q3.4 codes with
     # int32 accumulation — the same arithmetic the QAT forward fakes in
     # f32, so the parity below is exact on codes, not a float tolerance
-    # one-group-per-tile layout: dispatched steps ARE the schedule steps
     exec_ = cnn.bind_execution(
         m4.params, m4.cfg,
-        spec=cnn.ExecSpec(packed=False, quantized=True, n_cu=board12.n_cu))
+        spec=cnn.ExecSpec(quantized=True, n_cu=board12.n_cu))
     small = imgs[:2]
     dense_logits, _ = cnn.apply(m4.params, m4.state, small, m4.cfg)
     sparse_logits, _ = cnn.apply(m4.params, m4.state, small, m4.cfg, sparse=exec_)

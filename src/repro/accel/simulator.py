@@ -27,6 +27,7 @@ import numpy as np
 
 from ..core import quant as Q
 from ..models import cnn
+from ..sparse.conv_plan import conv_m_blocks
 from .config import AcceleratorConfig
 from .cycle_model import NetworkCycles, network_cycles
 
@@ -44,15 +45,15 @@ class SimulationReport:
     group_sparsity_per_layer: dict
     data_col_nonzero_frac: dict
     # Executed TPU dispatch accounting for the same group masks the cycle
-    # model prices, via two accounting-only binds (bind_execution with
-    # bind_kernels=False) reported through SparseConvExec.report: the one-
-    # group-per-tile layout at fixed bm=128 (dead tiles == skipped
-    # (g, f_block) schedule steps by construction) and the packed MXU-
-    # shaped layout at the production contract — implicit kernel, adaptive
-    # bm — i.e. what the serving path actually dispatches (tiles cover
-    # many groups, accounting via per-tile occupancy). schedule_steps_* is
-    # the layout-independent paper granularity and equals the cycle
-    # model's DSB step count.
+    # model prices, via one accounting-only bind (bind_execution with
+    # bind_kernels=False) reported through SparseConvExec.report at the
+    # production contract — implicit kernel, adaptive bm — i.e. what the
+    # serving path actually dispatches (packed_*: tiles cover many groups).
+    # executed/dense_grid_steps count (g, f_block) schedule groups, live
+    # and total, from per-tile occupancy times each layer's M-blocks at
+    # fixed bm=128: one step per group per M-block, the cycle model's
+    # granularity. schedule_steps_* is the same count for one M-block and
+    # equals the cycle model's DSB step count.
     grid_steps_per_layer: dict = dataclasses.field(default_factory=dict)
     executed_grid_steps: int = 0
     dense_grid_steps: int = 0
@@ -60,9 +61,8 @@ class SimulationReport:
     packed_dense_grid_steps: int = 0
     schedule_steps_live: int = 0
     schedule_steps_total: int = 0
-    padded_mac_utilization: float = 0.0      # packed layout, dispatched tiles
-    pergroup_mac_utilization: float = 0.0    # one-group-per-tile layout
-    # HBM data-movement contract per image on the packed layout (the
+    padded_mac_utilization: float = 0.0      # dispatched tiles
+    # HBM data-movement contract per image on the dispatched layout (the
     # canonical hbm_bytes_* fields of SparseConvExec.report):
     # materializing (im2col patch matrix in HBM, fixed bm=128 — the PR-3
     # execution) vs implicit (in-kernel window gather from the NHWC
@@ -150,7 +150,6 @@ class SimulationReport:
             "schedule_steps_live": self.schedule_steps_live,
             "schedule_steps_total": self.schedule_steps_total,
             "padded_mac_utilization": self.padded_mac_utilization,
-            "pergroup_mac_utilization": self.pergroup_mac_utilization,
             "dsb_cycle_ratio": self.dsb_cycle_ratio,
             "hbm_bytes_materialized": self.hbm_bytes_materialized,
             "hbm_bytes_implicit": self.hbm_bytes_implicit,
@@ -200,37 +199,31 @@ def simulate(
     qcfg = dataclasses.replace(cfg, quantized=True)
     dims = cnn.layer_dims(cfg, params)
 
-    # --- dispatch + HBM accounting via accounting-only binds ---------------
-    # Two execs, no kernels (bind_kernels=False — plans/layouts/masks only),
-    # each reported through SparseConvExec.report so the simulator prices
-    # exactly what the executed path dispatches. quantized=True reproduces
-    # this simulator's skippability rule: masks from the Q2.5-quantized
-    # weights' zero groups.
-    # - per-group layout, materializing fixed bm=128: live tiles ARE the
-    #   live (g, f_block) schedule steps per M-block (paper granularity);
-    # - packed MXU-shaped layout at the production contract (implicit
-    #   kernel, adaptive bm): what the hardware actually dispatches.
-    pg = cnn.bind_execution(
-        params, cfg, bind_kernels=False,
-        spec=cnn.ExecSpec(packed=False, quantized=True, implicit=False,
-                          bm=128, n_cu=accel.n_cu))
+    # --- dispatch + HBM accounting via an accounting-only bind -------------
+    # No kernels (bind_kernels=False — plans/layouts/masks only), reported
+    # through SparseConvExec.report at the production contract (implicit
+    # kernel, adaptive bm), so the simulator prices exactly what the
+    # executed path dispatches. quantized=True reproduces this simulator's
+    # skippability rule: masks from the Q2.5-quantized weights' zero groups.
     pk = cnn.bind_execution(
         params, cfg, bind_kernels=False,
-        spec=cnn.ExecSpec(packed=True, quantized=True, implicit=True,
-                          bm="auto", n_cu=accel.n_cu))
-    pg_rep = pg.report(cfg, batch=1, per_layer=True)
+        spec=cnn.ExecSpec(quantized=True, implicit=True, bm="auto",
+                          n_cu=accel.n_cu))
     pk_rep = pk.report(cfg, batch=1, per_layer=True)
 
     group_masks, layer_sparsity, grid_steps, bm_eff_per_layer = [], {}, {}, {}
-    for path, _layer in dims:
+    for path, layer in dims:
         name = "/".join(path)
-        gm = np.asarray(pg.group_masks_np[path])
+        gm = np.asarray(pk.group_masks_np[path])
         group_masks.append(gm)
         layer_sparsity[name] = float(1.0 - gm.mean())
-        pg_l, pk_l = pg_rep["per_layer"][name], pk_rep["per_layer"][name]
-        # per-layer HBM contracts priced on the packed (dispatched) layout
-        grid_steps[name] = {"executed": pg_l["executed"],
-                            "dense": pg_l["dense"],
+        pk_l = pk_rep["per_layer"][name]
+        # schedule groups per M-block at fixed bm=128, paper granularity
+        mb = conv_m_blocks(layer.out_x, layer.out_y, 1, bm=128)[0]
+        live, total = pk.layouts[path].tile_occupancy(gm)
+        # per-layer HBM contracts priced on the dispatched layout
+        grid_steps[name] = {"executed": mb * int(live.sum()),
+                            "dense": mb * int(total.sum()),
                             "packed_executed": pk_l["executed"],
                             "packed_dense": pk_l["dense"],
                             "hbm_materialized": pk_l["hbm_materialized"],
@@ -298,14 +291,13 @@ def simulate(
         group_sparsity_per_layer=layer_sparsity,
         data_col_nonzero_frac=col_fracs,
         grid_steps_per_layer=grid_steps,
-        executed_grid_steps=pg_rep["executed_grid_steps"],
-        dense_grid_steps=pg_rep["dense_grid_steps"],
+        executed_grid_steps=sum(v["executed"] for v in grid_steps.values()),
+        dense_grid_steps=sum(v["dense"] for v in grid_steps.values()),
         packed_executed_grid_steps=pk_rep["executed_grid_steps"],
         packed_dense_grid_steps=pk_rep["dense_grid_steps"],
         schedule_steps_live=pk_rep["schedule_steps_live"],
         schedule_steps_total=pk_rep["schedule_steps_total"],
         padded_mac_utilization=pk_rep["padded_mac_utilization"],
-        pergroup_mac_utilization=pg_rep["padded_mac_utilization"],
         hbm_bytes_materialized=pk_rep["hbm_bytes_materialized"],
         hbm_bytes_implicit=pk_rep["hbm_bytes_implicit"],
         hbm_bytes_materialized_int8=pk_rep["hbm_bytes_materialized_int8"],

@@ -16,7 +16,7 @@ three pieces, all consumed by :class:`repro.launch.serve_cnn.CnnServer`:
   guardrail, mask validation, per-request deadlines, and the overload
   (admission-control) action.
 - :func:`degradation_ladder` — the spec downgrade order
-  ``streamed → quantized → f32 packed → dense lax.conv``. Every rung is
+  ``streamed → quantized → f32 → dense lax.conv``. Every rung is
   a *valid* :class:`~repro.models.cnn.ExecSpec` (or ``None`` for the
   dense fallback), and a degraded answer is still bit-exact **for the
   spec it ran under** — the ladder trades throughput for availability,
@@ -68,7 +68,7 @@ def degradation_ladder(spec: ExecSpec) -> Tuple[Any, ...]:
     """The graceful-degradation rungs for ``spec``, fastest first:
     ``streamed → quantized → f32 → dense`` (``None`` = dense ``lax.conv``).
     Each step clears exactly one capability, so every intermediate rung is
-    a valid :class:`ExecSpec` (the ``folded``/``packed`` structure of the
+    a valid :class:`ExecSpec` (the ``folded`` structure of the
     bind is preserved — only the wire/operand contract degrades). A spec
     that already sits low on the ladder just gets the rungs below it.
 

@@ -99,7 +99,7 @@ class CnnServer:
     """Serve ``cnn.apply`` / ``cnn.apply_folded`` through the exec cache.
 
     ``spec`` fixes the execution contract for every request this server
-    answers (packed/implicit/quantized/folded/streamed/bm — one server,
+    answers (implicit/quantized/folded/streamed/bm — one server,
     one contract; run two servers over one shared :class:`ExecCache` for
     mixed fleets). The run config's ``quantized`` flag follows the spec,
     so a quantized bind serves a quantized forward without the caller

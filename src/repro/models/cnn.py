@@ -14,8 +14,6 @@ method in :mod:`repro.core`.
 from __future__ import annotations
 
 import dataclasses
-import warnings
-from collections import OrderedDict
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -253,7 +251,7 @@ def apply(
     cfg: ResNetConfig,
     train: bool = False,
     *,
-    sparse: Any = None,
+    sparse: Optional[SparseConvExec] = None,
 ) -> Tuple[jnp.ndarray, PyTree]:
     """Forward pass. ``x``: (B, H, W, C) in [0, 1]. Returns (logits, new_state).
 
@@ -261,17 +259,13 @@ def apply(
     keeping this function mask-agnostic.
 
     ``sparse`` selects the conv execution path:
-      - ``None``/``False``: dense ``lax.conv`` (default);
-      - a :class:`SparseConvExec` (from :func:`build_sparse_execution`):
+      - ``None``: dense ``lax.conv`` (default);
+      - a :class:`SparseConvExec` (from :func:`bind_execution`):
         every conv dispatches through the Pallas block-sparse kernel on its
         bound plan with its *bind-time prepacked* weight (interpret mode on
         CPU, compiled on TPU), except layers the builder left dense
         (density ≈ 1 fallback). Build with ``quantized=cfg.quantized`` so
         the prepacked weights match the dense path's per-call quantization.
-      - ``True``: build a :class:`SparseConvExec` from the zero slabs of
-        ``params`` (requires concrete weights — under jit this raises;
-        prebuild instead). Builds are memoized on the identity of
-        ``params`` so repeated calls don't reconstruct the plan table.
 
     A *prepacked* exec (the default bind) is inference-only with respect
     to the conv weights: bind-time prepacking makes them compile-time
@@ -345,19 +339,6 @@ def conv_group_specs(params: PyTree, n_cu: int) -> PyTree:
     return jax.tree_util.tree_map_with_path(f, params)
 
 
-def conv_tile_group_specs(params: PyTree, block=(128, 128)) -> PyTree:
-    """TPU-native variant: TpuTileGroupSpec over each conv's 2-D im2col
-    weight matrix (kx*ky*cin, cout) — groups are kernel tiles directly."""
-    from ..core.groups import tpu_tile_groups
-
-    def f(path, leaf):
-        if is_conv_weight(path, leaf):
-            kx, ky, cin, cout = leaf.shape
-            return tpu_tile_groups((kx * ky * cin, cout), block)
-        return None
-    return jax.tree_util.tree_map_with_path(f, params)
-
-
 def _get_path(tree, keys):
     node = tree
     for k in keys:
@@ -373,13 +354,12 @@ class ExecSpec:
     key (``launch.exec_cache``: ``(arch fp, sparsity fp, spec, bucket)``),
     so two binds compare equal iff they are interchangeable.
 
-    ``packed``: MXU-shaped multi-group tiles vs one (g, f_block) group per
-    tile. ``quantized``: native int8 Q2.5×Q3.4 execution (per-cout
+    ``quantized``: native int8 Q2.5×Q3.4 execution (per-cout
     calibrated scales when ``folded``). ``folded``: the tree is
     ``fold_batchnorm`` output and the bias/ReLU epilogue is fused at the
     kernel flush (consume with :func:`apply_folded`). ``implicit``: the
-    in-kernel window-gather data-movement contract (``None`` = auto on
-    channel-major layouts). ``bm``: M-blocking policy, ``"auto"`` or a
+    in-kernel window-gather data-movement contract (``None`` = auto).
+    ``bm``: M-blocking policy, ``"auto"`` or a
     fixed int. ``n_cu``: the schedule-group granularity. Layers whose plan
     density reaches ``dense_fallback`` stay on dense ``lax.conv``.
 
@@ -416,7 +396,6 @@ class ExecSpec:
     authority, callers never see layer-dependent messages.
     """
 
-    packed: bool = True
     quantized: bool = False
     folded: bool = False
     implicit: Optional[bool] = None
@@ -803,11 +782,12 @@ def conv_kernel_name(keys: tuple) -> str:
 
 
 def _bind_conv_layers(tree: PyTree, specs: PyTree, group_masks: PyTree,
-                      n_cu: int, packed: bool, weight_of, bind_one,
+                      n_cu: int, weight_of, bind_one,
                       spans=NO_SPANS):
-    """Shared bind loop of the two exec builders: walk the conv weights of
-    ``tree``, derive each layer's (spec, group mask, layout, plan), and let
-    ``bind_one(keys, w, layout, gm, plan, leaf)`` produce the table entry.
+    """The bind loop of :func:`bind_execution`, plain and folded: walk the
+    conv weights of ``tree``, derive each layer's (spec, group mask,
+    layout, plan), and let ``bind_one(keys, w, layout, gm, plan, leaf)``
+    produce the table entry.
     ``weight_of(leaf)`` is the weight the mask derivation should score
     (e.g. the Q2.5-quantized view); ``leaf`` is the raw array for binders
     that quantize themselves (a calibrated QuantSpec must see unclipped
@@ -840,11 +820,9 @@ def _bind_conv_layers(tree: PyTree, specs: PyTree, group_masks: PyTree,
             else:
                 gm = _get_path(group_masks, keys)
             if gm is None:
-                # tile specs score the 2-D im2col matrix, not the HWIO tensor
-                w2 = w.reshape(spec.shape) if w.shape != spec.shape else w
-                gm = np.asarray(spec.group_scores(w2)) > 0
+                gm = np.asarray(spec.group_scores(w)) > 0
             gm = np.asarray(gm, np.float32)
-            layout = conv_gemm_layout(spec, packed=packed)
+            layout = conv_gemm_layout(spec)
             plan = layout.plan(gm)
             plans[keys], layouts[keys], gms[keys] = plan, layout, gm
             bound[keys] = leaf
@@ -874,22 +852,10 @@ def derive_group_masks(tree: PyTree, n_cu: int, *,
             continue
         keys = tuple(getattr(k, "key", str(k)) for k in path)
         w = weight_of(leaf)
-        spec = _get_path(specs, keys)
-        w2 = w.reshape(spec.shape) if w.shape != spec.shape else w
         masks[keys] = np.asarray(
-            np.asarray(spec.group_scores(w2)) > 0, np.float32)
+            np.asarray(_get_path(specs, keys).group_scores(w)) > 0,
+            np.float32)
     return masks
-
-
-def _resolve_exec_implicit(implicit: Optional[bool], layouts) -> bool:
-    """The exec-level execution contract: what the builder *requested*
-    (resolved against layout capability), not which layers happened to
-    bind — an all-dense-fallback exec must still price/report the
-    contract its kernels would run, or the density-1.0 bench row labels
-    materializing bytes as implicit ones."""
-    capable = any(lo.implicit_geometry() is not None
-                  for lo in layouts.values())
-    return capable if implicit is None else bool(implicit) and capable
 
 
 def bind_execution(
@@ -905,10 +871,8 @@ def bind_execution(
 ) -> SparseConvExec:
     """The one bind entry point: every conv layer of ``params`` onto the
     Pallas block-sparse kernels under the execution contract ``spec``
-    (an :class:`ExecSpec`; default: packed layout, auto-implicit kernel,
-    adaptive M-blocking, f32). The two legacy builders —
-    :func:`build_sparse_execution` and :func:`build_sparse_inference` —
-    are thin deprecated wrappers over this.
+    (an :class:`ExecSpec`; default: auto-implicit kernel, adaptive
+    M-blocking, f32).
 
     ``spec.folded=False`` (plain bind): ``params`` is the raw param tree.
     With ``spec.quantized`` every bound layer prepacks **int8 Q2.5 weight
@@ -1044,8 +1008,7 @@ def bind_execution(
                              quant=qspec, activation_dsb=spec.activation_dsb)
 
     table, plans, layouts, gms, bound = _bind_conv_layers(
-        tree, specs, group_masks, spec.n_cu, spec.packed, weight_of,
-        bind_one, spans)
+        tree, specs, group_masks, spec.n_cu, weight_of, bind_one, spans)
     return SparseConvExec(table=table, plans=plans, n_cu=spec.n_cu,
                           layouts=layouts, group_masks_np=gms,
                           quantized=spec.quantized, folded=spec.folded,
@@ -1053,114 +1016,18 @@ def bind_execution(
                           activation_dsb=spec.activation_dsb,
                           trainable=spec.trainable,
                           bound_weights=None if spec.trainable else bound,
-                          implicit=_resolve_exec_implicit(spec.implicit,
-                                                          layouts),
+                          implicit=spec.implicit is not False,
                           bm=spec.bm, spec=spec)
 
 
-def build_sparse_execution(
-    params: PyTree,
-    *,
-    n_cu: int = 12,
-    specs: PyTree = None,
-    group_masks: PyTree = None,
-    dense_fallback: float = 0.999,
-    bm: Any = "auto",
-    packed: bool = False,
-    quantized: bool = False,
-    quant_spec: Any = None,
-    implicit: Optional[bool] = None,
-) -> SparseConvExec:
-    """Deprecated: use ``bind_execution(params, spec=ExecSpec(...))``.
-
-    Kept as a thin wrapper (parity-tested in ``tests/test_exec_cache.py``)
-    so no call site silently changes behavior; note its legacy default is
-    ``packed=False`` where :class:`ExecSpec` defaults to the production
-    ``packed=True``."""
-    warnings.warn(
-        "build_sparse_execution is deprecated — use "
-        "bind_execution(params, spec=ExecSpec(...))",
-        DeprecationWarning, stacklevel=2)
-    return bind_execution(
-        params,
-        spec=ExecSpec(packed=packed, quantized=quantized, folded=False,
-                      implicit=implicit, bm=bm, n_cu=n_cu,
-                      dense_fallback=dense_fallback),
-        specs=specs, group_masks=group_masks, quant_spec=quant_spec)
-
-
-def build_sparse_inference(
-    folded: PyTree,
-    cfg: ResNetConfig,
-    *,
-    n_cu: int = 12,
-    specs: PyTree = None,
-    group_masks: PyTree = None,
-    dense_fallback: float = 0.999,
-    bm: Any = "auto",
-    packed: bool = True,
-    quantized: bool = False,
-    implicit: Optional[bool] = True,
-) -> SparseConvExec:
-    """Deprecated: use ``bind_execution(folded, cfg,
-    spec=ExecSpec(folded=True, ...))``. Thin wrapper, parity-tested."""
-    warnings.warn(
-        "build_sparse_inference is deprecated — use "
-        "bind_execution(folded, cfg, spec=ExecSpec(folded=True, ...))",
-        DeprecationWarning, stacklevel=2)
-    return bind_execution(
-        folded, cfg,
-        spec=ExecSpec(packed=packed, quantized=quantized, folded=True,
-                      implicit=implicit, bm=bm, n_cu=n_cu,
-                      dense_fallback=dense_fallback),
-        specs=specs, group_masks=group_masks)
-
-
-# sparse=True builds are memoized on params identity: the cache holds a
-# strong reference to the keyed params tree, which pins its id() for the
-# lifetime of the entry. A true LRU (a repeat hit moves its entry to the
-# back; the least-recently-USED entry is evicted, not merely the oldest
-# insert) with an explicit, configurable bound — a long-lived serving
-# process alternating between a few models keeps all of them hot without
-# pinning every historical params tree.
-_SPARSE_EXEC_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
-_SPARSE_EXEC_CACHE_MAX = 4
-
-
-def set_sparse_exec_cache_capacity(n: int) -> None:
-    """Set the ``apply(..., sparse=True)`` memo bound (entries, >= 1),
-    evicting least-recently-used entries immediately if over the new cap."""
-    global _SPARSE_EXEC_CACHE_MAX
-    if n < 1:
-        raise ValueError(f"capacity must be >= 1, got {n}")
-    _SPARSE_EXEC_CACHE_MAX = n
-    while len(_SPARSE_EXEC_CACHE) > _SPARSE_EXEC_CACHE_MAX:
-        _SPARSE_EXEC_CACHE.popitem(last=False)
-
-
 def _resolve_sparse(sparse, params, quantized: bool = False) -> Optional[SparseConvExec]:
-    if sparse is None or sparse is False:
+    if sparse is None:
         return None
-    if sparse is True:
-        key = (id(params), quantized)
-        hit = _SPARSE_EXEC_CACHE.get(key)
-        if hit is not None and hit[0] is params:
-            _SPARSE_EXEC_CACHE.move_to_end(key)
-            return hit[1]
-        # legacy packed=False layout preserved for the memoized path —
-        # its grid-step accounting is what tests/benches pin down
-        exec_ = bind_execution(
-            params, spec=ExecSpec(packed=False, quantized=quantized,
-                                  implicit=None))
-        while len(_SPARSE_EXEC_CACHE) >= _SPARSE_EXEC_CACHE_MAX:
-            _SPARSE_EXEC_CACHE.popitem(last=False)
-        _SPARSE_EXEC_CACHE[key] = (params, exec_)
-        return exec_
     if isinstance(sparse, SparseConvExec):
         if sparse.folded:
             raise ValueError(
                 "this SparseConvExec fuses the folded-BN bias/ReLU epilogue "
-                "(build_sparse_inference) — apply() would run BN on top of "
+                "(ExecSpec(folded=True)) — apply() would run BN on top of "
                 "it; consume it with apply_folded()")
         if sparse.trainable:
             # per-call weights: nothing is prepacked, so there is nothing
@@ -1189,7 +1056,7 @@ def _resolve_sparse(sparse, params, quantized: bool = False) -> Optional[SparseC
                         "prepacked bind-time weight is not the array in "
                         "params — rebuild the exec after weight updates")
         return sparse
-    raise TypeError(f"sparse must be None/bool/SparseConvExec, got {type(sparse)}")
+    raise TypeError(f"sparse must be None or a SparseConvExec, got {type(sparse)}")
 
 
 def conv_layer_order(cfg: ResNetConfig):
@@ -1267,8 +1134,8 @@ def apply_folded(
 
     if sparse is not None and not sparse.folded:
         raise ValueError(
-            "apply_folded needs a folded SparseConvExec (build_sparse_"
-            "inference) — this one has no fused bias/ReLU epilogue, its "
+            "apply_folded needs a folded SparseConvExec (ExecSpec("
+            "folded=True)) — this one has no fused bias/ReLU epilogue, its "
             "convs would silently drop the folded bias")
     streamed = sparse is not None and sparse.streamed
     if streamed and wire_quantize is False:

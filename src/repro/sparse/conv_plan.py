@@ -7,37 +7,22 @@ so every pruned group is a *dead tile* the kernel's dispatch plan never
 visits — compute and HBM→VMEM DMA both skipped, exactly the FPGA DSB's
 skipped (f_block, g) schedule steps hoisted to dispatch time.
 
-Three layouts:
+One layout, :class:`ConvGemmLayout` (from ``FpgaConvGroupSpec``): K is
+channel-major and each K-tile packs ``bk // slot`` input channels, one
+row *slot* per channel (:func:`channel_slot`: 8-aligned, one row for a
+1x1, whose tile then holds a whole 128-lane channel group); each N-tile
+packs ``bn // n_cu`` f_blocks, so the tile shape matches the 128-deep MXU
+datapath instead of one group. A tile is live iff *any* covered (g,
+f_block) group is live; pruned groups inside a live tile are zero slabs
+in the packed (masked) weight, so the GEMM stays exact.
+Paper-granularity accounting survives through
+:meth:`ConvGemmLayout.tile_occupancy`: every tile records how many live /
+total schedule groups it covers, so callers can report *both* packed grid
+steps (what the hardware dispatches) and schedule-group steps (what the
+cycle model prices) plus the padded-MAC utilization of the dispatched
+tiles.
 
-- :class:`FpgaConvGemmLayout` (from ``FpgaConvGroupSpec``): K is channel-
-  major — input channel ``g`` owns rows ``[g*bk, g*bk + kx*ky)`` of one
-  K-tile (``bk = kx*ky`` rounded up to the 8-sublane multiple); N gives each
-  ``f_block`` its own 128-lane tile (``cout`` padded to ``n_fb*n_cu``, each
-  block to 128 lanes). Tiles are therefore *exactly* the paper's (g,
-  f_block) groups: live grid steps == live groups, so the executed step
-  count equals the cycle model's DSB step count by construction. The lane
-  padding trades MAC utilization for that exactness — a 3×3 conv fills
-  only ``9·n_cu / (16·128)`` of each dispatched tile.
-- :class:`PackedFpgaConvGemmLayout` (``conv_gemm_layout(spec,
-  packed=True)``): the TPU-efficiency layout. Each K-tile packs
-  ``bk // slot`` input channels, one row *slot* per channel
-  (:func:`channel_slot`: 8-aligned, one row for a 1x1, whose tile then
-  holds a whole 128-lane channel group) and each N-tile packs ``bn //
-  n_cu`` f_blocks, so the tile shape matches the 128-deep MXU datapath
-  instead of one group. A tile is
-  live iff *any* covered (g, f_block) group is live; pruned groups inside
-  a live tile are zero slabs in the packed (masked) weight, so the GEMM
-  stays exact. Paper-granularity accounting survives through
-  :meth:`ConvGemmLayout.tile_occupancy`: every tile records how many live
-  / total schedule groups it covers, so callers can report *both* packed
-  grid steps (what the hardware dispatches) and schedule-group steps
-  (what the cycle model prices) plus the padded-MAC utilization of the
-  dispatched tiles.
-- :class:`TileConvGemmLayout` (from ``TpuTileGroupSpec`` over the 2-D
-  ``(kx*ky*cin, cout)`` matrix): groups already are kernel tiles; packing
-  is plain zero-padding to the tile multiples.
-
-All layouts pack zeros into the padding, so packed GEMM == conv for any
+The layout packs zeros into the padding, so packed GEMM == conv for any
 operand values; dead-tile skipping is additionally exact because pruned
 groups are zero slabs in the masked weight.
 
@@ -55,8 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.groups import (FpgaConvGroupSpec, GroupSpec, TpuTileGroupSpec,
-                           apply_group_mask)
+from ..core.groups import FpgaConvGroupSpec, apply_group_mask
 from .block_mask import BlockSparsePlan, plan_from_tile_mask, transpose_plan
 
 
@@ -112,9 +96,16 @@ def mask_fingerprint(group_masks) -> str:
 
 @dataclasses.dataclass(frozen=True)
 class ConvGemmLayout:
-    """Packing of one conv weight onto the block-sparse kernel's tile grid."""
+    """Packing of one conv weight onto the block-sparse kernel's tile grid:
+    ``cpk = bk // channel_slot(kx, ky)`` input channels per K-tile
+    (channel ``g`` -> tile ``g // cpk``, row slot ``g % cpk``) and ``fpn =
+    bn // n_cu`` f_blocks per N-tile (f_block ``f`` -> tile ``f // fpn``,
+    lane slot ``f % fpn``). A tile is live iff any covered group is —
+    pruned groups inside live tiles are zeros in the packed masked weight,
+    so the GEMM stays exact while the grid holds up to ``cpk·fpn`` groups
+    a tile."""
 
-    spec: GroupSpec
+    spec: FpgaConvGroupSpec
     block: Tuple[int, int]          # (bk, bn) kernel tile
     tiles: Tuple[int, int]          # (nKb, nNb)
 
@@ -126,19 +117,34 @@ class ConvGemmLayout:
     def n_packed(self) -> int:
         return self.tiles[1] * self.block[1]
 
-    # -- API (implemented by subclasses) -----------------------------------
-    def tile_mask(self, group_mask) -> np.ndarray:
-        """(num_groups,) {0,1} -> (nKb, nNb) bool, host-side."""
-        raise NotImplementedError
+    def _packing(self):
+        kx, ky, cin, cout = self.spec.shape
+        n_cu, n_fb = self.spec.n_cu, self.spec.n_fblocks
+        bk, bn = self.block
+        slot = channel_slot(kx, ky)
+        return kx * ky, cin, cout, n_cu, n_fb, slot, bk // slot, bn // n_cu
 
-    def implicit_geometry(self) -> Optional[dict]:
-        """Window geometry of the K axis for the implicit-im2col kernel, or
-        ``None`` when this layout's K packing isn't channel-major (the
-        in-kernel gather contract: K-tile ``t`` covers input channels
+    def implicit_geometry(self) -> dict:
+        """Window geometry of the K axis for the implicit-im2col kernel
+        (the in-kernel gather contract: K-tile ``t`` covers input channels
         ``[t*cpk, (t+1)*cpk)``, channel slot ``c`` owns rows ``[c*slot,
         c*slot + kx*ky)`` = the (dy, dx) taps in row-major tap order).
         Keys: ``kx, ky, cpk, slot``."""
-        return None
+        kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
+        kx, ky = self.spec.shape[:2]
+        return {"kx": kx, "ky": ky, "cpk": cpk, "slot": slot}
+
+    def _group_grid(self, group_mask) -> np.ndarray:
+        """(num_groups,) -> (nKb, cpk, nNb, fpn) bool, padded with False."""
+        kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
+        nKb, nNb = self.tiles
+        g = np.asarray(group_mask).reshape(cin, n_fb) > 0
+        g = np.pad(g, ((0, nKb * cpk - cin), (0, nNb * fpn - n_fb)))
+        return g.reshape(nKb, cpk, nNb, fpn)
+
+    def tile_mask(self, group_mask) -> np.ndarray:
+        """(num_groups,) {0,1} -> (nKb, nNb) bool, host-side."""
+        return self._group_grid(group_mask).any(axis=(1, 3))
 
     def implicit_index_table(self, group_mask):
         """Offset-augmented dispatch table for the implicit kernel.
@@ -153,11 +159,6 @@ class ConvGemmLayout:
         back to the materialized im2col rows (property-tested in
         ``tests/test_implicit_conv.py``)."""
         geo = self.implicit_geometry()
-        if geo is None:
-            raise ValueError(
-                f"{type(self).__name__} packs K in a non-channel-major "
-                "order — no implicit-im2col table (use the materializing "
-                "path)")
         plan = self.plan(group_mask)
         cin = self.spec.shape[2]
         cpk = geo["cpk"]
@@ -181,11 +182,8 @@ class ConvGemmLayout:
         bk = self.block[0]
         t = np.arange(self.tiles[0])
         geo = self.implicit_geometry()
-        if geo is None:                  # K tap-major over the 2-D matrix
-            real = np.clip(self.spec.shape[0] - t * bk, 0, bk)
-        else:
-            cin, cpk = self.spec.shape[2], geo["cpk"]
-            real = np.clip(cin - t * cpk, 0, cpk) * geo["kx"] * geo["ky"]
+        cin, cpk = self.spec.shape[2], geo["cpk"]
+        real = np.clip(cin - t * cpk, 0, cpk) * geo["kx"] * geo["ky"]
         live = self.tile_mask(group_mask).sum(axis=1)
         return float((real * live).sum() / max(live.sum() * bk, 1))
 
@@ -194,11 +192,10 @@ class ConvGemmLayout:
 
         ``live.sum()`` is the paper-granularity live-step count (== the
         cycle model's DSB steps) regardless of how many groups share a
-        tile; for the one-group-per-tile layouts it degenerates to the
-        tile mask itself.
-        """
-        tm = self.tile_mask(group_mask)
-        return tm.astype(np.int64), np.ones_like(tm, np.int64)
+        tile."""
+        live = self._group_grid(group_mask).sum(axis=(1, 3))
+        total = self._group_grid(np.ones(self.spec.num_groups)).sum(axis=(1, 3))
+        return live.astype(np.int64), total.astype(np.int64)
 
     def mac_accounting(self, group_mask) -> Tuple[int, int]:
         """(live weight elements, dispatched-tile MAC area) for this layer —
@@ -220,107 +217,6 @@ class ConvGemmLayout:
 
     def pack_weight(self, w: jnp.ndarray) -> jnp.ndarray:
         """(kx, ky, cin, cout) -> (k_packed, n_packed)."""
-        raise NotImplementedError
-
-    def pack_bias(self, b: jnp.ndarray) -> jnp.ndarray:
-        """(cout,) -> (n_packed,), lanes aligned with ``pack_weight``."""
-        raise NotImplementedError
-
-    def pack_patches(self, patches: jnp.ndarray) -> jnp.ndarray:
-        """(..., kx, ky, cin) im2col patches -> (M, k_packed)."""
-        raise NotImplementedError
-
-    def unpack_output(self, out2d: jnp.ndarray, lead_shape) -> jnp.ndarray:
-        """(M, n_packed) -> (*lead_shape, cout)."""
-        raise NotImplementedError
-
-
-@dataclasses.dataclass(frozen=True)
-class FpgaConvGemmLayout(ConvGemmLayout):
-    def _dims(self):
-        kx, ky, cin, cout = self.spec.shape
-        return kx, ky, cin, cout, self.spec.n_cu, self.spec.n_fblocks
-
-    def implicit_geometry(self) -> Optional[dict]:
-        kx, ky = self.spec.shape[:2]
-        # one channel per K-tile: the whole bk is that channel's slot
-        return {"kx": kx, "ky": ky, "cpk": 1, "slot": self.block[0]}
-
-    def tile_mask(self, group_mask) -> np.ndarray:
-        kx, ky, cin, cout, n_cu, n_fb = self._dims()
-        return np.asarray(group_mask).reshape(cin, n_fb) > 0
-
-    def pack_weight(self, w: jnp.ndarray) -> jnp.ndarray:
-        kx, ky, cin, cout, n_cu, n_fb = self._dims()
-        bk, bn = self.block
-        kxky = kx * ky
-        w2 = jnp.transpose(w.reshape(kxky, cin, cout), (1, 0, 2))
-        w2 = jnp.pad(w2, ((0, 0), (0, bk - kxky), (0, n_fb * n_cu - cout)))
-        w2 = w2.reshape(cin, bk, n_fb, n_cu)
-        w2 = jnp.pad(w2, ((0, 0), (0, 0), (0, 0), (0, bn - n_cu)))
-        return w2.reshape(cin * bk, n_fb * bn)
-
-    def pack_bias(self, b: jnp.ndarray) -> jnp.ndarray:
-        kx, ky, cin, cout, n_cu, n_fb = self._dims()
-        _, bn = self.block
-        b2 = jnp.pad(b, (0, n_fb * n_cu - cout)).reshape(n_fb, n_cu)
-        return jnp.pad(b2, ((0, 0), (0, bn - n_cu))).reshape(n_fb * bn)
-
-    def pack_patches(self, patches: jnp.ndarray) -> jnp.ndarray:
-        kx, ky, cin, cout, n_cu, n_fb = self._dims()
-        bk, _ = self.block
-        kxky = kx * ky
-        p = patches.reshape(-1, kxky, cin)
-        p = jnp.transpose(p, (0, 2, 1))                   # channel-major K
-        p = jnp.pad(p, ((0, 0), (0, 0), (0, bk - kxky)))
-        return p.reshape(-1, cin * bk)
-
-    def unpack_output(self, out2d: jnp.ndarray, lead_shape) -> jnp.ndarray:
-        kx, ky, cin, cout, n_cu, n_fb = self._dims()
-        _, bn = self.block
-        o = out2d.reshape(-1, n_fb, bn)[:, :, :n_cu]
-        return o.reshape(-1, n_fb * n_cu)[:, :cout].reshape(*lead_shape, cout)
-
-
-@dataclasses.dataclass(frozen=True)
-class PackedFpgaConvGemmLayout(ConvGemmLayout):
-    """Multi-group tiles: ``cpk = bk // channel_slot(kx, ky)`` input
-    channels per K-tile (channel ``g`` -> tile ``g // cpk``, row slot
-    ``g % cpk``) and ``fpn = bn // n_cu`` f_blocks per N-tile (f_block
-    ``f`` -> tile ``f // fpn``, lane slot ``f % fpn``). A tile is live
-    iff any covered group is — pruned groups inside live tiles are zeros
-    in the packed masked weight, so the GEMM stays exact while the grid
-    shrinks by up to ``cpk·fpn`` over the one-group-per-tile layout."""
-
-    def _packing(self):
-        kx, ky, cin, cout = self.spec.shape
-        n_cu, n_fb = self.spec.n_cu, self.spec.n_fblocks
-        bk, bn = self.block
-        slot = channel_slot(kx, ky)
-        return kx * ky, cin, cout, n_cu, n_fb, slot, bk // slot, bn // n_cu
-
-    def implicit_geometry(self) -> Optional[dict]:
-        kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
-        kx, ky = self.spec.shape[:2]
-        return {"kx": kx, "ky": ky, "cpk": cpk, "slot": slot}
-
-    def _group_grid(self, group_mask) -> np.ndarray:
-        """(num_groups,) -> (nKb, cpk, nNb, fpn) bool, padded with False."""
-        kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
-        nKb, nNb = self.tiles
-        g = np.asarray(group_mask).reshape(cin, n_fb) > 0
-        g = np.pad(g, ((0, nKb * cpk - cin), (0, nNb * fpn - n_fb)))
-        return g.reshape(nKb, cpk, nNb, fpn)
-
-    def tile_mask(self, group_mask) -> np.ndarray:
-        return self._group_grid(group_mask).any(axis=(1, 3))
-
-    def tile_occupancy(self, group_mask) -> Tuple[np.ndarray, np.ndarray]:
-        live = self._group_grid(group_mask).sum(axis=(1, 3))
-        total = self._group_grid(np.ones(self.spec.num_groups)).sum(axis=(1, 3))
-        return live.astype(np.int64), total.astype(np.int64)
-
-    def pack_weight(self, w: jnp.ndarray) -> jnp.ndarray:
         kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
         nKb, nNb = self.tiles
         bk, bn = self.block
@@ -335,6 +231,7 @@ class PackedFpgaConvGemmLayout(ConvGemmLayout):
         return w2.reshape(nKb * bk, nNb * bn)
 
     def pack_bias(self, b: jnp.ndarray) -> jnp.ndarray:
+        """(cout,) -> (n_packed,), lanes aligned with ``pack_weight``."""
         kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
         nNb = self.tiles[1]
         bn = self.block[1]
@@ -342,6 +239,7 @@ class PackedFpgaConvGemmLayout(ConvGemmLayout):
         return jnp.pad(b2, ((0, 0), (0, bn - fpn * n_cu))).reshape(nNb * bn)
 
     def pack_patches(self, patches: jnp.ndarray) -> jnp.ndarray:
+        """(..., kx, ky, cin) im2col patches -> (M, k_packed)."""
         kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
         nKb = self.tiles[0]
         bk = self.block[0]
@@ -353,6 +251,7 @@ class PackedFpgaConvGemmLayout(ConvGemmLayout):
         return p.reshape(-1, nKb * bk)
 
     def unpack_output(self, out2d: jnp.ndarray, lead_shape) -> jnp.ndarray:
+        """(M, n_packed) -> (*lead_shape, cout)."""
         kxky, cin, cout, n_cu, n_fb, slot, cpk, fpn = self._packing()
         nNb = self.tiles[1]
         bn = self.block[1]
@@ -361,60 +260,21 @@ class PackedFpgaConvGemmLayout(ConvGemmLayout):
         return o.reshape(-1, n_fb * n_cu)[:, :cout].reshape(*lead_shape, cout)
 
 
-@dataclasses.dataclass(frozen=True)
-class TileConvGemmLayout(ConvGemmLayout):
-    def tile_mask(self, group_mask) -> np.ndarray:
-        return np.asarray(group_mask).reshape(self.tiles) > 0
-
-    def pack_weight(self, w: jnp.ndarray) -> jnp.ndarray:
-        K, N = self.spec.shape
-        w2 = w.reshape(K, N)
-        return jnp.pad(w2, ((0, self.k_packed - K), (0, self.n_packed - N)))
-
-    def pack_bias(self, b: jnp.ndarray) -> jnp.ndarray:
-        _, N = self.spec.shape
-        return jnp.pad(b, (0, self.n_packed - N))
-
-    def pack_patches(self, patches: jnp.ndarray) -> jnp.ndarray:
-        K, _ = self.spec.shape
-        p = patches.reshape(-1, K)
-        return jnp.pad(p, ((0, 0), (0, self.k_packed - K)))
-
-    def unpack_output(self, out2d: jnp.ndarray, lead_shape) -> jnp.ndarray:
-        _, N = self.spec.shape
-        return out2d[:, :N].reshape(*lead_shape, N)
-
-
-def conv_gemm_layout(spec: GroupSpec, *, bn: int = 128, packed: bool = False,
+def conv_gemm_layout(spec: FpgaConvGroupSpec, *, bn: int = 128,
                      bk: int = 128) -> ConvGemmLayout:
-    """Layout for a conv's im2col GEMM, tile grid aligned with ``spec``.
-
-    ``packed=False`` (default): one (g, f_block) group per tile — exact
-    schedule-step accounting, heavy lane padding. ``packed=True``: MXU-
-    shaped ``(bk, bn)`` tiles covering many groups — far fewer grid steps
-    at the same pruning, accounting via :meth:`ConvGemmLayout.tile_occupancy`.
-    """
-    if isinstance(spec, FpgaConvGroupSpec):
-        kx, ky, cin, cout = spec.shape
-        if spec.n_cu > bn:
-            raise ValueError(f"n_cu={spec.n_cu} exceeds the {bn}-lane tile")
-        if packed:
-            slot = channel_slot(kx, ky)
-            bk_eff = max(bk, slot)          # giant kernels: one channel/tile
-            cpk, fpn = bk_eff // slot, bn // spec.n_cu
-            return PackedFpgaConvGemmLayout(
-                spec=spec, block=(bk_eff, bn),
-                tiles=(-(-cin // cpk), -(-spec.n_fblocks // fpn)))
-        bk_pg = max(8, _ceil_to(kx * ky, 8))
-        return FpgaConvGemmLayout(spec=spec, block=(bk_pg, bn),
-                                  tiles=(cin, spec.n_fblocks))
-    if isinstance(spec, TpuTileGroupSpec):
-        if len(spec.shape) != 2:
-            raise ValueError("conv tile specs must cover the 2-D im2col "
-                             f"matrix, got shape {spec.shape}")
-        nKb, nNb = spec.tiles
-        return TileConvGemmLayout(spec=spec, block=spec.block, tiles=(nKb, nNb))
-    raise TypeError(f"no conv GEMM layout for {type(spec).__name__}")
+    """Layout for a conv's im2col GEMM, tile grid aligned with ``spec``:
+    MXU-shaped ``(bk, bn)`` tiles covering many (g, f_block) groups, with
+    schedule-group accounting via :meth:`ConvGemmLayout.tile_occupancy`."""
+    if not isinstance(spec, FpgaConvGroupSpec):
+        raise TypeError(f"no conv GEMM layout for {type(spec).__name__}")
+    kx, ky, cin, cout = spec.shape
+    if spec.n_cu > bn:
+        raise ValueError(f"n_cu={spec.n_cu} exceeds the {bn}-lane tile")
+    slot = channel_slot(kx, ky)
+    bk_eff = max(bk, slot)          # giant kernels: one channel/tile
+    cpk, fpn = bk_eff // slot, bn // spec.n_cu
+    return ConvGemmLayout(spec=spec, block=(bk_eff, bn),
+                          tiles=(-(-cin // cpk), -(-spec.n_fblocks // fpn)))
 
 
 def adaptive_bm(m_rows: int, cap: int = 128) -> int:
@@ -441,9 +301,8 @@ def implicit_m_block(layout: ConvGemmLayout, h: int, w: int, stride: int,
                      activation_dsb: bool = False):
     """The implicit kernel's M-block for one conv geometry and a
     ``batch``-image call, or ``None`` when the layer takes the
-    materializing path: the layout packs K tap-major (no window to
-    gather), no whole-row or column-segment M-block fits the ``bm`` cap,
-    or the kernel's padded VMEM working set
+    materializing path: no whole-row or column-segment M-block fits the
+    ``bm`` cap, or the kernel's padded VMEM working set
     (:func:`repro.kernels.implicit_conv.window_vmem_bytes`, at operand
     width ``itemsize``) exceeds :data:`~repro.kernels.implicit_conv.
     SLAB_VMEM_BUDGET` even for one image. A fold of whole images
@@ -457,10 +316,7 @@ def implicit_m_block(layout: ConvGemmLayout, h: int, w: int, stride: int,
     from ..kernels import implicit_conv as IC
     from ..kernels.conv_lowering import conv_out_size
 
-    geo = layout.implicit_geometry()
-    if geo is None:
-        return None
-    kx, ky = geo["kx"], geo["ky"]
+    kx, ky = layout.spec.shape[:2]
     ho = conv_out_size(h, kx, stride, padding)
     wo = conv_out_size(w, ky, stride, padding)
     cap = 128 if bm == "auto" else int(bm)
@@ -561,23 +417,21 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
 
     Returns ``conv(x, w=None, stride=1, padding="SAME") -> (B, Ho, Wo, cout)``
     computing ``conv(x, w ⊙ expand(group_mask))`` — pruned groups are dead
-    tiles the grid never dispatches (and, for the packed layout, zero slabs
-    inside live tiles). The plan is static: rebind after HAPM prunes more
-    groups (an epoch-boundary event).
+    tiles the grid never dispatches (or zero slabs inside live tiles).
+    The plan is static: rebind after HAPM prunes more groups (an
+    epoch-boundary event).
 
     ``implicit`` selects the kernel (default ``None`` = auto):
-      - ``True`` / auto on the channel-major FPGA layouts: the
-        **implicit-im2col** kernel (:mod:`repro.kernels.implicit_conv`)
-        gathers kernel windows from the padded NHWC activation inside the
-        grid — the ``(B·Ho·Wo, kx·ky·cin)`` patch matrix is never
-        materialized in HBM. A geometry :func:`implicit_m_block` rejects
+      - ``True`` / auto: the **implicit-im2col** kernel
+        (:mod:`repro.kernels.implicit_conv`) gathers kernel windows from
+        the padded NHWC activation inside the grid — the ``(B·Ho·Wo,
+        kx·ky·cin)`` patch matrix is never materialized in HBM. A geometry :func:`implicit_m_block` rejects
         (no M-block fits, or the padded window working set exceeds
         :data:`implicit_conv.SLAB_VMEM_BUDGET`) runs the materializing
         path instead.
         Forward-only (the materializing non-epilogue path keeps its VJP).
       - ``False``: the materializing im2col + ``block_sparse_matmul``
-        path — the parity oracle, and the only path for
-        :class:`TileConvGemmLayout` (its K axis is tap-major).
+        path — the parity oracle.
 
     ``geometry``: ``(h, w, stride, padding)`` of the layer's input —
     the route is then chosen here, once: the implicit kernel's M-block
@@ -683,15 +537,11 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
     tm = layout.tile_mask(gm)
     plan = plan_from_tile_mask(tm, layout.block)
     geo = layout.implicit_geometry()
-    if implicit and geo is None:
-        raise ValueError(
-            f"implicit=True needs a channel-major K layout; "
-            f"{type(layout).__name__} has none — use implicit=False")
-    use_implicit = (geo is not None) if implicit is None else bool(implicit)
+    use_implicit = implicit is not False
     if activation_dsb and not use_implicit:
         raise ValueError(
             "activation_dsb lives in the implicit kernel's window gather "
-            "— bind with implicit=True (needs a channel-major layout)")
+            "— bind with implicit=True")
     adaptive = bm == "auto"
     bm_cap = 128 if adaptive else int(bm)
     packed_bias = (None if bias is None
@@ -717,13 +567,8 @@ def make_sparse_conv(layout: ConvGemmLayout, group_mask, *, bm="auto",
 
     gm_dev = jnp.asarray(gm, jnp.float32)
 
-    def _masked(w):
-        spec = layout.spec
-        w2 = w.reshape(spec.shape) if w.shape != spec.shape else w
-        return apply_group_mask(spec, w2, gm_dev.astype(w.dtype)).reshape(w.shape)
-
     def _pack_w(w):
-        wm = _masked(w)
+        wm = apply_group_mask(layout.spec, w, gm_dev.astype(w.dtype))
         if quant is None:
             return layout.pack_weight(wm)
         # int8 codes packed onto the tile grid: zero-masked groups emit

@@ -49,7 +49,7 @@ def _bound_pair(rng, kshape, n_cu, density, *, relu=False, streamed=False):
     spec = fpga_conv_groups(kshape, n_cu)
     gm = _group_mask(rng, spec.num_groups, density)
     w = jnp.asarray(rng.randn(*kshape).astype(np.float32) * 0.2)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     quant = Q.QuantSpec()
     out_q = Q.QuantSpec() if streamed else None
     mk = lambda **kw: make_sparse_conv(layout, gm, weight=w, quant=quant,
@@ -187,7 +187,7 @@ def test_dsb_rejects_f32_and_materializing():
     rng = np.random.RandomState(1)
     spec = fpga_conv_groups((3, 3, 8, 8), 4)
     gm = _group_mask(rng, spec.num_groups, 0.5)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     w = jnp.asarray(rng.randn(3, 3, 8, 8).astype(np.float32))
     with pytest.raises(ValueError, match="requires[\\s\\S]*quant"):
         make_sparse_conv(layout, gm, weight=w, activation_dsb=True)

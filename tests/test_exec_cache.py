@@ -3,10 +3,9 @@
 Covers the cache key mechanics (hit on same-bucket repeat, one bind
 shared across buckets, miss + rebind on pruning-mask change, LRU
 eviction), bucket selection boundaries (batch 9 -> bucket 32), the
-deprecated builder wrappers' parity vs ``bind_execution``, the staleness
-guard through cached execs, the ``apply(sparse=True)`` memo LRU, the
-batcher's flush policies, and ``SparseConvExec.report`` consistency vs
-the individual accounting methods.
+staleness guard through cached execs, the batcher's flush policies, and
+``SparseConvExec.report`` consistency vs the individual accounting
+methods.
 """
 import dataclasses
 
@@ -80,7 +79,7 @@ def test_execspec_validation_and_hashability():
     assert a == b and hash(a) == hash(b)
     assert cnn.ExecSpec(folded=True) != cnn.ExecSpec(folded=False)
     with pytest.raises(dataclasses.FrozenInstanceError):
-        a.packed = False
+        a.quantized = False
 
 
 # ------------------------------------------------------------- ExecCache
@@ -252,36 +251,6 @@ def test_staleness_guard_through_cache(served, tiny):
         cnn.apply(other, state, x, cfg, train=False, sparse=exec_)
 
 
-# ------------------------------------------- deprecated wrappers (parity)
-def test_build_sparse_execution_wrapper_parity(tiny):
-    cfg, pruned, state = tiny
-    with pytest.warns(DeprecationWarning, match="bind_execution"):
-        old = cnn.build_sparse_execution(pruned, n_cu=N_CU)
-    new = cnn.bind_execution(
-        pruned, cfg, spec=cnn.ExecSpec(packed=False, n_cu=N_CU))
-    assert old.spec == new.spec               # legacy defaults preserved
-    assert old.step_counts(cfg, batch=1) == new.step_counts(cfg, batch=1)
-    x = jax.random.uniform(jax.random.PRNGKey(3), (2, 16, 16, 3))
-    a, _ = cnn.apply(pruned, state, x, cfg, train=False, sparse=old)
-    b, _ = cnn.apply(pruned, state, x, cfg, train=False, sparse=new)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_build_sparse_inference_wrapper_parity(tiny):
-    cfg, pruned, state = tiny
-    folded = cnn.fold_batchnorm(pruned, state, cfg)
-    with pytest.warns(DeprecationWarning, match="bind_execution"):
-        old = cnn.build_sparse_inference(folded, cfg, n_cu=N_CU)
-    new = cnn.bind_execution(
-        folded, cfg, spec=cnn.ExecSpec(folded=True, implicit=True,
-                                       n_cu=N_CU))
-    assert old.spec == new.spec and old.folded and new.folded
-    x = jax.random.uniform(jax.random.PRNGKey(4), (2, 16, 16, 3))
-    np.testing.assert_array_equal(
-        np.asarray(cnn.apply_folded(folded, x, cfg, sparse=old)),
-        np.asarray(cnn.apply_folded(folded, x, cfg, sparse=new)))
-
-
 def test_bind_execution_rejects_quant_spec_misuse(tiny):
     cfg, pruned, state = tiny
     from repro.core import quant as Q
@@ -294,33 +263,6 @@ def test_bind_execution_rejects_quant_spec_misuse(tiny):
             folded, cfg,
             spec=cnn.ExecSpec(folded=True, quantized=True, n_cu=N_CU),
             quant_spec=Q.QuantSpec())
-
-
-# ----------------------------------------------- apply(sparse=True) memo
-def test_apply_sparse_true_memo_is_lru(tiny):
-    cfg, _, state = tiny
-    trees = [_tiny(0.5, seed=s)[1] for s in range(3)]
-    x = jnp.zeros((1, 16, 16, 3))
-    old_cap = cnn._SPARSE_EXEC_CACHE_MAX
-    cnn._SPARSE_EXEC_CACHE.clear()
-    try:
-        cnn.set_sparse_exec_cache_capacity(2)
-        for t in trees[:2]:
-            cnn.apply(t, state, x, cfg, train=False, sparse=True)
-        cnn.apply(trees[0], state, x, cfg, train=False, sparse=True)  # touch
-        cnn.apply(trees[2], state, x, cfg, train=False, sparse=True)
-        kept = {k[0] for k in cnn._SPARSE_EXEC_CACHE}
-        # trees[1] (least recently used) evicted, trees[0] survived the
-        # touch — an insert-ordered dict would have evicted trees[0]
-        assert kept == {id(trees[0]), id(trees[2])}
-        # shrinking the capacity evicts immediately, LRU first
-        cnn.set_sparse_exec_cache_capacity(1)
-        assert {k[0] for k in cnn._SPARSE_EXEC_CACHE} == {id(trees[2])}
-        with pytest.raises(ValueError, match=">= 1"):
-            cnn.set_sparse_exec_cache_capacity(0)
-    finally:
-        cnn._SPARSE_EXEC_CACHE.clear()
-        cnn.set_sparse_exec_cache_capacity(old_cap)
 
 
 # -------------------------------------------------------------- batcher

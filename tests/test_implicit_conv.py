@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import fpga_conv_groups, tpu_tile_groups
+from repro.core import fpga_conv_groups
 from repro.kernels import conv_lowering as CL
 from repro.kernels import implicit_conv as IC
 from repro.models import cnn
@@ -49,7 +49,7 @@ def test_implicit_conv_parity_sweep(stride, padding, dtype, density, batch):
     wm = w * spec.expand(jnp.asarray(gm)).astype(dtype)
     x = jnp.asarray(rng.randn(batch, 7, 6, cin), dtype)
 
-    conv = make_sparse_conv(conv_gemm_layout(spec, packed=True), gm,
+    conv = make_sparse_conv(conv_gemm_layout(spec), gm,
                             weight=w, implicit=True)
     assert conv.implicit and conv.prebound
     out = conv(x, stride=stride, padding=padding)
@@ -72,7 +72,7 @@ def test_implicit_equals_materializing_exactly():
     gm = _group_mask(rng, spec.num_groups, 0.4)
     w = jnp.asarray(rng.randn(3, 3, 16, 32).astype(np.float32))
     x = jnp.asarray(rng.randn(2, 9, 8, 16).astype(np.float32))
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     for stride, padding in [(1, "SAME"), (2, "SAME"), (1, "VALID")]:
         outs = {}
         for implicit in (True, False):
@@ -85,22 +85,22 @@ def test_implicit_equals_materializing_exactly():
 
 
 # ragged shapes: cin not a multiple of cpk, cout leaving remainder
-# f_blocks, 1x1 and 3x3 windows, both fpga layouts
+# f_blocks, 1x1 and 3x3 windows
 RAGGED = [
-    (3, 11, 10, 4, True), (3, 16, 32, 12, True), (1, 20, 9, 4, True),
-    (3, 5, 12, 4, False), (1, 7, 9, 4, False),
+    (3, 11, 10, 4), (3, 16, 32, 12), (1, 20, 9, 4), (3, 5, 12, 4),
+    (1, 7, 9, 4),
 ]
 
 
-@pytest.mark.parametrize("kx,cin,cout,n_cu,packed", RAGGED)
-def test_implicit_index_table_roundtrips_im2col(kx, cin, cout, n_cu, packed):
+@pytest.mark.parametrize("kx,cin,cout,n_cu", RAGGED)
+def test_implicit_index_table_roundtrips_im2col(kx, cin, cout, n_cu):
     """Property: gathering the padded NHWC activation through the
     offset-augmented index table reconstructs exactly the live column
     blocks of the materialized packed im2col matrix — the two kernels'
     shared data contract."""
     rng = np.random.RandomState(kx * 1000 + cin * 10 + n_cu)
     spec = fpga_conv_groups((kx, kx, cin, cout), n_cu)
-    layout = conv_gemm_layout(spec, packed=packed)
+    layout = conv_gemm_layout(spec)
     gm = _group_mask(rng, spec.num_groups, 0.5)
     entries, cnt, taps = layout.implicit_index_table(gm)
     geo = layout.implicit_geometry()
@@ -138,15 +138,6 @@ def test_implicit_index_table_roundtrips_im2col(kx, cin, cout, n_cu, packed):
         np.testing.assert_array_equal(rebuilt[:, t * bk:(t + 1) * bk],
                                       packed_patches[:, t * bk:(t + 1) * bk],
                                       err_msg=f"K-tile {t}")
-
-
-def test_implicit_index_table_rejects_tap_major_layouts():
-    spec = tpu_tile_groups((3 * 3 * 5, 20), (32, 128))
-    layout = conv_gemm_layout(spec)
-    with pytest.raises(ValueError, match="channel-major"):
-        layout.implicit_index_table(np.ones(spec.num_groups))
-    with pytest.raises(ValueError, match="channel-major"):
-        make_sparse_conv(layout, np.ones(spec.num_groups), implicit=True)
 
 
 def test_choose_m_block_invariants():
@@ -201,7 +192,7 @@ def test_folded_implicit_conv_matches_materializing(h, k, stride, batch,
                                          jnp.float32), relu=True)
     if form == "int8_streamed":
         kw.update(quant=QuantSpec(), out_quant=QuantSpec())
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     convs = {implicit: make_sparse_conv(layout, gm, implicit=implicit, **kw)
              for implicit in (True, False)}
     outs = {implicit: np.asarray(conv(x, stride=stride))
@@ -224,8 +215,7 @@ def test_packed_layout_channel_slot(k, cin, slot, cpk):
     """A packed 1x1 K-tile holds 128 channels, one row each, so its tile
     is one 128-lane channel group of the activation; 3x3 and 7x7
     channels keep their 8-aligned slots of 16 and 56 rows."""
-    layout = conv_gemm_layout(fpga_conv_groups((k, k, cin, 64), 12),
-                              packed=True)
+    layout = conv_gemm_layout(fpga_conv_groups((k, k, cin, 64), 12))
     geo = layout.implicit_geometry()
     assert (geo["slot"], geo["cpk"]) == (slot, cpk)
     assert layout.block[0] == 128 and layout.tiles[0] == -(-cin // cpk)
@@ -261,7 +251,7 @@ def test_pointwise_conv_matches_materializing(h, stride, cin, batch):
     rng = np.random.RandomState(h * 10_000 + cin * 10 + stride + batch)
     cout, n_cu = 160, 12                    # two N-tiles of 10 f_blocks
     spec = fpga_conv_groups((1, 1, cin, cout), n_cu)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     gm = _pointwise_mask(rng, spec, layout)
     plan = layout.plan(gm)
     assert plan.cnt[1] < plan.cnt[0] <= layout.tiles[0]
@@ -312,7 +302,7 @@ def test_images_per_block_rule():
     spec = fpga_conv_groups((3, 3, 16, 16), 4)
     gm = np.ones(spec.num_groups, np.float32)
     w = jnp.ones((3, 3, 16, 16), jnp.float32)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     assert implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8).ipb == 8
     assert implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8,
                             activation_dsb=True).ipb == 1
@@ -332,7 +322,7 @@ def test_fold_shrinks_to_the_vmem_budget(monkeypatch):
     largest that fits before the layer leaves the implicit path."""
     from repro.sparse.conv_plan import implicit_m_block
     spec = fpga_conv_groups((3, 3, 16, 16), 4)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     full = implicit_m_block(layout, 4, 4, 1, "SAME", 1, batch=8)
     assert full.ipb == 8
     need = {d: IC.window_vmem_bytes(full._replace(ipb=d), 3, 3, 1, 128, 1)
@@ -354,7 +344,7 @@ def test_implicit_falls_back_to_materializing(monkeypatch):
     gm = _group_mask(rng, spec.num_groups, 0.5)
     w = jnp.asarray(rng.randn(1, 1, 4, 8).astype(np.float32))
     wm = w * spec.expand(jnp.asarray(gm))
-    conv = make_sparse_conv(conv_gemm_layout(spec, packed=True), gm, weight=w,
+    conv = make_sparse_conv(conv_gemm_layout(spec), gm, weight=w,
                             implicit=True)
     # 130-wide rows: segmented M-blocks keep the implicit path
     x = jnp.asarray(rng.randn(1, 2, 130, 4).astype(np.float32))
@@ -382,7 +372,7 @@ def test_wide_input_keeps_implicit_path(stride, padding):
     w = jnp.asarray(rng.randn(3, 3, 8, 8).astype(np.float32))
     wm = w * spec.expand(jnp.asarray(gm))
     x = jnp.asarray(rng.randn(1, 64, 256, 8).astype(np.float32))
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     ho = CL.conv_out_size(64, 3, stride, padding)
     wo = CL.conv_out_size(256, 3, stride, padding)
     mb = IC.choose_m_block(ho, wo)
@@ -430,7 +420,7 @@ def _pruned_tiny_resnet(target=0.5, n_cu=4):
 
 
 def test_implicit_exec_end_to_end_matches_materializing():
-    """build_sparse_execution(implicit=True) == implicit=False == dense on
+    """An ``implicit=True`` bind == ``implicit=False`` == dense on
     a HAPM-pruned net, with identical schedule accounting, every layer on
     the requested route, and each exec's analytic HBM bytes priced on
     that route (kernel layers bound on both paths)."""
@@ -440,9 +430,10 @@ def test_implicit_exec_end_to_end_matches_materializing():
     dense, _ = cnn.apply(pruned, state, x, cfg)
     execs = {}
     for implicit in (True, False):
-        e = cnn.build_sparse_execution(
-            pruned, n_cu=n_cu, specs=specs, group_masks=st.group_masks,
-            packed=True, implicit=implicit, dense_fallback=2.0)
+        e = cnn.bind_execution(
+            pruned, spec=cnn.ExecSpec(n_cu=n_cu, implicit=implicit,
+                                      dense_fallback=2.0),
+            specs=specs, group_masks=st.group_masks)
         out, _ = cnn.apply(pruned, state, x, cfg, sparse=e)
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                    rtol=1e-4, atol=1e-4)
@@ -471,7 +462,7 @@ def test_conv_hbm_bytes_contract():
     copy of the activation; the materializing path writes and re-reads
     the patch matrix."""
     spec = fpga_conv_groups((3, 3, 16, 32), 12)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     gm = np.ones(spec.num_groups, np.float32)
     imp = conv_hbm_bytes(layout, gm, 1, 16, 16, implicit=True)
     mat = conv_hbm_bytes(layout, gm, 1, 16, 16, implicit=False, bm=128)

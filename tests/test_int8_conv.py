@@ -6,7 +6,7 @@ everywhere accumulation is int32 (the arithmetic is integer, and the
 static power-of-two dequant scales make the f32 epilogue exact), plus
 ≤ quant-tolerance agreement with the unquantized f32 reference. Overflow
 edges (all-±127 operands), fully-pruned-column dequant→bias flush, the
-end-to-end ``build_sparse_execution(quantized=True)`` == QAT-forward
+end-to-end ``ExecSpec(quantized=True)`` bind == QAT-forward
 bit-parity, the calibrated folded-BN inference path, and the int8 HBM
 operand pricing.
 """
@@ -59,7 +59,7 @@ def test_quantized_parity_sweep(stride, padding, density, batch):
     x = jnp.asarray(rng.uniform(-4, 4, (batch, 7, 6, cin)).astype(np.float32))
     wm = w * spec.expand(jnp.asarray(gm))
     qspec = QuantSpec()
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
 
     outs = {}
     for implicit in (True, False):
@@ -101,7 +101,7 @@ def test_overflow_edge_all_saturated_codes():
     x = jnp.full((1, 6, 6, cin), Q3_4.max_val, jnp.float32)
     assert int(jnp.abs(qspec.weight_codes(w)).min()) == 127
     assert int(jnp.abs(qspec.act_codes(x)).min()) == 127
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     expect = ref.int8_conv_ref(qspec.act_codes(x), qspec.weight_codes(w),
                                np.asarray(qspec.dequant_row(cout)), 1, "SAME")
     assert float(jnp.abs(expect).max()) >= 576 * 127 * 127 / 512 * 0.4
@@ -127,12 +127,11 @@ def test_fully_pruned_column_dequant_bias_flush():
     expect = ref.int8_conv_ref(qspec.act_codes(x), qspec.weight_codes(wm),
                                np.asarray(qspec.dequant_row(32)), 1, "SAME",
                                bias=b, relu=True)
-    for layout in (conv_gemm_layout(spec, packed=True), conv_gemm_layout(spec)):
-        for implicit in (True, False):
-            conv = make_sparse_conv(layout, gm, weight=w, bias=b, relu=True,
-                                    implicit=implicit, quant=qspec)
-            out = conv(x, stride=1, padding="SAME")
-            np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+    for implicit in (True, False):
+        conv = make_sparse_conv(conv_gemm_layout(spec), gm, weight=w, bias=b,
+                                relu=True, implicit=implicit, quant=qspec)
+        out = conv(x, stride=1, padding="SAME")
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
     # the dead f_block's lanes are exactly relu(bias)
     dead = np.asarray(expect[..., 24:])               # last f_block (n_cu=12)
     np.testing.assert_array_equal(
@@ -140,7 +139,7 @@ def test_fully_pruned_column_dequant_bias_flush():
 
 
 def test_quantized_exec_matches_qat_forward_exactly():
-    """build_sparse_execution(quantized=True): int8 kernels on both paths
+    """An ``ExecSpec(quantized=True)`` bind: int8 kernels on both paths
     reproduce the dense QAT (fake-quant) forward bit-for-bit, with
     schedule accounting identical to the f32 exec and <= 0.5x the
     f32-operand HBM bytes."""
@@ -159,9 +158,10 @@ def test_quantized_exec_matches_qat_forward_exactly():
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
     qat, _ = cnn.apply(pruned, state, x, cfg)
 
-    common = dict(n_cu=n_cu, specs=specs, group_masks=st.group_masks,
-                  packed=True, quantized=True, dense_fallback=2.0)
-    execs = {imp: cnn.build_sparse_execution(pruned, implicit=imp, **common)
+    execs = {imp: cnn.bind_execution(
+                 pruned, spec=cnn.ExecSpec(n_cu=n_cu, quantized=True,
+                                           implicit=imp, dense_fallback=2.0),
+                 specs=specs, group_masks=st.group_masks)
              for imp in (True, False)}
     for imp, e in execs.items():
         out, _ = cnn.apply(pruned, state, x, cfg, sparse=e)
@@ -175,9 +175,10 @@ def test_quantized_exec_matches_qat_forward_exactly():
                                            sparse=execs[True])[0])(pruned, x)
     np.testing.assert_array_equal(np.asarray(jout), np.asarray(qat))
 
-    f32_exec = cnn.build_sparse_execution(
-        pruned, n_cu=n_cu, specs=specs, group_masks=st.group_masks,
-        packed=True, implicit=True, dense_fallback=2.0)
+    f32_exec = cnn.bind_execution(
+        pruned, spec=cnn.ExecSpec(n_cu=n_cu, implicit=True,
+                                  dense_fallback=2.0),
+        specs=specs, group_masks=st.group_masks)
     assert (execs[True].schedule_step_counts()
             == f32_exec.schedule_step_counts())
     assert (execs[True].step_counts(cfg, batch=1)
@@ -193,12 +194,12 @@ def test_quantized_exec_matches_qat_forward_exactly():
     with pytest.raises(ValueError, match="quantized"):
         cnn.apply(pruned, state, x, ucfg, sparse=execs[True])
     with pytest.raises(ValueError, match="quant_spec"):
-        cnn.build_sparse_execution(pruned, n_cu=n_cu,
-                                   quant_spec=QuantSpec())
+        cnn.bind_execution(pruned, spec=cnn.ExecSpec(n_cu=n_cu),
+                           quant_spec=QuantSpec())
 
 
 def test_calibrated_quant_spec_sees_raw_weights():
-    """Regression: build_sparse_execution(quant_spec=calibrated) must emit
+    """Regression: ``bind_execution(quant_spec=calibrated)`` must emit
     codes from the RAW weights — pre-quantizing onto the static Q2.5 grid
     first would clip a wide-range channel to ±4 and then rescale it ~25x
     too small (double quantization)."""
@@ -208,9 +209,10 @@ def test_calibrated_quant_spec_sees_raw_weights():
     w = jnp.asarray(w)
     cal = QuantSpec.calibrate(w)
     x = jnp.asarray(rng.uniform(-4, 4, (1, 8, 8, 8)).astype(np.float32))
-    exec_ = cnn.build_sparse_execution({"c": {"w": w}}, n_cu=4,
-                                       quantized=True, quant_spec=cal,
-                                       dense_fallback=2.0)
+    exec_ = cnn.bind_execution(
+        {"c": {"w": w}},
+        spec=cnn.ExecSpec(n_cu=4, quantized=True, dense_fallback=2.0),
+        quant_spec=cal)
     conv = exec_.table[("c", "w")]
     out = conv(x, stride=1, padding="SAME")
     expect = ref.int8_conv_ref(cal.act_codes(x), cal.weight_codes(w),
@@ -224,7 +226,7 @@ def test_calibrated_quant_spec_sees_raw_weights():
 
 
 def test_quantized_folded_inference_calibrated():
-    """fold_batchnorm -> build_sparse_inference(quantized=True): per-cout
+    """fold_batchnorm -> quantized folded bind: per-cout
     calibrated weight scales absorb the BN folding, the fused
     dequant→bias→ReLU epilogue runs in-kernel, and logits stay within
     activation-quantization tolerance of the float folded path."""
@@ -243,9 +245,11 @@ def test_quantized_folded_inference_calibrated():
     folded = cnn.fold_batchnorm(pruned, state, cfg)
     plain = cnn.apply_folded(folded, x, cfg)
     for implicit in (True, False):
-        inf = cnn.build_sparse_inference(folded, cfg, n_cu=n_cu,
-                                         group_masks=st.group_masks,
-                                         quantized=True, implicit=implicit)
+        inf = cnn.bind_execution(
+            folded, cfg,
+            spec=cnn.ExecSpec(folded=True, quantized=True, implicit=implicit,
+                              n_cu=n_cu),
+            group_masks=st.group_masks)
         assert inf.quantized and inf.folded
         out = cnn.apply_folded(folded, x, cfg, sparse=inf)
         # activations quantize to Q3.4 (1/16 LSB) per layer: tolerance is
@@ -259,7 +263,7 @@ def test_conv_hbm_bytes_int8_operand_pricing():
     ingest, window slabs, patch matrix, patch reads and weight tiles —
     never the f32 output write."""
     spec = fpga_conv_groups((3, 3, 16, 32), 12)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     gm = np.ones(spec.num_groups, np.float32)
     for implicit in (True, False):
         f32 = conv_hbm_bytes(layout, gm, 1, 16, 16, implicit=implicit, bm=128)

@@ -166,42 +166,57 @@ def test_apply_group_mask_matches_expand_tpu(K, N, lead, bk, bn, seed):
         np.asarray(w * spec.expand(gm)), rtol=1e-6, atol=0)
 
 
-@given(kx=st.integers(1, 3), cin=st.integers(1, 5), cout=st.integers(1, 20),
-       n_cu=st.integers(1, 8), seed=st.integers(0, 99))
-@settings(**SETTINGS)
-def test_conv_plan_tiles_are_groups(kx, cin, cout, n_cu, seed):
-    """FPGA conv GEMM layout: one tile per (g, f_block) group — the plan's
-    live-tile count always equals the live-group count."""
-    spec = fpga_conv_groups((kx, kx, cin, cout), n_cu)
-    rng = np.random.RandomState(seed)
-    gm = (rng.rand(spec.num_groups) > 0.5).astype(np.float32)
-    layout = conv_gemm_layout(spec)
-    plan = layout.plan(gm)
-    assert plan.tiles == (cin, spec.n_fblocks)
-    assert int(plan.cnt.sum()) == int(gm.sum())
-    assert layout.k_packed % 8 == 0 and layout.n_packed % 128 == 0
-
-
 @given(kx=st.integers(1, 3), cin=st.integers(1, 40), cout=st.integers(1, 40),
        n_cu=st.integers(1, 16), seed=st.integers(0, 99))
 @settings(**SETTINGS)
 def test_packed_conv_plan_occupancy_exact(kx, cin, cout, n_cu, seed):
     """Packed MXU-shaped layout: per-tile occupancy preserves the paper's
     schedule-step accounting exactly (live groups == occupancy sum) while
-    never dispatching more tiles than the one-group-per-tile layout."""
+    never dispatching more tiles than there are (live) groups."""
     spec = fpga_conv_groups((kx, kx, cin, cout), n_cu)
     rng = np.random.RandomState(seed)
     gm = (rng.rand(spec.num_groups) > 0.5).astype(np.float32)
-    packed = conv_gemm_layout(spec, packed=True)
-    pergroup = conv_gemm_layout(spec)
+    packed = conv_gemm_layout(spec)
     live, total = packed.tile_occupancy(gm)
     assert int(live.sum()) == int(gm.sum())
     assert int(total.sum()) == spec.num_groups
     assert (packed.tile_mask(gm) == (live > 0)).all()
-    p_plan, g_plan = packed.plan(gm), pergroup.plan(gm)
-    assert int(p_plan.cnt.sum()) <= int(g_plan.cnt.sum())
-    assert np.prod(p_plan.tiles) <= np.prod(g_plan.tiles)
+    p_plan = packed.plan(gm)
+    assert int(p_plan.cnt.sum()) <= int(gm.sum())
+    assert np.prod(p_plan.tiles) <= spec.num_groups
     assert packed.k_packed % 8 == 0 and packed.n_packed % 128 == 0
+
+
+@pytest.mark.parametrize("shape", [
+    (7, 7, 3, 64),          # stem
+    (1, 1, 64, 256),        # s0 expand / projection
+    (1, 1, 256, 64),        # s0 reduce
+    (3, 3, 128, 128),       # s1 3x3
+    (1, 1, 1024, 2048),     # s3 projection
+    (3, 3, 512, 512),       # s3 3x3
+])
+def test_packed_occupancy_at_resnet50_shapes(shape):
+    """ResNet-50 weight shapes, where a 1x1 K-tile holds 128 channels and
+    is full, and a layer spans many K- and N-tiles: at n_cu 12 and a
+    seeded 50 % group mask, occupancy counts every group once, a tile is
+    live iff one of its groups is, and the plan dispatches the live
+    tiles."""
+    spec = fpga_conv_groups(shape, 12)
+    rng = np.random.RandomState(sum(shape))
+    gm = (rng.rand(spec.num_groups) < 0.5).astype(np.float32)
+    layout = conv_gemm_layout(spec)
+    live, total = layout.tile_occupancy(gm)
+    assert int(live.sum()) == int(gm.sum())
+    assert int(total.sum()) == spec.num_groups
+    # a tile is live iff one of the groups it packs is
+    cpk = layout.implicit_geometry()["cpk"]
+    fpn = layout.block[1] // spec.n_cu
+    g = gm.reshape(shape[2], spec.n_fblocks) > 0
+    expect = np.zeros(layout.tiles, bool)
+    for c, f in zip(*np.nonzero(g)):
+        expect[c // cpk, f // fpn] = True
+    np.testing.assert_array_equal(layout.tile_mask(gm), expect)
+    assert int(layout.plan(gm).cnt.sum()) == int(expect.sum())
 
 
 @given(seed=st.integers(0, 99))

@@ -145,8 +145,7 @@ def test_k_row_fill_of_the_packed_layout(k, cin, fill):
     ``k*k`` taps in a slot of ``ceil8(k*k)`` rows (one row for a 1x1),
     ``128 // slot`` channels a tile, the last tile holding what is left
     of ``cin``."""
-    layout = conv_gemm_layout(fpga_conv_groups((k, k, cin, 64), 12),
-                              packed=True)
+    layout = conv_gemm_layout(fpga_conv_groups((k, k, cin, 64), 12))
     assert layout.k_row_fill(np.ones(layout.spec.num_groups)) == fill
 
 

@@ -1,8 +1,8 @@
 """Group-sparse conv path vs the ``lax.conv`` oracle (interpret mode).
 
 Sweeps stride, padding, non-tile-aligned ``cin*kx*ky``, remainder ``cout``
-(``n_cu`` not dividing ``cout``), density {0, 0.3, 1.0}, f32/bf16 — on
-both the one-group-per-tile and the packed MXU-shaped layouts — and the
+(``n_cu`` not dividing ``cout``), density {0, 0.3, 1.0}, f32/bf16 on the
+packed MXU-shaped layout — and the
 end-to-end ``cnn.apply(..., sparse=...)`` /
 ``fold_batchnorm -> apply_folded`` acceptance paths on a HAPM-pruned tiny
 ResNet.
@@ -13,8 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core import (HAPMConfig, apply_masks, fpga_conv_groups,
-                        hapm_element_masks, hapm_epoch_update, hapm_init,
-                        tpu_tile_groups)
+                        hapm_element_masks, hapm_epoch_update, hapm_init)
 from repro.kernels import conv_lowering as CL
 from repro.models import cnn
 from repro.sparse.conv_plan import conv_gemm_layout, make_sparse_conv
@@ -51,9 +50,11 @@ def test_im2col_lowering_matches_lax_conv(stride, padding, kx, H, W):
                                rtol=1e-5, atol=1e-5)
 
 
-# stride {1,2} x SAME/VALID; cin*kx*ky never a multiple of the K tile;
-# cout = 10 or 9 leaves a remainder f_block (n_cu=4); densities {0, .3, 1}
-CASES = [
+# packed-layout sweep: stride {1,2} x SAME/VALID x n_cu {4,12} x f32/bf16
+# x density {0, .3, 1}; cin*kx*ky never a multiple of the K tile, cin
+# chosen so some cases span multiple K-tiles (cpk=8 channels/tile for
+# 3x3) and cout leaves remainder f_blocks (n_cu not dividing cout)
+PACKED_CASES = [
     (1, "SAME", 3, 3, 10, 4, 0.3, jnp.float32),
     (2, "SAME", 3, 5, 12, 4, 0.3, jnp.float32),
     (1, "VALID", 3, 4, 10, 4, 0.3, jnp.float32),
@@ -62,36 +63,6 @@ CASES = [
     (2, "SAME", 3, 2, 6, 4, 0.0, jnp.float32),   # fully pruned -> zeros
     (1, "SAME", 3, 3, 10, 4, 0.3, jnp.bfloat16),
     (2, "SAME", 3, 5, 9, 4, 0.3, jnp.bfloat16),
-]
-
-
-@pytest.mark.parametrize("stride,padding,kx,cin,cout,n_cu,density,dtype", CASES)
-def test_sparse_conv_parity(stride, padding, kx, cin, cout, n_cu, density, dtype):
-    rng = np.random.RandomState(hash((stride, kx, cin, cout)) % 2**31)
-    spec = fpga_conv_groups((kx, kx, cin, cout), n_cu)
-    gm = _group_mask(rng, spec.num_groups, density)
-    w = jnp.asarray(rng.randn(kx, kx, cin, cout), dtype)
-    wm = (w * spec.expand(jnp.asarray(gm)).astype(dtype))
-    x = jnp.asarray(rng.randn(2, 9, 8, cin), dtype)
-
-    conv = make_sparse_conv(conv_gemm_layout(spec), gm)
-    out = conv(x, wm, stride, padding)
-    expect = _oracle(x, wm, stride, padding)
-    assert out.shape == expect.shape and out.dtype == expect.dtype
-    tol = 1e-4 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expect, np.float32), rtol=tol, atol=tol)
-    if density == 0.0:
-        assert float(jnp.abs(out).max()) == 0.0
-    # plan == groups, exactly (the bridge's core claim)
-    assert conv.plan.tiles == (cin, spec.n_fblocks)
-    assert int(conv.plan.cnt.sum()) == int(gm.sum())
-
-
-# packed-layout sweep: stride {1,2} x SAME/VALID x n_cu {4,12} x f32/bf16
-# x density {0, .3, 1}; cin chosen so some cases span multiple K-tiles
-# (cpk=8 channels/tile for 3x3) and cout leaves remainder f_blocks
-PACKED_CASES = [
     (1, "SAME", 3, 16, 32, 12, 0.3, jnp.float32),   # 2 K-tiles, ragged f_blocks
     (2, "SAME", 3, 16, 32, 12, 0.3, jnp.float32),
     (1, "VALID", 3, 9, 10, 4, 0.3, jnp.float32),
@@ -117,7 +88,7 @@ def test_packed_sparse_conv_parity(stride, padding, kx, cin, cout, n_cu,
     wm = (w * spec.expand(jnp.asarray(gm)).astype(dtype))
     x = jnp.asarray(rng.randn(2, 9, 8, cin), dtype)
 
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     # bind-time prepacking masks the weight itself: pass the UNMASKED w
     conv = make_sparse_conv(layout, gm, weight=w)
     assert conv.prebound
@@ -140,10 +111,9 @@ def test_packed_sparse_conv_parity(stride, padding, kx, cin, cout, n_cu,
     assert int(live.sum()) == int(gm.sum())
     assert int(total.sum()) == spec.num_groups
     np.testing.assert_array_equal(layout.tile_mask(gm), live > 0)
-    # never more grid tiles than the one-group-per-tile layout
-    pergroup = conv_gemm_layout(spec)
-    assert np.prod(layout.tiles) <= np.prod(pergroup.tiles)
-    assert int(conv.plan.cnt.sum()) <= int(pergroup.plan(gm).cnt.sum())
+    # never more grid tiles than groups, nor live tiles than live groups
+    assert np.prod(layout.tiles) <= spec.num_groups
+    assert int(conv.plan.cnt.sum()) <= int(gm.sum())
 
 
 def test_packed_epilogue_bias_relu_parity():
@@ -157,12 +127,12 @@ def test_packed_epilogue_bias_relu_parity():
     b = jnp.asarray(rng.randn(32).astype(np.float32))
     wm = w * spec.expand(jnp.asarray(gm))
     x = jnp.asarray(rng.randn(2, 9, 8, 16).astype(np.float32))
-    for layout in (conv_gemm_layout(spec, packed=True), conv_gemm_layout(spec)):
-        conv = make_sparse_conv(layout, gm, weight=w, bias=b, relu=True)
-        out = conv(x, stride=1, padding="SAME")
-        expect = jax.nn.relu(_oracle(x, wm, 1, "SAME") + b)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                                   rtol=1e-4, atol=1e-4)
+    conv = make_sparse_conv(conv_gemm_layout(spec), gm, weight=w, bias=b,
+                            relu=True)
+    out = conv(x, stride=1, padding="SAME")
+    expect = jax.nn.relu(_oracle(x, wm, 1, "SAME") + b)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_valid_conv_smaller_than_kernel_raises():
@@ -177,21 +147,6 @@ def test_valid_conv_smaller_than_kernel_raises():
     assert CL.im2col_patches(x, 3, 3, 1, "SAME").shape == (1, 2, 5, 3, 3, 3)
     # and a kernel-sized input has exactly one VALID output pixel
     assert CL.conv_out_size(3, 3, 2, "VALID") == 1
-
-
-def test_sparse_conv_tile_layout_parity():
-    """TPU-native path: TpuTileGroupSpec over the 2-D im2col matrix."""
-    rng = np.random.RandomState(7)
-    kx, cin, cout = 3, 5, 20
-    spec = tpu_tile_groups((kx * kx * cin, cout), (32, 128))   # ragged K (45)
-    gm = (rng.rand(spec.num_groups) < 0.5).astype(np.float32)
-    w = jnp.asarray(rng.randn(kx, kx, cin, cout).astype(np.float32))
-    wm = w * spec.expand(jnp.asarray(gm)).reshape(w.shape)
-    x = jnp.asarray(rng.randn(2, 9, 8, cin).astype(np.float32))
-    conv = make_sparse_conv(conv_gemm_layout(spec), gm)
-    out = conv(x, wm, 1, "SAME")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(_oracle(x, wm, 1, "SAME")),
-                               rtol=1e-4, atol=1e-4)
 
 
 def _pruned_tiny_resnet(target=0.5, n_cu=4):
@@ -211,46 +166,34 @@ def _pruned_tiny_resnet(target=0.5, n_cu=4):
 
 def test_cnn_apply_sparse_matches_dense():
     """Acceptance: HAPM-pruned tiny ResNet, sparse == dense within 1e-4 and
-    dispatched grid steps at 50 % group sparsity <= 60 % of dense."""
+    live schedule steps at 50 % group sparsity <= 60 % of dense."""
     n_cu = 4
     cfg, pruned, state, specs, st = _pruned_tiny_resnet(0.5, n_cu)
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
     dense, _ = cnn.apply(pruned, state, x, cfg)
 
-    exec_ = cnn.build_sparse_execution(pruned, n_cu=n_cu, specs=specs,
-                                       group_masks=st.group_masks)
+    exec_ = cnn.bind_execution(pruned, spec=cnn.ExecSpec(n_cu=n_cu),
+                               specs=specs, group_masks=st.group_masks)
     sparse, _ = cnn.apply(pruned, state, x, cfg, sparse=exec_)
     np.testing.assert_allclose(np.asarray(sparse), np.asarray(dense),
                                rtol=1e-4, atol=1e-4)
 
     executed, dense_steps = exec_.step_counts(cfg, batch=2)
-    assert executed / dense_steps <= 0.6
+    assert executed <= dense_steps
+    live, total = exec_.schedule_step_counts()
+    assert live / total <= 0.6
 
-    # sparse=True derives the same plans from the pruned weights' zero slabs
-    auto, _ = cnn.apply(pruned, state, x, cfg, sparse=True)
+    # masks derived from the pruned weights' zero slabs give the same plans
+    auto_exec = cnn.bind_execution(pruned, spec=cnn.ExecSpec(n_cu=n_cu))
+    assert auto_exec.schedule_step_counts() == (live, total)
+    auto, _ = cnn.apply(pruned, state, x, cfg, sparse=auto_exec)
     np.testing.assert_allclose(np.asarray(auto), np.asarray(dense),
                                rtol=1e-4, atol=1e-4)
 
 
-def test_cnn_apply_sparse_with_tile_specs():
-    """TPU-native granularity end to end: conv_tile_group_specs over the
-    im2col matrices, plans derived from the pruned weights' zero slabs."""
-    n_cu = 4
-    cfg, pruned, state, _, _ = _pruned_tiny_resnet(0.5, n_cu)
-    tile_specs = cnn.conv_tile_group_specs(pruned, block=(32, 128))
-    exec_ = cnn.build_sparse_execution(pruned, specs=tile_specs)
-    x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
-    dense, _ = cnn.apply(pruned, state, x, cfg)
-    sparse, _ = cnn.apply(pruned, state, x, cfg, sparse=exec_)
-    np.testing.assert_allclose(np.asarray(sparse), np.asarray(dense),
-                               rtol=1e-4, atol=1e-4)
-    executed, dense_steps = exec_.step_counts(cfg, batch=2)
-    assert executed <= dense_steps
-
-
 def test_cnn_apply_packed_exec_matches_dense():
-    """Packed MXU-shaped exec: same logits, >=4x fewer dispatched grid
-    steps than the per-group layout, identical schedule-step accounting."""
+    """Packed MXU-shaped exec: same logits, fewer dispatched grid steps
+    than schedule groups, exact schedule-step accounting."""
     n_cu = 12
     cfg = cnn.ResNetConfig(stages=(1, 1), widths=(16, 32), image_size=16)
     params, state = cnn.init(jax.random.PRNGKey(0), cfg)
@@ -265,35 +208,29 @@ def test_cnn_apply_packed_exec_matches_dense():
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
     dense, _ = cnn.apply(pruned, state, x, cfg)
 
-    execs = {p: cnn.build_sparse_execution(pruned, n_cu=n_cu, specs=specs,
-                                           group_masks=st.group_masks, packed=p)
-             for p in (False, True)}
-    for packed, exec_ in execs.items():
-        out, _ = cnn.apply(pruned, state, x, cfg, sparse=exec_)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
-                                   rtol=1e-4, atol=1e-4)
-    # grid: packed dispatches a fraction of the per-group steps
-    packed_exec, _ = execs[True].step_counts(cfg, batch=2)
-    pergroup_exec, _ = execs[False].step_counts(cfg, batch=2)
-    assert packed_exec * 4 <= pergroup_exec
-    # schedule: occupancy accounting is layout-independent and exact
+    exec_ = cnn.bind_execution(pruned, spec=cnn.ExecSpec(n_cu=n_cu),
+                               specs=specs, group_masks=st.group_masks)
+    out, _ = cnn.apply(pruned, state, x, cfg, sparse=exec_)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
+                               rtol=1e-4, atol=1e-4)
+    # schedule: occupancy accounting is exact
     live = int(sum(np.asarray(cnn._get_path(st.group_masks, k)).sum()
-                   for k in execs[True].plans))
+                   for k in exec_.plans))
     total = sum(np.asarray(cnn._get_path(st.group_masks, k)).size
-                for k in execs[True].plans)
-    assert execs[True].schedule_step_counts() == (live, total)
-    assert execs[False].schedule_step_counts() == (live, total)
-    # padding drops with packing at full density: dispatched-tile MAC
-    # utilization of the dense plan improves
-    dense_gm = {k: np.ones_like(v) for k, v in execs[True].group_masks_np.items()}
-    ld = {p: cnn.SparseConvExec(table=e.table, plans=e.plans, n_cu=n_cu,
-                                layouts=e.layouts, group_masks_np=dense_gm)
-          for p, e in execs.items()}
-    assert ld[True].mac_utilization(cfg, batch=2) > 2 * ld[False].mac_utilization(cfg, batch=2)
+                for k in exec_.plans)
+    assert exec_.schedule_step_counts() == (live, total)
+    # grid: packed tiles cover many groups, so the live tiles per M-block
+    # are a fraction of the live groups
+    assert sum(int(p.cnt.sum()) for p in exec_.plans.values()) * 4 <= live
+    # dispatched-tile MAC utilization of the dense plan is a share
+    dense_gm = {k: np.ones_like(v) for k, v in exec_.group_masks_np.items()}
+    ld = cnn.SparseConvExec(table=exec_.table, plans=exec_.plans, n_cu=n_cu,
+                            layouts=exec_.layouts, group_masks_np=dense_gm)
+    assert 0.0 < ld.mac_utilization(cfg, batch=2) <= 1.0
 
 
 def test_fold_batchnorm_sparse_inference_e2e():
-    """fold_batchnorm -> build_sparse_inference (fused bias/ReLU epilogue)
+    """fold_batchnorm -> folded bind (fused bias/ReLU epilogue)
     matches dense BN inference within 1e-4 and preserves zero groups."""
     n_cu = 4
     cfg, pruned, state, specs, st = _pruned_tiny_resnet(0.5, n_cu)
@@ -316,14 +253,13 @@ def test_fold_batchnorm_sparse_inference_e2e():
     plain = cnn.apply_folded(folded, x, cfg)
     np.testing.assert_allclose(np.asarray(plain), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
-    # sparse folded path, packed layout + in-kernel bias/ReLU epilogue
-    for packed in (True, False):
-        inf = cnn.build_sparse_inference(folded, cfg, n_cu=n_cu,
-                                         group_masks=st.group_masks,
-                                         packed=packed)
-        out = cnn.apply_folded(folded, x, cfg, sparse=inf)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)
+    # sparse folded path, in-kernel bias/ReLU epilogue
+    inf = cnn.bind_execution(
+        folded, cfg, spec=cnn.ExecSpec(folded=True, implicit=True, n_cu=n_cu),
+        group_masks=st.group_masks)
+    out = cnn.apply_folded(folded, x, cfg, sparse=inf)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
     # the jitted end-to-end inference graph also agrees
     jout = jax.jit(lambda xx: cnn.apply_folded(folded, xx, cfg, sparse=inf))(x)
     np.testing.assert_allclose(np.asarray(jout), np.asarray(ref),
@@ -331,20 +267,16 @@ def test_fold_batchnorm_sparse_inference_e2e():
 
 
 def test_sparse_true_is_memoized_and_rejects_tracers():
-    """sparse=True no longer rebuilds the plan table per call: builds are
-    memoized on params identity; under jit it raises instead of silently
-    tracing host-side plan construction."""
+    """Binds are host-side: under jit ``bind_execution`` raises
+    :class:`PermanentBindError` instead of silently tracing host-side plan
+    construction, while a prebuilt exec is jittable."""
     cfg, pruned, state, _, _ = _pruned_tiny_resnet(0.5, 4)
-    e1 = cnn._resolve_sparse(True, pruned)
-    e2 = cnn._resolve_sparse(True, pruned)
-    assert e1 is e2
-    # a different params tree gets its own build
-    other = jax.tree_util.tree_map(lambda l: l, pruned)
-    assert cnn._resolve_sparse(True, other) is not e1
+    e1 = cnn.bind_execution(pruned, spec=cnn.ExecSpec(n_cu=4))
 
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
-    with pytest.raises(ValueError, match="outside jit"):
-        jax.jit(lambda p: cnn.apply(p, state, x, cfg, sparse=True)[0])(pruned)
+    with pytest.raises(cnn.PermanentBindError, match="outside jit"):
+        jax.jit(lambda p: cnn.bind_execution(
+            p, spec=cnn.ExecSpec(n_cu=4)).n_cu)(pruned)
     # prebuilt execs ARE jittable (plans become compile-time constants)
     out = jax.jit(lambda p, xx: cnn.apply(p, state, xx, cfg, sparse=e1)[0])(pruned, x)
     dense, _ = cnn.apply(pruned, state, x, cfg)
@@ -371,12 +303,13 @@ def test_folded_and_plain_execs_are_not_interchangeable():
     cfg, pruned, state, _, st = _pruned_tiny_resnet(0.5, 4)
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 16, 3))
     folded = cnn.fold_batchnorm(pruned, state, cfg)
-    inf = cnn.build_sparse_inference(folded, cfg, n_cu=4,
-                                     group_masks=st.group_masks)
+    inf = cnn.bind_execution(
+        folded, cfg, spec=cnn.ExecSpec(folded=True, implicit=True, n_cu=4),
+        group_masks=st.group_masks)
     with pytest.raises(ValueError, match="apply_folded"):
         cnn.apply(pruned, state, x, cfg, sparse=inf)
-    plain = cnn.build_sparse_execution(pruned, n_cu=4,
-                                       group_masks=st.group_masks)
+    plain = cnn.bind_execution(pruned, spec=cnn.ExecSpec(n_cu=4),
+                               group_masks=st.group_masks)
     with pytest.raises(ValueError, match="folded SparseConvExec"):
         cnn.apply_folded(folded, x, cfg, sparse=plain)
 
@@ -385,7 +318,7 @@ def test_cnn_apply_dense_fallback_on_unpruned():
     """Density ~1 layers stay on lax.conv: identical output, no bound kernel."""
     cfg = cnn.ResNetConfig(stages=(1,), widths=(8,), image_size=8)
     params, state = cnn.init(jax.random.PRNGKey(0), cfg)
-    exec_ = cnn.build_sparse_execution(params, n_cu=4)
+    exec_ = cnn.bind_execution(params, spec=cnn.ExecSpec(n_cu=4))
     assert all(fn is None for fn in exec_.table.values())
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 8, 8, 3))
     dense, _ = cnn.apply(params, state, x, cfg)
@@ -409,8 +342,8 @@ def test_simulator_reports_grid_steps():
     assert 0.0 < rep.dsb_cycle_ratio < 1.0
     assert set(rep.grid_steps_per_layer) == set(rep.group_sparsity_per_layer)
     # packed layout: far fewer dispatched steps for the same masks, and the
-    # occupancy-based schedule accounting matches the per-group live tiles
-    # (which ARE the cycle model's live DSB steps by construction)
+    # occupancy-based schedule accounting matches the per-layer live
+    # groups (the cycle model's live DSB steps)
     assert rep.packed_dense_grid_steps < rep.dense_grid_steps
     assert rep.packed_executed_grid_steps <= rep.packed_dense_grid_steps
     assert 0 < rep.schedule_steps_live < rep.schedule_steps_total
@@ -420,7 +353,6 @@ def test_simulator_reports_grid_steps():
                              cnn.layer_dims(cfg, pruned)))
     assert per_layer_live == rep.schedule_steps_live
     assert 0.0 < rep.padded_mac_utilization < 1.0
-    assert 0.0 < rep.pergroup_mac_utilization < 1.0
     assert "packed_grid_step_ratio" in rep.row()
     base = simulate(cnn.init(jax.random.PRNGKey(0), cfg)[0], state, cfg, accel)
     assert base.grid_step_ratio == 1.0

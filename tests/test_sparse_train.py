@@ -43,24 +43,24 @@ def _group_mask(rng, n, density):
     return (rng.rand(n) < density).astype(np.float32)
 
 
-# stride {1,2} x SAME/VALID x density {0, .3, 1} x layout/kernel
+# stride {1,2} x SAME/VALID x density {0, .3, 1} x kernel
 GRAD_CASES = [
-    # stride padding cin cout n_cu density packed implicit
-    (1, "SAME", 16, 32, 12, 0.3, True, True),
-    (2, "SAME", 16, 32, 12, 0.3, True, False),
-    (1, "VALID", 9, 10, 4, 0.3, True, True),
-    (2, "VALID", 5, 12, 4, 0.3, True, True),
-    (1, "SAME", 3, 10, 4, 0.3, False, False),   # one-group-per-tile layout
-    (2, "SAME", 5, 12, 4, 0.3, False, False),
-    (1, "SAME", 8, 16, 4, 1.0, True, True),     # fully dense plan
-    (1, "SAME", 16, 32, 12, 0.0, True, True),   # fully pruned -> zero grads
+    # stride padding cin cout n_cu density implicit
+    (1, "SAME", 16, 32, 12, 0.3, True),
+    (2, "SAME", 16, 32, 12, 0.3, False),
+    (1, "VALID", 9, 10, 4, 0.3, True),
+    (2, "VALID", 5, 12, 4, 0.3, True),
+    (1, "SAME", 3, 10, 4, 0.3, False),          # one K-tile, ragged cout
+    (2, "SAME", 5, 12, 4, 0.3, False),
+    (1, "SAME", 8, 16, 4, 1.0, True),           # fully dense plan
+    (1, "SAME", 16, 32, 12, 0.0, True),         # fully pruned -> zero grads
 ]
 
 
 @pytest.mark.parametrize(
-    "stride,padding,cin,cout,n_cu,density,packed,implicit", GRAD_CASES)
+    "stride,padding,cin,cout,n_cu,density,implicit", GRAD_CASES)
 def test_trainable_conv_grad_parity(stride, padding, cin, cout, n_cu,
-                                    density, packed, implicit):
+                                    density, implicit):
     rng = np.random.RandomState(hash((stride, cin, cout, density)) % 2**31)
     spec = fpga_conv_groups((3, 3, cin, cout), n_cu)
     gm = _group_mask(rng, spec.num_groups, density)
@@ -68,7 +68,7 @@ def test_trainable_conv_grad_parity(stride, padding, cin, cout, n_cu,
     w = jnp.asarray(rng.randn(3, 3, cin, cout).astype(np.float32))
     x = jnp.asarray(rng.randn(2, 9, 8, cin).astype(np.float32))
 
-    conv = make_sparse_conv(conv_gemm_layout(spec, packed=packed), gm,
+    conv = make_sparse_conv(conv_gemm_layout(spec), gm,
                             implicit=implicit, trainable=True)
     assert conv.trainable
 
@@ -102,7 +102,7 @@ def test_trainable_conv_under_jit_and_repeated_shapes():
     spec = fpga_conv_groups((3, 3, 8, 16), 4)
     gm = _group_mask(rng, spec.num_groups, 0.5)
     em = spec.expand(jnp.asarray(gm))
-    conv = make_sparse_conv(conv_gemm_layout(spec, packed=True), gm,
+    conv = make_sparse_conv(conv_gemm_layout(spec), gm,
                             trainable=True)
     x = jnp.asarray(rng.randn(2, 8, 8, 8).astype(np.float32))
 
@@ -123,7 +123,7 @@ def test_trainable_rejects_inference_epilogues():
     spec = fpga_conv_groups((3, 3, 8, 16), 4)
     gm = np.ones(spec.num_groups, np.float32)
     with pytest.raises(ValueError, match="inference-only"):
-        make_sparse_conv(conv_gemm_layout(spec, packed=True), gm,
+        make_sparse_conv(conv_gemm_layout(spec), gm,
                          trainable=True, relu=True)
 
 

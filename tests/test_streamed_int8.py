@@ -125,7 +125,7 @@ def _conv_fixture(rng, density=0.4, kx=3, cin=9, cout=10, n_cu=4):
     spec = fpga_conv_groups((kx, kx, cin, cout), n_cu)
     gm = (rng.rand(spec.num_groups) < density).astype(np.float32)
     w = jnp.asarray(rng.uniform(-2, 2, (kx, kx, cin, cout)), jnp.float32)
-    layout = conv_gemm_layout(spec, packed=True)
+    layout = conv_gemm_layout(spec)
     return spec, gm, w, layout
 
 
